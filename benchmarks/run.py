@@ -8,7 +8,6 @@ Prints ``name,us_per_call,derived`` CSV rows (comment lines start '#').
     fig5_psweep     RECEIPT time vs P                             (Fig 5)
     fig67_ablation  HUC/DGM ablations (RECEIPT--/-/full)          (Figs 6-7)
     fig89_breakup   wedge & time breakup per phase                (Figs 8-9)
-    fig1011_scaling multi-device scaling of the distributed engine(Figs 10-11)
     kernel_bench    butterfly kernel: dense blocked vs segment
 """
 from __future__ import annotations
@@ -152,56 +151,6 @@ def fig89_breakup(rows):
         ))
 
 
-def fig1011_scaling(rows):
-    """Distributed-engine scaling over forced host devices (subprocess)."""
-    import json
-    import subprocess
-
-    script = r"""
-import os, sys, json, time
-os.environ["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={sys.argv[1]}"
-sys.path.insert(0, "src")
-import jax, jax.numpy as jnp, numpy as np
-from repro.launch.mesh import make_mesh
-from repro.core.distributed import distributed_butterfly_support
-n_dev = int(sys.argv[1])
-mesh = make_mesh((1, n_dev), ("data", "model"))
-rng = np.random.default_rng(0)
-a = jnp.asarray((rng.random((2048, 2048)) < 0.02).astype(np.float32))
-s = jnp.ones((2048,), jnp.float32)
-out = distributed_butterfly_support(mesh, a, s)  # compile
-out.block_until_ready()
-t0 = time.perf_counter()
-for _ in range(3):
-    out = distributed_butterfly_support(mesh, a, s)
-    out.block_until_ready()
-print(json.dumps({"dt": (time.perf_counter() - t0) / 3, "check": float(out.sum())}))
-"""
-    base = None
-    check0 = None
-    for nd in (1, 2, 4, 8):
-        try:
-            res = subprocess.run(
-                [sys.executable, "-c", script, str(nd)],
-                capture_output=True, text=True, timeout=900,
-            )
-            data = json.loads(res.stdout.strip().splitlines()[-1])
-            dt = data["dt"]
-            base = base or dt
-            check0 = check0 if check0 is not None else data["check"]
-            assert abs(data["check"] - check0) < 1e-3 * max(abs(check0), 1)
-            rows.append((
-                f"fig1011_scaling/devices={nd}", dt * 1e6,
-                f"speedup={base/dt:.2f}x "
-                "(CAVEAT: forced host devices share one CPU socket; "
-                "intra-op threading already saturates cores at 1 device, "
-                "so wall-clock scaling inverts — the dry-run collective "
-                "analysis in EXPERIMENTS.md is the scalability evidence)",
-            ))
-        except Exception as e:  # pragma: no cover
-            rows.append((f"fig1011_scaling/devices={nd}", 0.0, f"error={e}"))
-
-
 def kernel_bench(rows):
     import jax
     import jax.numpy as jnp
@@ -301,7 +250,7 @@ def wing_ext(rows):
 
 BENCHES = [
     table3_time, table3_wedges, table3_sync, fig5_psweep,
-    fig67_ablation, fig89_breakup, fig1011_scaling, kernel_bench,
+    fig67_ablation, fig89_breakup, kernel_bench,
     wing_ext,
 ]
 

@@ -33,6 +33,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import time
+import warnings
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
@@ -70,12 +71,17 @@ __all__ = ["Executor", "Decomposition", "TipDecomposition",
 # device-program failures the fallback chain recovers from: the taxonomy's
 # KernelBackendError (incl. injected faults) plus whatever the XLA runtime
 # raises for a failed executable
-try:
-    from jax.errors import JaxRuntimeError as _JaxRuntimeError
+_KERNEL_FAILURES: Tuple = (KernelBackendError, jax.errors.JaxRuntimeError)
 
-    _KERNEL_FAILURES: Tuple = (KernelBackendError, _JaxRuntimeError)
-except ImportError:                                    # pragma: no cover
-    _KERNEL_FAILURES = (KernelBackendError,)
+def _warn_degraded(backend: str, exc: BaseException, where) -> None:
+    """Every backend degradation is loud: the run stays exact on the
+    next stop of the chain, but it no longer measures ``backend``."""
+    nxt = kops.fallback_backend(backend)
+    warnings.warn(
+        f"kernel backend {backend!r} failed ({type(exc).__name__}: {exc}); "
+        + (f"degrading to {nxt!r}" if nxt else "no fallback left")
+        + f" [{where}]", RuntimeWarning, stacklevel=3)
+
 
 # failures of a plan's PRIMARY backend before its signature is quarantined
 # onto the fallback backend (subsequent runs skip the primary entirely)
@@ -502,6 +508,7 @@ class Executor:
                 except _KERNEL_FAILURES as e:
                     failed.append(b)
                     last = e
+                    _warn_degraded(b, e, plan.signature)
                     if b == primary:
                         entry.failures += 1
                         nxt = kops.fallback_backend(b)
@@ -749,8 +756,9 @@ class Executor:
                     report["chunk_retries"] += 1
                     self._fallback_runs += 1
                 return
-            except _KERNEL_FAILURES:
+            except _KERNEL_FAILURES as e:
                 report["chunk_failures"] += 1
+                _warn_degraded(b, e, ("map", mm, cc))
                 if not terminal:
                     continue
                 if len(chunk) == 1:

@@ -47,7 +47,12 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..kernels.ref import EXACT
 from ..launch.mesh import dp_axes
+
+# the mesh FD local body is pure jnp (DESIGN.md §4): one program per
+# shard, no Pallas launch inside shard_map
+_LOCAL_BACKEND = "xla"
 
 
 def _specs(mesh: Mesh):
@@ -105,7 +110,8 @@ def cd_sweep_step(a, support, alive, rows, valid, ids, lo, *,
         w = shard_act(w, ("batch", "tp"))
         b2 = w * (w - 1.0) * 0.5
         not_self = (ids[:, None] != rows_c[None, :]).astype(jnp.float32)
-        return (b2 * not_self) @ valid_c.astype(jnp.float32)
+        return jnp.matmul(b2 * not_self, valid_c.astype(jnp.float32),
+                          precision=EXACT)
 
     if n_s <= chunk:
         delta = delta_chunk(rows, valid)
@@ -149,7 +155,6 @@ def cd_sweep_shardmap(mesh: Mesh, *, chunk: int = 16384):
 
     Returns a function with the same signature as cd_sweep_step.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as PS
 
     dp = dp_axes(mesh)
@@ -162,9 +167,7 @@ def cd_sweep_shardmap(mesh: Mesh, *, chunk: int = 16384):
         # global row offset of this dp shard
         dp_idx = jax.lax.axis_index(dp[0])
         for ax in dp[1:]:
-            # mesh.shape (closed over) — jax.lax.axis_size only exists in
-            # newer jax releases
-            dp_idx = dp_idx * mesh.shape[ax] + jax.lax.axis_index(ax)
+            dp_idx = dp_idx * jax.lax.axis_size(ax) + jax.lax.axis_index(ax)
         row0 = dp_idx * n_u_loc
         tp_idx = jax.lax.axis_index(tp)
 
@@ -195,7 +198,8 @@ def cd_sweep_shardmap(mesh: Mesh, *, chunk: int = 16384):
             valid_s = jax.lax.dynamic_slice_in_dim(valid_c, tp_idx * scat, scat)
             b2 = w * (w - 1.0) * 0.5
             not_self = (ids_loc[:, None] != rows_s[None, :]).astype(jnp.float32)
-            return acc + (b2 * not_self) @ valid_s, None
+            return acc + jnp.matmul(b2 * not_self, valid_s,
+                                    precision=EXACT), None
 
         delta_par, _ = jax.lax.scan(
             one_chunk,
@@ -217,12 +221,12 @@ def cd_sweep_shardmap(mesh: Mesh, *, chunk: int = 16384):
         return support_loc, alive_after
 
     dp_spec = dp if len(dp) > 1 else dp[0]
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(PS(dp_spec, tp), PS(dp_spec), PS(dp_spec), PS(), PS(),
                   PS(dp_spec), PS()),
         out_specs=(PS(dp_spec), PS(dp_spec)),
-        check_rep=False,
+        check_vma=False,
     )
 
 
@@ -328,7 +332,7 @@ def recount_step(a, alive, ids):
     w = a @ (a * s[:, None]).T
     b2 = w * (w - 1.0) * 0.5
     not_self = (ids[:, None] != ids[None, :]).astype(a.dtype)
-    return (b2 * not_self) @ s
+    return jnp.matmul(b2 * not_self, s, precision=EXACT)
 
 
 # --------------------------------------------------------------------- #
@@ -442,8 +446,6 @@ def fd_level_shardmap(mesh: Mesh, *, max_sweeps: int = 100_000,
     state (sup, alive, dv, theta, rho, wedges) so the end-to-end driver
     can re-enter after a ``max_sweeps`` cap-exit.
     """
-    from jax.experimental.shard_map import shard_map
-
     from .engine.peel_loop import batched_level_loop
 
     all_axes = tuple(mesh.axis_names)
@@ -453,7 +455,8 @@ def fd_level_shardmap(mesh: Mesh, *, max_sweeps: int = 100_000,
         valid1 = (jnp.arange(a_l1.shape[1])[None, :]
                   < n_l1[:, None]).astype(f32)
         w1 = jnp.einsum("gmc,gwc->gmw", a.astype(f32), a_l1.astype(f32))
-        delta1 = jnp.einsum("gmw,gw->gm", w1 * (w1 - 1.0) * 0.5, valid1)
+        delta1 = jnp.einsum("gmw,gw->gm", w1 * (w1 - 1.0) * 0.5, valid1,
+                            precision=EXACT)
         sup = jnp.maximum(sup - delta1, cap1[:, None])
         row_ext = jnp.zeros(a.shape[:2], jnp.int32)   # xla path ignores it
         pw = a.shape[1] if peel_width is None else min(peel_width,
@@ -461,7 +464,7 @@ def fd_level_shardmap(mesh: Mesh, *, max_sweeps: int = 100_000,
         (sup2, alive2, dv2, theta, rho, wedges, _max_level,
          _sweeps) = batched_level_loop(
             a, row_ext, sup, alive, dv, lo,
-            backend="xla", blocks=(8, 8, 8),
+            backend=_LOCAL_BACKEND, blocks=(8, 8, 8),
             peel_width=pw, max_sweeps=max_sweeps,
             update_mode=update_mode,
         )
@@ -473,12 +476,12 @@ def fd_level_shardmap(mesh: Mesh, *, max_sweeps: int = 100_000,
     g1 = P(all_axes)
     out_specs = ((vec, vec, vec, vec, g1, g1) if full_state
                  else (vec, g1, g1))
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(all_axes, None, None), P(all_axes, None, None),
                   g1, g1, vec, vec, vec, g1),
         out_specs=out_specs,
-        check_rep=False,
+        check_vma=False,
     )
 
 
@@ -589,18 +592,16 @@ def lower_fd_stack(mesh: Mesh, *, n_subsets: int, rows: int, cols: int):
     device peels its local stack with zero collectives.  (Left to GSPMD,
     the per-step batched argmin/gather lowered to ~12k tiny all-reduces —
     EXPERIMENTS.md §Roofline notes.)"""
-    from jax.experimental.shard_map import shard_map
-
     all_axes = tuple(mesh.axis_names)
     stack = NamedSharding(mesh, P(all_axes, None, None))
     vec = NamedSharding(mesh, P(all_axes, None))
     g1 = NamedSharding(mesh, P(all_axes))
-    local_fd = shard_map(
+    local_fd = jax.shard_map(
         fd_stack_step, mesh=mesh,
         in_specs=(P(all_axes, None, None), P(all_axes, None),
                   P(all_axes), P(all_axes)),
         out_specs=P(all_axes, None),
-        check_rep=False,
+        check_vma=False,
     )
     sds = jax.ShapeDtypeStruct
     f32 = jnp.float32

@@ -62,6 +62,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ...kernels import ops as kops
+from ...kernels.ref import EXACT
 from ...kernels.butterfly_sparse import (
     batched_gathered_tile_extents,
     gathered_tile_extents,
@@ -363,7 +364,8 @@ def sweep_info(a, support, alive, hi):
     """
     peel = alive & (support < hi)
     dv = a.T @ alive.astype(a.dtype)                 # residual V degrees
-    wcur = a @ jnp.maximum(dv - 1.0, 0.0)            # per-row residual wedges
+    wcur = jnp.matmul(a, jnp.maximum(dv - 1.0, 0.0),  # per-row residual
+                      precision=EXACT)               # wedges (§8)
     c_peel = jnp.sum(jnp.where(peel, wcur, 0.0))
     return peel, jnp.sum(peel), c_peel
 
@@ -893,7 +895,7 @@ def device_cd_graph_loop(ids, state, *, backend, blocks, use_huc, use_dgm,
         init_sup = jnp.where(st["alive"], st["support"], st["init_sup"])
         # fresh residual wedge counts: the range proxy the subset driver
         # only refreshes at DGM compactions, here free at every boundary
-        w = a2 @ jnp.maximum(dv - 1.0, 0.0)
+        w = jnp.matmul(a2, jnp.maximum(dv - 1.0, 0.0), precision=EXACT)
         rem = jnp.sum(jnp.where(st["alive"], w, 0.0))
         catch = i2 >= p_total - 1
         tgt = jnp.where(
@@ -1119,20 +1121,19 @@ def batched_level_loop(a, row_ext, support, alive, dv, lo, eu=None, ev=None,
         # mask fuse into the staircase-skipping b2_stack kernel; the xla
         # route (and any block-misaligned stack) keeps the einsum —
         # bit-identical either way (integer regime).
-        if (backend != "xla" and mm % blocks[0] == 0
-                and mm % blocks[1] == 0 and cc % blocks[2] == 0):
-            b2 = kops.b2_stack(a.astype(f32), backend=backend,
-                               blocks=blocks)
-        else:
-            b2 = kops.b2_stack(a.astype(f32), backend="xla",
-                               blocks=blocks)
+        aligned = (mm % blocks[0] == 0 and mm % blocks[1] == 0
+                   and cc % blocks[2] == 0)
+        b2 = kops.b2_stack(a.astype(f32),
+                           backend=backend if aligned else "xla",
+                           blocks=blocks)
     elif update_mode != "kernel":
         raise ValueError(f"unknown update_mode {update_mode!r}")
 
     def full_mask_update(peel):
         """Full-width update: B = A, s = peel mask (no gather)."""
         if update_mode == "b2":
-            delta = jnp.einsum("gm,gmn->gn", peel.astype(f32), b2)
+            delta = jnp.einsum("gm,gmn->gn", peel.astype(f32), b2,
+                               precision=EXACT)
         else:
             delta = kops.butterfly_update_batched(
                 a, a, peel.astype(a.dtype), ids, ids,
@@ -1156,7 +1157,8 @@ def batched_level_loop(a, row_ext, support, alive, dv, lo, eu=None, ev=None,
         )
         if update_mode == "b2":
             b2_rows = jnp.take_along_axis(b2, rows[:, :, None], axis=1)
-            delta = jnp.einsum("gw,gwm->gm", valid.astype(f32), b2_rows)
+            delta = jnp.einsum("gw,gwm->gm", valid.astype(f32), b2_rows,
+                               precision=EXACT)
         else:
             if sparse:
                 kb = batched_gathered_tile_extents(row_ext, rows, valid,

@@ -33,8 +33,11 @@ partition, ``RunStats.bounds``):
   survivors keep the stored one.
 
 The loops below are the bounded variant of ``batched_level_loop``:
-single-graph, mask-form updates, and a ``hi_stop`` cut in the loop
-condition — the sweep pieces (``level_threshold`` / ``select_peel`` /
+single-graph, a ``hi_stop`` cut in the loop condition, and on the
+vertex axis the same two update forms — the peel level gathered into a
+fixed ``peel_width`` buffer, or the mask form when the level overflows
+it (a mask-form sweep contracts every row against every row, so a
+refresh of thousands of small levels must not pay it per level) — the sweep pieces (``level_threshold`` / ``select_peel`` /
 ``apply_delta`` / ``record_theta`` / ``peel_cost``) are the shared ones,
 not copies.  ``hi_stop`` rides the carry as a traced scalar so neither
 different mutation batches nor stop escalations retrace.
@@ -54,7 +57,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ...kernels import ops as kops
-from ...kernels.butterfly_sparse import batched_row_extents
+from ...kernels.butterfly_sparse import (batched_row_extents,
+                                         gathered_tile_extents)
 from ..graph import BipartiteGraph
 from .peel_loop import (
     _INF,
@@ -105,9 +109,10 @@ def synthesize_bounds(numbers, num_partitions: int):
 
 
 @functools.partial(jax.jit, static_argnames=("backend", "blocks",
-                                             "max_sweeps"))
-def _tip_prefix_loop(a, ids, kmax, support, alive, dv, theta, rho, wedges,
-                     hi_stop, *, backend, blocks, max_sweeps):
+                                             "peel_width", "max_sweeps"))
+def _tip_prefix_loop(a, ids, row_ext, kmax, support, alive, dv, theta, rho,
+                     wedges, hi_stop, *, backend, blocks, peel_width,
+                     max_sweeps):
     """Level-peel every row whose tip number lands below ``hi_stop``.
 
     One ``lax.while_loop``; each sweep peels the whole current-minimum
@@ -116,9 +121,30 @@ def _tip_prefix_loop(a, ids, kmax, support, alive, dv, theta, rho, wedges,
     clamp.  Exits when every survivor's support >= ``hi_stop`` (their
     numbers are >= the stop and stay stored) or on the ``max_sweeps``
     valve; the host re-enters on either (cap re-entry / stop
-    escalation) by feeding the state straight back.
+    escalation) by feeding the state straight back.  A level of at most
+    ``peel_width`` rows is gathered and contracted against the whole
+    matrix; a larger one takes the mask form (``B = A``).
     """
     f32 = jnp.float32
+    sparse = backend in kops.SPARSE_BACKENDS
+
+    def mask_update(peel, n_peel):
+        delta = kops.butterfly_update(
+            a, a, peel.astype(a.dtype), ids, ids,
+            backend=backend, blocks=blocks, kmax_a=kmax, kmax_b=kmax)
+        return delta, peel.astype(f32) @ a.astype(f32)
+
+    def gathered_update(peel, n_peel):
+        rows = jnp.nonzero(peel, size=peel_width, fill_value=0)[0]
+        rows = rows.astype(jnp.int32)
+        valid = jnp.arange(peel_width) < n_peel
+        a_peel = a[rows] * valid[:, None].astype(a.dtype)
+        kb = (gathered_tile_extents(row_ext, rows, valid, blocks[1])
+              if sparse else None)
+        delta = kops.butterfly_update(
+            a, a_peel, valid.astype(a.dtype), ids, rows,
+            backend=backend, blocks=blocks, kmax_a=kmax, kmax_b=kb)
+        return delta, valid.astype(f32) @ a_peel.astype(f32)
 
     def cond_fn(st):
         support, alive = st[0], st[1]
@@ -130,10 +156,9 @@ def _tip_prefix_loop(a, ids, kmax, support, alive, dv, theta, rho, wedges,
         support, alive, dv, theta, rho, wedges, sweeps = st
         hi, cap = level_threshold(support, alive, 0.0)
         peel = select_peel(support, alive, hi)
-        delta = kops.butterfly_update(
-            a, a, peel.astype(a.dtype), ids, ids,
-            backend=backend, blocks=blocks, kmax_a=kmax, kmax_b=kmax)
-        colsum = peel.astype(f32) @ a.astype(f32)
+        n_peel = jnp.sum(peel)
+        delta, colsum = jax.lax.cond(n_peel > peel_width, mask_update,
+                                     gathered_update, peel, n_peel)
         wedges = wedges + peel_cost(colsum, dv)
         support2, alive2 = apply_delta(support, alive, peel, delta, cap)
         theta2 = record_theta(theta, peel, cap)
@@ -268,8 +293,12 @@ def repeel_tip_prefix(
         rext = batched_row_extents(a[None], bk)[0]
         kmax = jnp.asarray(
             rext.reshape(-1, bi).max(axis=1).astype(np.int32))
+        row_ext = jnp.asarray(rext)
     else:
-        kmax = None
+        kmax = row_ext = None
+    # levels are small once the lowest ones are gone: one row tile of
+    # buffer (or the configured width) keeps a sweep O(width * rows)
+    peel_width = min(bucket(cfg.peel_width or bj, bj), rows_pad)
     carry = dict(
         support=jnp.where(jnp.asarray(alive0),
                           jnp.asarray(sup_pad, jnp.float32), _INF),
@@ -281,10 +310,10 @@ def repeel_tip_prefix(
 
     def run_one(stop):
         out = _tip_prefix_loop(
-            a_dev, ids, kmax, carry["support"], carry["alive"],
+            a_dev, ids, row_ext, kmax, carry["support"], carry["alive"],
             carry["dv"], carry["theta"], carry["rho"], carry["wedges"],
-            jnp.float32(stop),
-            backend=backend, blocks=blocks, max_sweeps=cfg.max_sweeps)
+            jnp.float32(stop), backend=backend, blocks=blocks,
+            peel_width=peel_width, max_sweeps=cfg.max_sweeps)
         (carry["support"], carry["alive"], carry["dv"], carry["theta"],
          carry["rho"], carry["wedges"], _sw) = out
         alive_h, th_h, rho_h, sup_h = jax.device_get(

@@ -51,11 +51,6 @@ __all__ = [
 
 DEFAULT_BLOCKS = (128, 128, 512)
 
-# jax renamed TPUCompilerParams -> CompilerParams across releases; accept both
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams"
-)
-
 
 def butterfly_kernel_body(
     a_ref,        # (BI, BK)  output-side rows
@@ -135,7 +130,7 @@ def butterfly_support_pallas(
         out_specs=pl.BlockSpec((1, bi), lambda i, j, k: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, n_a), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bi, bj), jnp.float32)],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
         interpret=interpret,
@@ -235,7 +230,7 @@ def butterfly_update_pallas_batched(
         out_specs=pl.BlockSpec((1, 1, bi), lambda g, i, j, k: (g, 0, i)),
         out_shape=jax.ShapeDtypeStruct((g_n, 1, n_a), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bi, bj), jnp.float32)],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(
                 "parallel", "parallel", "arbitrary", "arbitrary",
             ),

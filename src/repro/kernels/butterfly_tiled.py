@@ -10,15 +10,18 @@ list instead of the dense tile grid, so both memory and wedge-kernel
 work scale with the number of occupied tiles rather than
 ``rows_pad * cols_pad``.
 
-Kernel geometry.  The grid is ``(n_row_tiles, n_slots)`` — outer index
-``j`` picks the B row-band, inner index ``t`` walks the tile slots in
-CSR order.  Scalar-prefetched maps drive the data movement exactly the
-way ``gathered_tile_extents`` drives the staircase kernel:
+Kernel geometry.  The grid is ``(active B bands, n_slots)`` — outer
+index ``jj`` picks the ``jj``-th B row-band that carries ``s`` mass
+(``jlist``), inner index ``t`` walks the tile slots in CSR order.
+Scalar-prefetched maps drive the data movement exactly the way
+``gathered_tile_extents`` drives the staircase kernel:
 
 * ``srow[t]`` / ``sptr`` give each slot's row-band and the band
   boundaries, so the wedge accumulator is zeroed at a band's first slot
   and flushed (B2 epilogue) at its last — every band owns >= 1 slot by
-  construction, so the lifecycle always fires;
+  construction, so the lifecycle always fires.  The flush adds into an
+  output that stays resident in VMEM for the whole grid: each A band is
+  revisited once per B band, so the output cannot be a per-band block;
 * the A tile is ``tile_data[t]``; the B tile is
   ``tile_data[pos[j, scol[t]]]``, a scalar-prefetch GATHER in the
   BlockSpec index map (clamped to 0 when absent; the kernel masks the
@@ -156,7 +159,7 @@ def row_weights_tiled(tile_data: jnp.ndarray, srow: jnp.ndarray,
 
 
 # --------------------------------------------------------------------- #
-# Pallas kernel: grid (n_row_tiles, n_slots), slot innermost
+# Pallas kernel: grid (active B bands, n_slots), slot innermost
 # --------------------------------------------------------------------- #
 def _tiled_update_kernel(
     srow_ref,     # scalar prefetch: (n_slots,) int32 slot -> row band
@@ -164,34 +167,40 @@ def _tiled_update_kernel(
     sptr_ref,     # scalar prefetch: (n_rt + 1,) int32 band boundaries
     pos_ref,      # scalar prefetch: (n_rt, n_ct) int32 reverse map
     live_ref,     # scalar prefetch: (n_slots,) int32 slot liveness
-    sband_ref,    # scalar prefetch: (n_rt,) int32 any-s-mass per B band
+    jlist_ref,    # scalar prefetch: (n_rt,) int32 B bands carrying s mass
+    nact_ref,     # scalar prefetch: (1,) int32 number of such bands
     a_ref, b_ref, s_ref,
     out_ref, w_acc_ref,
     *,
     block_rows: int,
 ):
-    j, t = pl.program_id(0), pl.program_id(1)
+    jj, t = pl.program_id(0), pl.program_id(1)
+    j = jlist_ref[jj]
     i = srow_ref[t]
     first = t == sptr_ref[i]
     last = t == sptr_ref[i + 1] - 1
+    active = jj < nact_ref[0]
+
+    # the whole output stays resident in VMEM for the entire grid (its
+    # block index never changes) and is written back once at the end:
+    # row band i is revisited once per B band, which a per-band output
+    # block would lose on the chip (a revisited block is not reloaded)
+    @pl.when(jnp.logical_and(jj == 0, t == 0))
+    def _zero_out():
+        out_ref[...] = jnp.zeros_like(out_ref)
 
     @pl.when(first)
     def _zero_wedge_acc():
         w_acc_ref[...] = jnp.zeros_like(w_acc_ref)
 
-    @pl.when(jnp.logical_and(j == 0, first))
-    def _zero_out():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
     # nonzero-block skip: the MXU dot fires only when the A slot is
-    # live, the mirrored B tile exists and is live, and band j carries
-    # any s mass at all (dead slots were zeroed by regather_tiles, so
-    # every skip is provably a zero contribution)
+    # live and the mirrored B tile exists and is live (dead slots were
+    # zeroed by regather_tiles, so every skip is provably a zero
+    # contribution)
     bslot = pos_ref[j, scol_ref[t]]
     live = jnp.logical_and(
         jnp.logical_and(bslot >= 0, live_ref[t] > 0),
-        jnp.logical_and(live_ref[jnp.maximum(bslot, 0)] > 0,
-                        sband_ref[j] > 0))
+        jnp.logical_and(live_ref[jnp.maximum(bslot, 0)] > 0, active))
 
     @pl.when(live)
     def _accumulate():
@@ -201,7 +210,7 @@ def _tiled_update_kernel(
             preferred_element_type=jnp.float32,
         )
 
-    @pl.when(last)
+    @pl.when(jnp.logical_and(last, active))
     def _epilogue():
         w = w_acc_ref[...]
         bi = block_rows
@@ -210,7 +219,7 @@ def _tiled_update_kernel(
         not_self = (ida != idb).astype(w.dtype)
         b2 = w * (w - 1.0) * 0.5
         contrib = b2 * not_self * s_ref[0, :][None, :]
-        out_ref[...] += jnp.sum(contrib, axis=1)[None, :]
+        out_ref[pl.ds(i, 1), :] += jnp.sum(contrib, axis=1)[None, :]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -230,36 +239,49 @@ def butterfly_update_pallas_tiled(
     out[x] = sum_{y != x} s[y] * C((A A^T)[x, y], 2)
 
     ``s`` = alive mask -> per-vertex butterfly counting; ``s`` = peel
-    mask -> the level-peel support delta.  Work is
-    ``O(n_row_tiles * n_slots)`` tile-pair visits instead of the dense
+    mask -> the level-peel support delta.  Only the B row bands that
+    carry ``s`` mass are visited: the grid's outer extent is their count
+    (a dynamic grid bound on the chip; the interpreter runs all
+    ``n_row_tiles`` and masks the rest), so a peel sweep touching a few
+    bands costs ``O(bands * n_slots)`` tile pairs instead of the dense
     kernel's ``O(n_i * n_j * n_k)`` grid.
     """
     n_slots, bi, bk = tile_data.shape
     n_rt, _n_ct = pos.shape
     n_rows = n_rt * bi
-    sband = (s.reshape(n_rt, bi) != 0).any(axis=1).astype(jnp.int32)
+    has = (s.reshape(n_rt, bi) != 0).any(axis=1)
+    n_act = jnp.sum(has).astype(jnp.int32)
+    order = jnp.argsort(~has).astype(jnp.int32)       # active bands first
+    # padding entries repeat the last active band: consecutive steps then
+    # keep the same block indices, so masked steps start no new DMA
+    jlist = jnp.where(jnp.arange(n_rt) < n_act, order,
+                      order[jnp.maximum(n_act - 1, 0)])
+    n_outer = n_rt if interpret else jnp.maximum(n_act, 1)
     kernel = functools.partial(_tiled_update_kernel, block_rows=bi)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=6,
-        grid=(n_rt, n_slots),
+        num_scalar_prefetch=7,
+        grid=(n_outer, n_slots),
         in_specs=[
             pl.BlockSpec((1, bi, bk),
-                         lambda j, t, sr, sc, sp, po, lv, sb: (t, 0, 0)),
+                         lambda jj, t, sr, sc, sp, po, lv, jl, na: (t, 0, 0)),
             pl.BlockSpec(
                 (1, bi, bk),
-                lambda j, t, sr, sc, sp, po, lv, sb:
-                    (jnp.maximum(po[j, sc[t]], 0), 0, 0)),
+                lambda jj, t, sr, sc, sp, po, lv, jl, na:
+                    (jnp.maximum(po[jl[jj], sc[t]], 0), 0, 0)),
             pl.BlockSpec((1, bi),
-                         lambda j, t, sr, sc, sp, po, lv, sb: (0, j)),
+                         lambda jj, t, sr, sc, sp, po, lv, jl, na:
+                             (0, jl[jj])),
         ],
         out_specs=pl.BlockSpec(
-            (1, bi), lambda j, t, sr, sc, sp, po, lv, sb: (0, sr[t])),
+            (n_rt, bi), lambda jj, t, sr, sc, sp, po, lv, jl, na: (0, 0)),
         scratch_shapes=[pltpu.VMEM((bi, bi), jnp.float32)],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((1, n_rows), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((n_rt, bi), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(
         srow.astype(jnp.int32),
@@ -267,12 +289,13 @@ def butterfly_update_pallas_tiled(
         sptr.astype(jnp.int32),
         pos.astype(jnp.int32),
         slot_live.astype(jnp.int32),
-        sband,
+        jlist,
+        n_act.reshape(1),
         tile_data.astype(jnp.float32),
         tile_data.astype(jnp.float32),
         s.reshape(1, n_rows).astype(jnp.float32),
     )
-    return out[0]
+    return out.reshape(n_rows)
 
 
 # --------------------------------------------------------------------- #

@@ -115,6 +115,9 @@ def route_label(backend: Optional[str]) -> str:
 # when its launches fail — compiled kernel -> same kernel body under the
 # interpreter -> the pure-jnp oracle.  All stops are exact (bit-identical
 # in the f32 integer regime), so degrading trades speed, never results.
+# On a TPU the interpreter stops are skipped: the interpreter is a CPU
+# validation tool, and a compiled kernel that fails there must surface
+# as a (warned) drop to the jnp oracle, not as a slow interpreted re-run.
 _FALLBACK_NEXT = {
     "pallas": "interpret",
     "pallas_sparse": "interpret_sparse",
@@ -122,22 +125,27 @@ _FALLBACK_NEXT = {
     "interpret_sparse": "xla",
     "xla": None,
 }
+_INTERPRETERS = ("interpret", "interpret_sparse")
 
 
 def fallback_backend(backend: Optional[str]) -> Optional[str]:
     """The next backend in the degradation chain (None = end of chain)."""
-    return _FALLBACK_NEXT[resolve_backend(backend)]
+    b = _FALLBACK_NEXT[resolve_backend(backend)]
+    if b in _INTERPRETERS and jax.default_backend() == "tpu":
+        b = _FALLBACK_NEXT[b]
+    return b
 
 
 def fallback_chain(backend: Optional[str]) -> tuple:
     """The full degradation chain starting AT ``backend`` (inclusive):
-    ``pallas -> interpret -> xla``, ``interpret_sparse -> xla``, ...
-    The Executor walks this on ``KernelBackendError`` (DESIGN.md §7)."""
+    ``pallas -> interpret -> xla`` (``pallas -> xla`` on a TPU),
+    ``interpret_sparse -> xla``, ...  The Executor walks this on
+    ``KernelBackendError`` (DESIGN.md §7)."""
     b: Optional[str] = resolve_backend(backend)
     chain = []
     while b is not None:
         chain.append(b)
-        b = _FALLBACK_NEXT[b]
+        b = fallback_backend(b)
     return tuple(chain)
 
 
@@ -198,7 +206,7 @@ def _update_ref(a, b, s, ids_a, ids_b):
     w = a @ b.T
     b2 = w * (w - 1.0) * 0.5
     not_self = (ids_a[:, None] != ids_b[None, :]).astype(a.dtype)
-    return (b2 * not_self) @ s.astype(a.dtype)
+    return jnp.matmul(b2 * not_self, s.astype(a.dtype), precision=ref.EXACT)
 
 
 def _full_extents(n_rows: int, block_rows: int, n_k: int) -> jnp.ndarray:
@@ -250,7 +258,8 @@ def _update_ref_batched(a, b, s, ids_a, ids_b):
     w = jnp.einsum("gic,gjc->gij", a, b)
     b2 = w * (w - 1.0) * 0.5
     not_self = (ids_a[:, :, None] != ids_b[:, None, :]).astype(a.dtype)
-    return jnp.einsum("gij,gj->gi", b2 * not_self, s.astype(a.dtype))
+    return jnp.einsum("gij,gj->gi", b2 * not_self, s.astype(a.dtype),
+                      precision=ref.EXACT)
 
 
 @functools.partial(jax.jit, static_argnames=("backend", "blocks"))
@@ -420,7 +429,7 @@ def edge_support_all(
     """
     resolve_backend(backend)
     at = jnp.swapaxes(a, -1, -2)
-    m3 = a @ (at @ a)
+    m3 = jnp.matmul(a, at @ a, precision=ref.EXACT)
     du = jnp.sum(a, axis=-1)
     dvv = jnp.sum(a, axis=-2)
     if a.ndim == 2:

@@ -18,9 +18,17 @@ which covers (a) per-vertex counting  (s = alive),
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
-__all__ = ["wedge_matrix", "shared_butterflies", "butterfly_support_ref"]
+__all__ = ["EXACT", "wedge_matrix", "shared_butterflies",
+           "butterfly_support_ref"]
+
+# f32 products with an operand that can exceed 256 (butterfly counts, B2
+# rows, degrees) run at HIGHEST: a TPU's DEFAULT f32 matmul is one bf16
+# pass, exact only for integers up to 256 (DESIGN.md §8).  0/1 x 0/1
+# products stay at the default.
+EXACT = jax.lax.Precision.HIGHEST
 
 
 def wedge_matrix(a: jnp.ndarray) -> jnp.ndarray:
@@ -42,4 +50,4 @@ def butterfly_support_ref(a: jnp.ndarray, s: jnp.ndarray) -> jnp.ndarray:
     a: (n_u, n_v) 0/1; s: (n_u,) 0/1 row-mask (the "peel set" / alive set).
     """
     b2 = shared_butterflies(a)
-    return b2 @ s.astype(a.dtype)
+    return jnp.matmul(b2, s.astype(a.dtype), precision=EXACT)

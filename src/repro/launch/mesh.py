@@ -15,18 +15,24 @@ from typing import Optional, Sequence, Tuple
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
-def make_mesh(shape: Sequence[int], axes: Sequence[str]) -> Mesh:
-    """Arbitrary mesh (tests use small shapes on forced host devices)."""
-    return jax.make_mesh(tuple(shape), tuple(axes))
+def make_mesh(shape: Sequence[int], axes: Sequence[str], *,
+              devices=None) -> Mesh:
+    """Mesh with ``Auto`` axes (tests use small shapes on forced host
+    devices).  ``jax.make_mesh`` defaults to ``Explicit`` axes, under
+    which ``with_sharding_constraint`` refuses the GSPMD-style specs the
+    engine and the dry-run cells use."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def dp_axes(mesh: Mesh) -> Tuple[str, ...]:

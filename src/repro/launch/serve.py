@@ -61,7 +61,7 @@ def selftest(workload: str = "tip", verbose: bool = True) -> int:
     from ..service import DecompositionService, ServiceConfig
 
     rng = np.random.default_rng(0)
-    cfg = EngineConfig(num_partitions=6, backend="xla")
+    cfg = EngineConfig(num_partitions=6)
     svc = DecompositionService(cfg, ServiceConfig(
         refresh_dirty_threshold=0.10))
     g = interaction_graph(72, 48, 560, seed=11)
@@ -113,7 +113,7 @@ def soak(workload: str = "tip", *, datasets: int = 3, rounds: int = 3,
     from ..service import DecompositionService, ServiceConfig
 
     rng = np.random.default_rng(7)
-    cfg = EngineConfig(num_partitions=6, backend="xla")
+    cfg = EngineConfig(num_partitions=6)
     scfg = ServiceConfig(background=background, worker_poll_s=0.01,
                          refresh_dirty_threshold=0.25,
                          cache_budget_bytes=cache_budget)
@@ -200,6 +200,10 @@ def main(argv=None):
                     help="print the resolved config and exit")
     args = ap.parse_args(argv)
 
+    from .compile_cache import enable_compile_cache
+
+    cache = enable_compile_cache()
+    print(f"[serve] compile cache: {cache.directory}")
     if args.selftest:
         return selftest(args.workload)
     if args.soak:
@@ -212,7 +216,7 @@ def main(argv=None):
     from ..data.synthetic import interaction_graph
     from ..service import DecompositionService, ServiceConfig
 
-    cfg = EngineConfig(num_partitions=args.partitions, backend="xla")
+    cfg = EngineConfig(num_partitions=args.partitions)
     svc = DecompositionService(cfg, ServiceConfig(
         background=args.background,
         cache_budget_bytes=args.cache_budget_bytes))

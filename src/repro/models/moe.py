@@ -153,7 +153,6 @@ def moe_forward_sharded(
         all-gather  (expert weight shards)       x3      (FSDP)
         psum        (aux scalar)
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as PS
 
     from ..launch.mesh import axis_size, dp_axes
@@ -227,11 +226,11 @@ def moe_forward_sharded(
             "down": PS(tp, dp_spec),
         }
     pl = {k: p[k] for k in pspecs}
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(PS(dp_spec, tp, None), pspecs),
         out_specs=(PS(dp_spec, tp, None), PS()),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(x, pl)
 

@@ -35,21 +35,11 @@ import numpy as np
 from ..api.errors import ReceiptError
 from ..api.executor import TipDecomposition, WingDecomposition
 from ..core.graph import BipartiteGraph
+from ..core.peeling import butterfly_supports
 from ..kernels import ops as kops
 from .state import DatasetState, ServiceConfig, edge_keys
 
 __all__ = ["refresh_dataset", "classify_refresh"]
-
-
-def _tip_supports_host(g: BipartiteGraph) -> np.ndarray:
-    """Whole-graph per-U-vertex butterfly supports, host f64 (primes the
-    maintained vector; independent of the device kernels)."""
-    a = np.zeros((g.n_u, g.n_v), np.float64)
-    a[g.edges_u, g.edges_v] = 1.0
-    w = a @ a.T
-    per = w * (w - 1.0) / 2.0
-    np.fill_diagonal(per, 0.0)
-    return per.sum(axis=1)
 
 
 def _ladder(bounds: Optional[List[float]], floor: float) -> List[float]:
@@ -103,7 +93,9 @@ def _tip_delta(ds: DatasetState, executor, kI: np.ndarray, kD: np.ndarray):
     a_u[gb.edges_u, gb.edges_v] = 1.0
     a_u[iu, iv] = 1.0
     if ds.supports is None:
-        ds.supports = _tip_supports_host(gb)
+        # primes the maintained vector: exact on the host, independent
+        # of the device kernels
+        ds.supports = butterfly_supports(gb).astype(np.float64)
     a_dev = jnp.asarray(a_u)
     gains = losses = 0.0
     if kI.size:

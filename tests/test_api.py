@@ -207,8 +207,8 @@ def test_executor_cache_skips_tracing_on_same_signature(dispatch, dgm):
     for g in graphs[1:]:
         with jtu.count_jit_tracing_cache_miss() as misses:
             td = ex.decompose(g)
-        assert misses[0] == 0, (
-            f"same-signature decompose retraced {misses[0]} function(s)")
+        assert misses() == 0, (
+            f"same-signature decompose retraced {misses()} function(s)")
         # cached executions stay bit-identical to a cold run
         cold_ref = Executor(_cfg(cd_dispatch=dispatch, use_dgm=dgm,
                                  num_partitions=4)).decompose(g)
@@ -223,7 +223,7 @@ def test_executor_cache_different_signature_retraces():
     ex.decompose(powerlaw_bipartite(90, 50, 600, seed=2))
     with jtu.count_jit_tracing_cache_miss() as misses:
         ex.decompose(powerlaw_bipartite(400, 220, 2400, seed=2))
-    assert misses[0] > 0
+    assert misses() > 0
     assert ex.cache_stats["entries"] == 2
 
 
@@ -342,7 +342,7 @@ def test_map_reuses_executables_across_calls():
     assert ex.last_map_report["cache_misses"] >= 1
     with jtu.count_jit_tracing_cache_miss() as misses:
         tds = ex.map(mk(20))
-    assert misses[0] == 0, "same-shape fleet must not retrace"
+    assert misses() == 0, "same-shape fleet must not retrace"
     assert ex.last_map_report["cache_hits"] >= 1
     for g, td in zip(mk(20), tds):
         tb, _ = bup_oracle(g)

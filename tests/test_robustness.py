@@ -181,12 +181,24 @@ class TestDegradation:
         base = Executor(_cfg(backend="interpret")).decompose(g).theta
         ex = Executor(_cfg(backend="interpret",
                            fault_spec="kernel_launch:backend=interpret@1"))
-        td = ex.decompose(g)
+        with pytest.warns(RuntimeWarning, match="degrading to 'xla'"):
+            td = ex.decompose(g)
         np.testing.assert_array_equal(td.theta, base)
         assert td.stats.backend_used == "xla"
         assert td.stats.backend_fallbacks == ["interpret"]
         assert ex.cache_stats["fallback_runs"] == 1
         assert ex.fault_report[0]["fired"] == 1
+
+    @pytest.mark.parametrize("primary", ["pallas", "pallas_sparse"])
+    def test_tpu_chain_skips_interpreter(self, monkeypatch, primary):
+        """On a TPU a failed compiled kernel drops straight to the jnp
+        oracle: the interpreter is a CPU validation tool."""
+        from repro.kernels import ops as kops
+
+        assert kops.fallback_chain(primary)[1].startswith("interpret")
+        monkeypatch.setattr(kops.jax, "default_backend", lambda: "tpu")
+        assert kops.fallback_chain(primary) == (primary, "xla")
+        assert kops.fallback_backend(primary) == "xla"
 
     def test_repeated_failure_quarantines_signature(self):
         g = _er(40, 30, 200, 1)

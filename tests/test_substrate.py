@@ -261,3 +261,33 @@ def test_lm_param_specs_match_paths():
     assert by_path["layers/moe/gate"][1] == "model"       # EP on experts
     assert by_path["embed"][0] == "model"                 # vocab sharded
     assert by_path["layers/attn/wkv_b"][2] == "model"     # MLA up-proj TP
+
+
+# --------------------------------------------------------------------- #
+# persistent compile cache placement
+# --------------------------------------------------------------------- #
+@pytest.mark.parametrize("env_set", [True, False])
+def test_compile_cache_directory_is_fixed(env_set, tmp_path, monkeypatch):
+    """``JAX_COMPILATION_CACHE_DIR`` wins and the helper sets nothing;
+    unset, the cache goes to ``<repo>/.jax_cache`` — never a temp,
+    pid- or time-derived path."""
+    import pathlib
+
+    from repro.launch import compile_cache as cc
+
+    repo = pathlib.Path(__file__).resolve().parents[1]
+    assert cc.REPO_CACHE_DIR == repo / ".jax_cache"
+    before = jax.config.jax_compilation_cache_dir
+    if env_set:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        cache = cc.enable_compile_cache()
+        want = str(tmp_path) if env_set else str(cc.REPO_CACHE_DIR)
+        assert cache.directory == want
+        assert jax.config.jax_compilation_cache_dir == (
+            before if env_set else want)
+        assert (cache.hits, cache.writes) == (0, 0)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
