@@ -21,11 +21,14 @@ Phases (one process, one chip):
 4. tiled    — the same graph through the tiled representation: theta
               == the dense theta;
 5. wing     — a 192 x 128 graph (small enough for the sequential wing
-              oracle): psi == ``wing_bup_oracle``;
+              oracle): psi == ``wing_bup_oracle``.  The edge axis has no
+              Pallas kernel: this phase checks its jnp products at
+              HIGHEST precision on the chip;
 6. fleet    — ``Executor.map`` over 32 seeded 512 x 256 graphs;
 7. service  — ingest, three rounds of edge inserts/deletes, queries,
-              and a draining shutdown: the served result == a fresh
-              decomposition of the mutated graph.
+              and a draining shutdown: the served result ==
+              ``bup_oracle`` of the mutated graph == a fresh
+              decomposition of it.
 
 Every phase must run on a Pallas backend with no fallback.  The last line
 of stdout is ``{"ok": true, "device": {...}}``; any failed check raises
@@ -186,6 +189,8 @@ def phase_wing(seed: int, device) -> None:
     t_oracle = time.perf_counter() - t0
     ex = Executor(EngineConfig(workload="wing"))
     dec, wall = _timed_decompose(ex, g)
+    # the plan's Pallas backend is only a label here: the edge axis runs
+    # jnp products on every backend (ops.edge_support_all/_delta)
     check_backend("wing", dec.plan, dec.stats, ex)
     check(np.array_equal(dec.edge_wing, psi_ref),
           f"wing: psi differs from wing_bup_oracle at "
@@ -246,6 +251,7 @@ def _mutations(g, count: int, rng):
 
 def phase_service(g, seed: int, device) -> None:
     from repro.api import EngineConfig, Executor
+    from repro.core.peeling import bup_oracle
     from repro.service import DecompositionService, ServiceConfig
 
     cfg = EngineConfig(representation="dense")
@@ -285,15 +291,23 @@ def phase_service(g, seed: int, device) -> None:
     check(report["executors"]["tip"]["fallback_runs"] == 0,
           f"service: executor fallback runs {report['executors']}")
     final, mutated = cur, cur.graph
+    t1 = time.perf_counter()
+    theta_ref = bup_oracle(mutated)[0]
+    t_oracle = time.perf_counter() - t1
+    check(np.array_equal(final.theta, theta_ref),
+          f"service: served theta differs from bup_oracle of the mutated "
+          f"graph at {int(np.sum(final.theta != theta_ref))} vertices")
     ref_ex = Executor(cfg)
     ref = ref_ex.decompose(mutated)
     check_backend("service reference", ref.plan, ref.stats, ref_ex)
-    check(np.array_equal(final.theta, ref.theta),
-          f"service: served theta differs from a fresh decomposition at "
-          f"{int(np.sum(final.theta != ref.theta))} vertices")
+    check(np.array_equal(ref.theta, theta_ref),
+          f"service: a fresh decomposition of the mutated graph differs "
+          f"from bup_oracle at {int(np.sum(ref.theta != theta_ref))} "
+          f"vertices")
     log("service", m_final=mutated.m, edges_per_round=count,
         rounds=SERVICE_ROUNDS, refresh_modes=",".join(refresh_modes),
         ingest_wall_s=t_ingest, total_wall_s=t_serve,
+        host_oracle_s=t_oracle,
         worker_cycles=(report["worker"] or {}).get("cycles"),
         max_level=final.max_level(),
         peak_bytes_in_use=peak_bytes(device), exact=True)

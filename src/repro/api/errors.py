@@ -117,7 +117,8 @@ class GraphValidationError(ReceiptError, ValueError):
 
 class PlanInfeasibleError(ReceiptError, ValueError):
     """Admission control: the plan's padded-bytes estimate exceeds the
-    configured device-memory budget and cannot be degraded under it."""
+    configured device-memory budget and cannot be degraded under it, or
+    its compiled kernel's on-chip tables exceed the chip's SMEM/VMEM."""
 
 
 class KernelBackendError(ReceiptError, RuntimeError):

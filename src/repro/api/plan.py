@@ -31,6 +31,7 @@ import numpy as np
 
 from ..core.engine.peel_loop import ReceiptConfig, bucket
 from ..core.graph import BipartiteGraph
+from ..kernels import butterfly_tiled as ktiled
 from ..kernels import ops as kops
 from .config import EngineConfig
 from .errors import PlanInfeasibleError
@@ -343,7 +344,8 @@ class Planner:
         elif req_rep == "auto" and mesh_shards == 0 and (
                 (budget is not None and fixed_bytes > budget)
                 or (tiled_est["tile_occupancy"] <= TILED_OCCUPANCY_CROSSOVER
-                    and dense_cells >= TILED_MIN_DENSE_CELLS)):
+                    and dense_cells >= TILED_MIN_DENSE_CELLS
+                    and tiled_est["tiled_kernel_fits"])):
             representation = "tiled"
         else:
             representation = "dense"
@@ -356,6 +358,8 @@ class Planner:
             "n_tiles": tiled_est["n_tiles"],
             "tile_occupancy": tiled_est["tile_occupancy"],
             "tile_blocks": tiled_est["tile_blocks"],
+            "tiled_kernel_smem_bytes": tiled_est["tiled_kernel_smem_bytes"],
+            "tiled_kernel_vmem_bytes": tiled_est["tiled_kernel_vmem_bytes"],
             "occupancy_crossover": TILED_OCCUPANCY_CROSSOVER,
             "min_dense_cells": TILED_MIN_DENSE_CELLS,
         }
@@ -382,6 +386,18 @@ class Planner:
                     f"{tiled_est['tile_blocks'][1]} tiles), over the "
                     f"memory_budget_bytes={budget} admission budget — "
                     "raise the budget or shrink the graph/blocks",
+                    dispatch=cfg.cd_dispatch, backend=backend,
+                    padded_bytes=padded_bytes, budget=budget)
+            if not tiled_est["tiled_kernel_fits"]:
+                raise PlanInfeasibleError(
+                    f"the compiled tiled kernel's tables need "
+                    f"{tiled_est['tiled_kernel_smem_bytes']} bytes of SMEM "
+                    f"(limit {ktiled.SMEM_BYTES}) and "
+                    f"{tiled_est['tiled_kernel_vmem_bytes']} bytes of VMEM "
+                    f"(limit {ktiled.VMEM_BYTES}) for "
+                    f"{tiled_est['tiled_rows_pad']} rows and "
+                    f"{tiled_est['n_tiles']} tiles — backend='xla' runs "
+                    "the streaming tiled form with no on-chip tables",
                     dispatch=cfg.cd_dispatch, backend=backend,
                     padded_bytes=padded_bytes, budget=budget)
         elif budget is not None and padded_bytes > budget:
@@ -420,7 +436,8 @@ class Planner:
             padded_bytes, admitted_p, est_groups, est_waste = best
             if not found and padded_bytes > budget:
                 if (req_rep == "auto" and mesh_shards == 0
-                        and tiled_est["tiled_bytes"] <= budget):
+                        and tiled_est["tiled_bytes"] <= budget
+                        and tiled_est["tiled_kernel_fits"]):
                     # no dense partitioning fits — the tile list does
                     representation = "tiled"
                     padded_bytes = tiled_est["tiled_bytes"]
@@ -596,8 +613,15 @@ class Planner:
             n_tiles = n_rt                      # one filler slot per band
         tiled_bytes = 4 * (3 * n_tiles * br * bc + n_rt * n_ct
                            + 4 * rows_pad_t)
+        kernel_shape = (n_rt, n_ct, bucket(n_tiles, 8), br, bc)
+        smem, vmem = ktiled.tiled_kernel_bytes(*kernel_shape)
         return {
             "tiled_bytes": int(tiled_bytes),
+            "tiled_kernel_smem_bytes": smem,
+            "tiled_kernel_vmem_bytes": vmem,
+            # only the compiled kernel keeps its tables on the chip
+            "tiled_kernel_fits": (backend not in ("pallas", "pallas_sparse")
+                                  or ktiled.tiled_kernel_fits(*kernel_shape)),
             "n_tiles": n_tiles,
             "tile_occupancy": n_tiles / float(n_rt * n_ct),
             "tile_blocks": (br, bc),

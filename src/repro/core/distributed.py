@@ -50,10 +50,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..kernels.ref import EXACT
 from ..launch.mesh import dp_axes
 
-# the mesh FD local body is pure jnp (DESIGN.md §4): one program per
-# shard, no Pallas launch inside shard_map
-_LOCAL_BACKEND = "xla"
-
 
 def _specs(mesh: Mesh):
     dp = dp_axes(mesh)
@@ -464,7 +460,9 @@ def fd_level_shardmap(mesh: Mesh, *, max_sweeps: int = 100_000,
         (sup2, alive2, dv2, theta, rho, wedges, _max_level,
          _sweeps) = batched_level_loop(
             a, row_ext, sup, alive, dv, lo,
-            backend=_LOCAL_BACKEND, blocks=(8, 8, 8),
+            # pinned on purpose: the local body is pure jnp (DESIGN.md
+            # §4), one program per shard, no Pallas launch in shard_map
+            backend="xla", blocks=(8, 8, 8),
             peel_width=pw, max_sweeps=max_sweeps,
             update_mode=update_mode,
         )

@@ -58,7 +58,19 @@ from jax.experimental.pallas import tpu as pltpu
 # walking all n_rt band columns
 _PEEL_ROW_WIDTH = 16
 
+# on-chip memory of one TPU v5e core that the compiled kernel's tables
+# fill; both grow with the graph (``tiled_kernel_bytes``).  The reserves
+# cover the compiler's own use: compiles for a described v5e succeed at
+# the admitted edges (tests/test_tpu_compile.py) and failed 2% past the
+# VMEM edge and 12% past the SMEM one
+SMEM_BYTES = 1 << 20          # scalar memory: the prefetched index tables
+VMEM_BYTES = 16 << 20         # default scoped VMEM: output + tile buffers
+_SMEM_RESERVE = 16 << 10
+_VMEM_RESERVE = 256 << 10
+
 __all__ = [
+    "SMEM_BYTES",
+    "VMEM_BYTES",
     "butterfly_update_pallas_tiled",
     "butterfly_update_tiled_xla",
     "colsum_tiled",
@@ -66,7 +78,34 @@ __all__ = [
     "regather_tiles",
     "row_weights_tiled",
     "slot_liveness",
+    "tiled_kernel_bytes",
+    "tiled_kernel_fits",
 ]
+
+
+def tiled_kernel_bytes(n_rt: int, n_ct: int, n_slots: int, bi: int,
+                       bk: int) -> tuple:
+    """(SMEM, VMEM) bytes the compiled tiled kernel needs, reserves in.
+
+    SMEM holds the scalar-prefetched tables: ``pos`` (``n_rt * n_ct``
+    words), three per-slot tables and two per-band ones, each padded to
+    4 KiB.  VMEM holds the output, resident for the whole grid
+    (``n_rt * bi`` f32), the double-buffered A and B tiles, the wedge
+    accumulator and the ``s`` block.
+    """
+    def words(n: int) -> int:
+        return -(-n // 1024) * 1024
+    smem = 4 * (words(n_rt * n_ct) + 3 * words(n_slots)
+                + words(n_rt + 1) + words(n_rt) + words(1))
+    vmem = 4 * (n_rt * bi + 4 * bi * bk + bi * bi + 2 * bi)
+    return smem + _SMEM_RESERVE, vmem + _VMEM_RESERVE
+
+
+def tiled_kernel_fits(n_rt: int, n_ct: int, n_slots: int, bi: int,
+                      bk: int) -> bool:
+    """Whether the compiled tiled kernel fits one chip's SMEM and VMEM."""
+    smem, vmem = tiled_kernel_bytes(n_rt, n_ct, n_slots, bi, bk)
+    return smem <= SMEM_BYTES and vmem <= VMEM_BYTES
 
 
 # --------------------------------------------------------------------- #
@@ -165,7 +204,7 @@ def _tiled_update_kernel(
     srow_ref,     # scalar prefetch: (n_slots,) int32 slot -> row band
     scol_ref,     # scalar prefetch: (n_slots,) int32 slot -> col band
     sptr_ref,     # scalar prefetch: (n_rt + 1,) int32 band boundaries
-    pos_ref,      # scalar prefetch: (n_rt, n_ct) int32 reverse map
+    pos_ref,      # scalar prefetch: (n_rt * n_ct,) int32 reverse map
     live_ref,     # scalar prefetch: (n_slots,) int32 slot liveness
     jlist_ref,    # scalar prefetch: (n_rt,) int32 B bands carrying s mass
     nact_ref,     # scalar prefetch: (1,) int32 number of such bands
@@ -173,6 +212,7 @@ def _tiled_update_kernel(
     out_ref, w_acc_ref,
     *,
     block_rows: int,
+    n_col_tiles: int,
 ):
     jj, t = pl.program_id(0), pl.program_id(1)
     j = jlist_ref[jj]
@@ -197,7 +237,7 @@ def _tiled_update_kernel(
     # live and the mirrored B tile exists and is live (dead slots were
     # zeroed by regather_tiles, so every skip is provably a zero
     # contribution)
-    bslot = pos_ref[j, scol_ref[t]]
+    bslot = pos_ref[j * n_col_tiles + scol_ref[t]]
     live = jnp.logical_and(
         jnp.logical_and(bslot >= 0, live_ref[t] > 0),
         jnp.logical_and(live_ref[jnp.maximum(bslot, 0)] > 0, active))
@@ -241,33 +281,31 @@ def butterfly_update_pallas_tiled(
     ``s`` = alive mask -> per-vertex butterfly counting; ``s`` = peel
     mask -> the level-peel support delta.  Only the B row bands that
     carry ``s`` mass are visited: the grid's outer extent is their count
-    (a dynamic grid bound on the chip; the interpreter runs all
-    ``n_row_tiles`` and masks the rest), so a peel sweep touching a few
-    bands costs ``O(bands * n_slots)`` tile pairs instead of the dense
-    kernel's ``O(n_i * n_j * n_k)`` grid.
+    (a dynamic grid bound), so a peel sweep touching a few bands costs
+    ``O(bands * n_slots)`` tile pairs instead of the dense kernel's
+    ``O(n_i * n_j * n_k)`` grid.  Compiled, it needs
+    ``tiled_kernel_fits``; the Planner refuses a tiled plan that does
+    not.
     """
     n_slots, bi, bk = tile_data.shape
-    n_rt, _n_ct = pos.shape
+    n_rt, n_ct = pos.shape
     n_rows = n_rt * bi
     has = (s.reshape(n_rt, bi) != 0).any(axis=1)
     n_act = jnp.sum(has).astype(jnp.int32)
-    order = jnp.argsort(~has).astype(jnp.int32)       # active bands first
-    # padding entries repeat the last active band: consecutive steps then
-    # keep the same block indices, so masked steps start no new DMA
-    jlist = jnp.where(jnp.arange(n_rt) < n_act, order,
-                      order[jnp.maximum(n_act - 1, 0)])
-    n_outer = n_rt if interpret else jnp.maximum(n_act, 1)
-    kernel = functools.partial(_tiled_update_kernel, block_rows=bi)
+    # active bands first; with none the single grid row is masked
+    jlist = jnp.argsort(~has).astype(jnp.int32)
+    kernel = functools.partial(_tiled_update_kernel, block_rows=bi,
+                               n_col_tiles=n_ct)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=7,
-        grid=(n_outer, n_slots),
+        grid=(jnp.maximum(n_act, 1), n_slots),
         in_specs=[
             pl.BlockSpec((1, bi, bk),
                          lambda jj, t, sr, sc, sp, po, lv, jl, na: (t, 0, 0)),
             pl.BlockSpec(
                 (1, bi, bk),
                 lambda jj, t, sr, sc, sp, po, lv, jl, na:
-                    (jnp.maximum(po[jl[jj], sc[t]], 0), 0, 0)),
+                    (jnp.maximum(po[jl[jj] * n_ct + sc[t]], 0), 0, 0)),
             pl.BlockSpec((1, bi),
                          lambda jj, t, sr, sc, sp, po, lv, jl, na:
                              (0, jl[jj])),
@@ -287,7 +325,7 @@ def butterfly_update_pallas_tiled(
         srow.astype(jnp.int32),
         scol.astype(jnp.int32),
         sptr.astype(jnp.int32),
-        pos.astype(jnp.int32),
+        pos.reshape(-1).astype(jnp.int32),
         slot_live.astype(jnp.int32),
         jlist,
         n_act.reshape(1),

@@ -16,7 +16,7 @@ import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, st
 
-from repro.api import EngineConfig, Planner, decompose
+from repro.api import EngineConfig, Planner, PlanInfeasibleError, decompose
 from repro.core.engine.tiled import build_tiled, tiled_blocks
 from repro.core.graph import (
     BipartiteGraph,
@@ -336,6 +336,32 @@ class TestRepresentationRouting:
         g = random_bipartite(50, 30, 0.15, seed=3)
         plan = Planner(EngineConfig(representation="tiled")).plan(g)
         assert plan.representation == "tiled"
+
+    @pytest.mark.parametrize("backend,representation,expect", [
+        ("pallas", "tiled", "refused"),
+        ("pallas_sparse", "tiled", "refused"),
+        ("pallas", "auto", "dense"),
+        ("interpret", "tiled", "tiled"),
+        ("xla", "tiled", "tiled"),
+    ])
+    def test_tiled_kernel_past_chip_tables(self, backend, representation,
+                                           expect):
+        # 1024 row bands x 256 column bands of 8x8 tiles: the compiled
+        # kernel's reverse map alone is 1 MiB of SMEM, past one v5e core;
+        # the interpreter and the streaming xla form keep no such table
+        n_u, n_v = 8192, 2048
+        v = np.arange(n_v)
+        g = BipartiteGraph.from_edges(n_u, n_v, np.concatenate([v, v + n_v]),
+                                      np.concatenate([v, v]))
+        cfg = EngineConfig(representation=representation, backend=backend,
+                           num_partitions=3, kernel_blocks=SMALL_BLOCKS)
+        if expect == "refused":
+            with pytest.raises(PlanInfeasibleError, match="SMEM"):
+                Planner(cfg).plan(g)
+            return
+        plan = Planner(cfg).plan(g)
+        assert plan.representation == expect
+        assert plan.cost_model["tiled_kernel_smem_bytes"] > ktiled.SMEM_BYTES
 
     def test_plan_dict_exposes_cost_model(self):
         g = random_bipartite(50, 30, 0.15, seed=3)

@@ -99,3 +99,42 @@ def test_tiled_update_compiles(one_chip):
              ((N_SLOTS, BLOCKS[0], BLOCKS[2]), F32), ((N_SLOTS,), I32),
              ((N_SLOTS,), I32), ((n_rt + 1,), I32), ((n_rt, N_CT), I32),
              ((N_SLOTS,), I32), ((N_U,), F32))
+
+
+def _largest(fits, lo):
+    """The largest multiple of 8 at or above ``lo`` that ``fits`` admits."""
+    hi = lo
+    while fits(hi * 2):
+        hi *= 2
+    step = hi
+    while step >= 8:
+        if fits(hi + step):
+            hi += step
+        step //= 2
+    return hi
+
+
+@pytest.mark.parametrize("edge", ["smem", "vmem"])
+def test_tiled_update_compiles_at_chip_limits(one_chip, edge):
+    # the largest tables the Planner admits for the compiled tiled kernel
+    # (butterfly_tiled.tiled_kernel_fits) compile for one chip: SMEM
+    # binds the reverse map and slot tables, VMEM the resident output
+    bi, bk = BLOCKS[0], BLOCKS[2]
+
+    def fits(n_rt, n_ct, n_slots):
+        return butterfly_tiled.tiled_kernel_fits(n_rt, n_ct, n_slots, bi, bk)
+
+    if edge == "smem":
+        n_rt, n_ct = 8192, N_CT
+        n_slots = _largest(lambda n: fits(n_rt, n_ct, n), n_rt)
+        assert not fits(n_rt, n_ct, n_slots + 1024)
+    else:
+        n_ct = 1
+        n_rt = n_slots = _largest(lambda n: fits(n, n_ct, n), 8)
+        assert not fits(n_rt + 8, n_ct, n_slots)
+    _compile(lambda td, sr, sc, sp, po, sl, s:
+             butterfly_tiled.butterfly_update_pallas_tiled(
+                 td, sr, sc, sp, po, sl, s), one_chip,
+             ((n_slots, bi, bk), F32), ((n_slots,), I32), ((n_slots,), I32),
+             ((n_rt + 1,), I32), ((n_rt, n_ct), I32), ((n_slots,), I32),
+             ((n_rt * bi,), F32))
