@@ -53,6 +53,7 @@ from ..core.scheduler import lpt_assign, pack_by_shape
 from ..kernels import ops as kops
 from ..kernels.butterfly_sparse import batched_row_extents
 from ..train.fault_tolerance import StragglerMonitor
+from ..utils.spans import span
 from . import faults
 from .errors import (
     FleetPartialFailure,
@@ -314,6 +315,8 @@ class Executor:
         self._injector = faults.FaultInjector(spec) if spec else None
         self._stragglers = StragglerMonitor()
         self._fallback_runs = 0
+        self._runs = 0                  # decompose/repeel calls: the ``run``
+        #                               # of their receipt.* spans
         self._admitted_partitions = self.config.num_partitions
         self._plan_representation = "dense"
 
@@ -346,7 +349,8 @@ class Executor:
         return self._injector.report() if self._injector else []
 
     def plan(self, graph: BipartiteGraph) -> ExecutionPlan:
-        return self._planner.plan(graph, mesh=self.mesh)
+        with span("plan"):
+            return self._planner.plan(graph, mesh=self.mesh)
 
     def _fault_scope(self):
         """Activate this executor's injector (env-armed faults apply
@@ -383,27 +387,29 @@ class Executor:
                 "workload='wing' runs single-device; the sharded FD "
                 "driver is a vertex-axis path (ROADMAP deferred item). "
                 "Build the executor without a mesh.")
-        if plan is None:
-            plan = self.plan(graph)
-        entry = self._seed(plan)
-        theta, stats = self._execute(graph, plan, entry)
-        self._absorb(plan, entry)
-        if self.workload == "wing":
+        self._runs += 1
+        with span("decompose", run=self._runs):
+            if plan is None:
+                plan = self.plan(graph)
+            entry = self._seed(plan)
+            theta, stats = self._execute(graph, plan, entry)
+            self._absorb(plan, entry)
+            if self.workload == "wing":
+                if verify:
+                    stats.verify_checks = verify_wing_decomposition(
+                        graph, theta, bounds=stats.bounds,
+                        plan_signature=plan.signature)
+                    stats.verified = True
+                return WingDecomposition(graph=graph, side=self.side,
+                                         edge_wing=theta, stats=stats,
+                                         plan=plan)
             if verify:
-                stats.verify_checks = verify_wing_decomposition(
-                    graph, theta, bounds=stats.bounds,
+                stats.verify_checks = verify_tip_decomposition(
+                    graph, self.side, theta, bounds=stats.bounds,
                     plan_signature=plan.signature)
                 stats.verified = True
-            return WingDecomposition(graph=graph, side=self.side,
-                                     edge_wing=theta, stats=stats,
-                                     plan=plan)
-        if verify:
-            stats.verify_checks = verify_tip_decomposition(
-                graph, self.side, theta, bounds=stats.bounds,
-                plan_signature=plan.signature)
-            stats.verified = True
-        return TipDecomposition(graph=graph, side=self.side, theta=theta,
-                                stats=stats, plan=plan)
+            return TipDecomposition(graph=graph, side=self.side,
+                                    theta=theta, stats=stats, plan=plan)
 
     # ------------------------------------------------------------------ #
     # incremental re-peel (serving layer, DESIGN.md §11)
@@ -438,31 +444,33 @@ class Executor:
         """
         from ..core.engine import repeel_tip_prefix, repeel_wing_prefix
 
-        if plan is None:
-            plan = self.plan(graph)
-        if plan.representation == "tiled":
-            raise PlanInfeasibleError(
-                "incremental re-peel runs on the dense geometry; this "
-                "plan routed to the tiled representation — refresh by "
-                "full recompute instead", plan_signature=plan.signature,
-                dispatch="repeel")
-        entry = self._seed(plan)
-        rcfg = self._run_cfg(plan.backend)
-        if self.workload == "tip" and self.side == "V":
-            graph = graph.transposed()
-        stats = RunStats()
-        stats.refresh_mode = "delta"
-        with self._fault_scope():
-            if self.workload == "wing":
-                numbers, _stop = repeel_wing_prefix(
-                    graph, sup0, numbers_old, stops, watch, rcfg, stats,
-                    plan=plan)
-            else:
-                numbers, _stop = repeel_tip_prefix(
-                    graph, sup0, numbers_old, stops, watch, rcfg, stats,
-                    plan=plan)
-        stats.backend_used = plan.backend
-        self._absorb(plan, entry)
+        self._runs += 1
+        with span("repeel", run=self._runs):
+            if plan is None:
+                plan = self.plan(graph)
+            if plan.representation == "tiled":
+                raise PlanInfeasibleError(
+                    "incremental re-peel runs on the dense geometry; this "
+                    "plan routed to the tiled representation — refresh by "
+                    "full recompute instead", plan_signature=plan.signature,
+                    dispatch="repeel")
+            entry = self._seed(plan)
+            rcfg = self._run_cfg(plan.backend)
+            if self.workload == "tip" and self.side == "V":
+                graph = graph.transposed()
+            stats = RunStats()
+            stats.refresh_mode = "delta"
+            with self._fault_scope():
+                if self.workload == "wing":
+                    numbers, _stop = repeel_wing_prefix(
+                        graph, sup0, numbers_old, stops, watch, rcfg, stats,
+                        plan=plan)
+                else:
+                    numbers, _stop = repeel_tip_prefix(
+                        graph, sup0, numbers_old, stops, watch, rcfg, stats,
+                        plan=plan)
+            stats.backend_used = plan.backend
+            self._absorb(plan, entry)
         return numbers, stats
 
     def _run_cfg(self, backend: str) -> ReceiptConfig:
@@ -533,11 +541,12 @@ class Executor:
                     plan: ExecutionPlan):
         """One engine invocation of the plan's workload (the fallback
         chain retries this per backend)."""
-        if self.workload == "wing":
-            return _engine_wing_decompose(graph, cfg, side=self.side,
-                                          plan=plan)
-        return _engine_tip_decompose(graph, cfg, side=self.side,
-                                     mesh=self.mesh, plan=plan)
+        with span("engine", backend=kops.resolve_backend(cfg.backend)):
+            if self.workload == "wing":
+                return _engine_wing_decompose(graph, cfg, side=self.side,
+                                              plan=plan)
+            return _engine_tip_decompose(graph, cfg, side=self.side,
+                                         mesh=self.mesh, plan=plan)
 
     def _seed(self, plan: ExecutionPlan) -> _CacheEntry:
         entry = self._entries.get(plan.signature)

@@ -8,14 +8,13 @@ number of synchronization rounds, which is the paper's argument.
 """
 from __future__ import annotations
 
-import time
 from typing import Optional, Tuple
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ...kernels import ops as kops
+from ...utils.spans import fetch, span
 from ..graph import BipartiteGraph
 from .peel_loop import (
     _INF,
@@ -62,73 +61,73 @@ def parb_tip_decompose(
     dv = dg.dv0
 
     theta = np.zeros(g.n_u, np.int64)
-    t0 = time.perf_counter()
-    if cfg.device_loop:
-        theta_dev = jnp.zeros(dg.rows_pad, jnp.float32)
-        # min-support sets are small (ParB's whole problem is that there
-        # are MANY of them): start at one kernel tile and let the
-        # overflow path double on demand
-        peel_width = min(dg.rows_pad, bucket(
-            cfg.peel_width if cfg.peel_width is not None else blocks[1],
-            blocks[1],
-        ))
-        while True:
-            (support, alive, dv, theta_dev, peeled, d_rho, d_wedges, _h,
-             d_elided, _c, _s, ovf) = device_peel_loop(
-                dg.a, dg.ids, dg.row_ext, dg.kmax, support, alive, dv,
-                theta_dev, 0.0, 0.0, 0.0,
-                backend=backend, blocks=blocks, use_huc=False,
-                peel_width=peel_width, max_sweeps=cfg.max_sweeps,
-                minmode=True,
-            )
-            stats.device_loop_calls += 1
-            (peeled_np, alive_np, th_np, d_rho, d_wedges, d_elided,
-             ovf_h) = jax.device_get(
-                (peeled, alive, theta_dev, d_rho, d_wedges, d_elided, ovf))
-            stats.host_round_trips += 1
-            stats.rho_cd += int(d_rho)
-            stats.wedges_cd += int(d_wedges)
-            stats.elided_sweeps += int(d_elided)
-            sel = peeled_np[: dg.n_rows].nonzero()[0]
-            theta[dg.members[sel]] = np.round(th_np[: dg.n_rows][sel]).astype(
-                np.int64)
-            if not bool(ovf_h):
-                if not alive_np.any():
+    with span("parb") as sp:
+        if cfg.device_loop:
+            theta_dev = jnp.zeros(dg.rows_pad, jnp.float32)
+            # min-support sets are small (ParB's whole problem is that
+            # there are MANY of them): start at one kernel tile and let
+            # the overflow path double on demand
+            peel_width = min(dg.rows_pad, bucket(
+                cfg.peel_width if cfg.peel_width is not None else blocks[1],
+                blocks[1],
+            ))
+            while True:
+                (support, alive, dv, theta_dev, peeled, d_rho, d_wedges, _h,
+                 d_elided, _c, _s, ovf) = device_peel_loop(
+                    dg.a, dg.ids, dg.row_ext, dg.kmax, support, alive, dv,
+                    theta_dev, 0.0, 0.0, 0.0,
+                    backend=backend, blocks=blocks, use_huc=False,
+                    peel_width=peel_width, max_sweeps=cfg.max_sweeps,
+                    minmode=True,
+                )
+                stats.device_loop_calls += 1
+                (peeled_np, alive_np, th_np, d_rho, d_wedges, d_elided,
+                 ovf_h) = fetch(
+                    stats, (peeled, alive, theta_dev, d_rho, d_wedges,
+                            d_elided, ovf), "parb.loop")
+                stats.rho_cd += int(d_rho)
+                stats.wedges_cd += int(d_wedges)
+                stats.elided_sweeps += int(d_elided)
+                sel = peeled_np[: dg.n_rows].nonzero()[0]
+                theta[dg.members[sel]] = np.round(
+                    th_np[: dg.n_rows][sel]).astype(np.int64)
+                if not bool(ovf_h):
+                    if not alive_np.any():
+                        break
+                    # max_sweeps cap-exit with survivors left (the host
+                    # schedule has no cap): re-enter — the loop reseeds its
+                    # sweep counter.  d_rho == 0 means no progress is
+                    # possible (max_sweeps <= 0): bail instead of spinning.
+                    if int(d_rho) == 0:
+                        break
+                    continue
+                # overflow: replay the min-sweep on the host, widen, re-enter
+                stats.overflow_fallbacks += 1
+                sup_np = np.asarray(fetch(stats, support, "parb.replay"),
+                                    np.float64)
+                mn = float(np.min(np.where(alive_np, sup_np, np.inf)))
+                support, alive, info = host_sweep(
+                    dg, cfg, stats, support, alive, mn + 1.0, mn, backend,
+                    blocks, allow_huc=False)
+                if info is not None:
+                    sel = info["peel_np"][: dg.n_rows].nonzero()[0]
+                    theta[dg.members[sel]] = int(mn)
+                dv = residual_dv(dg.a, alive)
+                peel_width = min(dg.rows_pad, peel_width * 2)
+        else:
+            while True:
+                n_alive = int(fetch(stats, jnp.sum(alive), "parb.alive"))
+                if n_alive == 0:
                     break
-                # max_sweeps cap-exit with survivors left (the host
-                # schedule has no cap): re-enter — the loop reseeds its
-                # sweep counter.  d_rho == 0 means no progress is
-                # possible (max_sweeps <= 0): bail instead of spinning.
-                if int(d_rho) == 0:
+                mn = float(fetch(
+                    stats, jnp.min(jnp.where(alive, support, _INF)),
+                    "parb.min"))
+                support, alive, info = host_sweep(
+                    dg, cfg, stats, support, alive, mn + 1.0, mn, backend,
+                    blocks, allow_huc=False)
+                if info is None:
                     break
-                continue
-            # overflow: replay the min-sweep on the host, widen, re-enter
-            stats.overflow_fallbacks += 1
-            sup_np = np.asarray(support, np.float64)
-            stats.host_round_trips += 1
-            mn = float(np.min(np.where(alive_np, sup_np, np.inf)))
-            support, alive, info = host_sweep(
-                dg, cfg, stats, support, alive, mn + 1.0, mn, backend,
-                blocks, allow_huc=False)
-            if info is not None:
                 sel = info["peel_np"][: dg.n_rows].nonzero()[0]
                 theta[dg.members[sel]] = int(mn)
-            dv = residual_dv(dg.a, alive)
-            peel_width = min(dg.rows_pad, peel_width * 2)
-    else:
-        while True:
-            n_alive = int(jnp.sum(alive))
-            stats.host_round_trips += 1
-            if n_alive == 0:
-                break
-            mn = float(jnp.min(jnp.where(alive, support, _INF)))
-            stats.host_round_trips += 1
-            support, alive, info = host_sweep(
-                dg, cfg, stats, support, alive, mn + 1.0, mn, backend,
-                blocks, allow_huc=False)
-            if info is None:
-                break
-            sel = info["peel_np"][: dg.n_rows].nonzero()[0]
-            theta[dg.members[sel]] = int(mn)
-    stats.time_cd = time.perf_counter() - t0
+    stats.time_cd = sp.seconds
     return theta, stats

@@ -20,16 +20,15 @@ mode.  Two dispatch granularities (``cfg.cd_dispatch``, DESIGN.md
 """
 from __future__ import annotations
 
-import time
 from typing import Tuple
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ...api.errors import KernelBackendError, PeelOverflowError
 from ...api.faults import fault_point
 from ...kernels import ops as kops
+from ...utils.spans import fetch, span
 from ..graph import BipartiteGraph
 from .peel_loop import (
     _INF,
@@ -148,13 +147,27 @@ def receipt_cd(
             raise ValueError(
                 "CD checkpointing captures subset-boundary state on the "
                 "host; use cd_dispatch='subset'")
-        return _receipt_cd_graph(g, cfg, stats, plan=plan)
+    with span("cd", dispatch=cfg.cd_dispatch) as sp:
+        if cfg.cd_dispatch == "graph":
+            out = _receipt_cd_graph(g, cfg, stats, plan=plan)
+        else:
+            out = _receipt_cd_subset(g, cfg, stats, plan=plan,
+                                     checkpoint_cb=checkpoint_cb,
+                                     resume_state=resume_state)
+    stats.time_cd = sp.seconds - stats.time_count
+    return out
+
+
+def _receipt_cd_subset(g: BipartiteGraph, cfg: ReceiptConfig,
+                       stats: RunStats, *, plan, checkpoint_cb,
+                       resume_state):
+    """Subset-dispatch CD: one device loop (and one blocking fetch) per
+    subset, findHi and DGM on the host (``receipt_cd``'s contract)."""
     backend = cfg.backend or kops.default_backend()
     blocks = cfg.kernel_blocks
     n_u = g.n_u
     p_total = cfg.num_partitions
 
-    t0 = time.perf_counter()
     if resume_state is not None:
         st = resume_state
         subset_id = np.asarray(st["subset_id"]).copy()
@@ -169,9 +182,8 @@ def receipt_cd(
             jnp.asarray(st["support"][: dg.n_rows], cfg.dtype)
         )
         dv = dg.dv0
-        sup_np = np.asarray(support, np.float64)
-        alive_np = np.asarray(alive)
-        stats.host_round_trips += 1
+        sup_np, alive_np = fetch(stats, (support, alive), "cd.resume")
+        sup_np = np.asarray(sup_np, np.float64)
         rem_wedges = float(st["rem_wedges"])
         scale = float(st["scale"])
         lo = float(st["lo"])
@@ -185,21 +197,20 @@ def receipt_cd(
         stats.wedges_pvbcnt = g.counting_wedge_bound()
 
         # --- initial per-vertex counting (pvBcnt) ---------------------- #
-        sparse = backend in kops.SPARSE_BACKENDS
-        alive = jnp.zeros(dg.rows_pad, bool).at[: dg.n_rows].set(True)
-        fault_point("kernel_launch", KernelBackendError,
-                    dispatch="subset", backend=backend, phase="count")
-        support = support_all(dg.a, alive, dg.ids,
-                              dg.kmax if sparse else None,
-                              backend=backend, blocks=blocks)
-        support = jnp.where(alive, support, _INF)
-        dv = dg.dv0
-        sup_np = np.asarray(support, np.float64)   # the blocking sync
-        alive_np = np.asarray(alive)
-        stats.host_round_trips += 1
-        stats.time_count = time.perf_counter() - t0
+        with span("cd.count") as sp:
+            sparse = backend in kops.SPARSE_BACKENDS
+            alive = jnp.zeros(dg.rows_pad, bool).at[: dg.n_rows].set(True)
+            fault_point("kernel_launch", KernelBackendError,
+                        dispatch="subset", backend=backend, phase="count")
+            support = support_all(dg.a, alive, dg.ids,
+                                  dg.kmax if sparse else None,
+                                  backend=backend, blocks=blocks)
+            support = jnp.where(alive, support, _INF)
+            dv = dg.dv0
+            sup_np, alive_np = fetch(stats, (support, alive), "cd.count")
+            sup_np = np.asarray(sup_np, np.float64)
+        stats.time_count = sp.seconds
 
-        t0 = time.perf_counter()
         rem_wedges = dg.total_wedges
         scale = 1.0
         lo = 0.0
@@ -215,128 +226,137 @@ def receipt_cd(
                          max(peel_width, bucket(width_hint, blocks[1])))
     width_max = peel_width
     while alive_np.any():
-        if checkpoint_cb is not None:
-            live = np.where(alive_np)[0]
-            checkpoint_cb(cd_checkpoint_state(
-                subset_id, init_support, bounds, dg.members[live],
-                sup_np[live], rem_wedges, scale, lo, i,
-            ))
-        # final catch-all subset (paper: "puts all of them in U_{P+1}")
-        catch_all = i >= p_total - 1
-        tgt = np.inf if catch_all else max(rem_wedges / (p_total - i) * scale, 1.0)
-
-        # support snapshot -> FD init vector (Alg. 3 lines 6-7)
-        live_rows = np.where(alive_np)[0]
-        init_support[dg.members[live_rows]] = sup_np[live_rows]
-
-        if catch_all:
-            hi = float(np.max(np.where(alive_np, sup_np, -np.inf))) + 1.0
-        else:
-            hi = find_hi_np(sup_np, dg.w_np, alive_np, tgt)
-
-        sweeps = 0
-        covered_wedges = 0.0
-        if cfg.device_loop:
-            # -------- device-resident sweep loop (O(1) syncs) ---------- #
-            # the subset's FIRST sweep peels the whole initial range; its
-            # size is already known from the host snapshot, so size the
-            # peel buffer to fit it and overflow only on larger cascades
-            # (an explicit cfg.peel_width — or a plan's measured width,
-            # which must stay data-independent to keep the trace cache
-            # hitting — pins the initial width instead)
-            if cfg.peel_width is None and width_hint is None:
-                n_first = int((alive_np & (sup_np < hi)).sum())
-                peel_width = max(peel_width, min(
-                    dg.rows_pad,
-                    bucket(max(n_first, blocks[1]), blocks[1]),
+        with span("cd.subset", i=i):
+            if checkpoint_cb is not None:
+                live = np.where(alive_np)[0]
+                checkpoint_cb(cd_checkpoint_state(
+                    subset_id, init_support, bounds, dg.members[live],
+                    sup_np[live], rem_wedges, scale, lo, i,
                 ))
-            if fault_point("peel_buffer", dispatch="subset", subset=i,
-                           backend=backend):
-                # injected sizing fault: undersize the buffer to the
-                # smallest width the backend accepts (one row on xla,
-                # one block tile on the kernel routes) so the overflow
-                # replay path is forced on any larger sweep (degrade-
-                # style point — results stay exact through the replay +
-                # retry-with-widening)
-                peel_width = 1 if backend == "xla" else blocks[1]
-            replays = 0
-            while True:
-                fault_point("kernel_launch", KernelBackendError,
-                            dispatch="subset", subset=i, backend=backend)
-                (support, alive, dv, _th, peeled, d_rho, d_wedges, d_hucs,
-                 d_elided, d_covered, _d_sweeps, ovf) = device_peel_loop(
-                    dg.a, dg.ids, dg.row_ext, dg.kmax, support, alive, dv,
-                    jnp.zeros(dg.rows_pad, jnp.float32), hi, lo, dg.c_rcnt,
-                    0,
-                    backend=backend, blocks=blocks, use_huc=cfg.use_huc,
-                    peel_width=peel_width, max_sweeps=cfg.max_sweeps,
-                    minmode=False,
-                )
-                stats.device_loop_calls += 1
-                (peeled_np, alive_np, sup_f32, d_rho, d_wedges, d_hucs,
-                 d_elided, d_covered, ovf_h) = jax.device_get(
-                    (peeled, alive, support, d_rho, d_wedges, d_hucs,
-                     d_elided, d_covered, ovf))
-                stats.host_round_trips += 1
-                sup_np = np.asarray(sup_f32, np.float64)
-                stats.rho_cd += int(d_rho)
-                stats.wedges_cd += int(d_wedges)
-                stats.huc_recounts += int(d_hucs)
-                stats.elided_sweeps += int(d_elided)
-                sweeps += int(d_rho)
-                covered_wedges += float(d_covered)
-                subset_id[dg.members[np.where(peeled_np)[0]]] = i
-                if bool(ovf_h):
-                    # peel buffer overflow: replay this one sweep on the
-                    # host at the precise bucket, re-enter with a wider
-                    # buffer (bounded retry-with-widening, DESIGN.md §7)
-                    replays += 1
-                    if replays > _MAX_OVERFLOW_REPLAYS:
-                        raise PeelOverflowError(
-                            f"peel-buffer overflow replay made no progress "
-                            f"after {_MAX_OVERFLOW_REPLAYS} widenings "
-                            f"(width={peel_width}, rows_pad={dg.rows_pad})",
-                            dispatch="subset", subset=i, backend=backend,
-                            peel_width=peel_width, rows_pad=dg.rows_pad)
-                    stats.overflow_fallbacks += 1
+            # final catch-all subset (paper: "puts all of them in U_{P+1}")
+            catch_all = i >= p_total - 1
+            tgt = (np.inf if catch_all
+                   else max(rem_wedges / (p_total - i) * scale, 1.0))
+
+            # support snapshot -> FD init vector (Alg. 3 lines 6-7)
+            live_rows = np.where(alive_np)[0]
+            init_support[dg.members[live_rows]] = sup_np[live_rows]
+
+            if catch_all:
+                hi = float(np.max(np.where(alive_np, sup_np, -np.inf)))
+                hi += 1.0
+            else:
+                hi = find_hi_np(sup_np, dg.w_np, alive_np, tgt)
+
+            sweeps = 0
+            covered_wedges = 0.0
+            if cfg.device_loop:
+                # -------- device-resident sweep loop (O(1) syncs) ------ #
+                # the subset's FIRST sweep peels the whole initial range; its
+                # size is already known from the host snapshot, so size the
+                # peel buffer to fit it and overflow only on larger cascades
+                # (an explicit cfg.peel_width — or a plan's measured width,
+                # which must stay data-independent to keep the trace cache
+                # hitting — pins the initial width instead)
+                if cfg.peel_width is None and width_hint is None:
+                    n_first = int((alive_np & (sup_np < hi)).sum())
+                    peel_width = max(peel_width, min(
+                        dg.rows_pad,
+                        bucket(max(n_first, blocks[1]), blocks[1]),
+                    ))
+                if fault_point("peel_buffer", dispatch="subset", subset=i,
+                               backend=backend):
+                    # injected sizing fault: undersize the buffer to the
+                    # smallest width the backend accepts (one row on xla,
+                    # one block tile on the kernel routes) so the overflow
+                    # replay path is forced on any larger sweep (degrade-
+                    # style point — results stay exact through the replay +
+                    # retry-with-widening)
+                    peel_width = 1 if backend == "xla" else blocks[1]
+                replays = 0
+                while True:
+                    fault_point("kernel_launch", KernelBackendError,
+                                dispatch="subset", subset=i,
+                                backend=backend)
+                    (support, alive, dv, _th, peeled, d_rho, d_wedges,
+                     d_hucs, d_elided, d_covered, _d_sweeps,
+                     ovf) = device_peel_loop(
+                        dg.a, dg.ids, dg.row_ext, dg.kmax, support, alive,
+                        dv, jnp.zeros(dg.rows_pad, jnp.float32), hi, lo,
+                        dg.c_rcnt, 0,
+                        backend=backend, blocks=blocks, use_huc=cfg.use_huc,
+                        peel_width=peel_width, max_sweeps=cfg.max_sweeps,
+                        minmode=False,
+                    )
+                    stats.device_loop_calls += 1
+                    (peeled_np, alive_np, sup_f32, d_rho, d_wedges, d_hucs,
+                     d_elided, d_covered, ovf_h) = fetch(
+                        stats, (peeled, alive, support, d_rho, d_wedges,
+                                d_hucs, d_elided, d_covered, ovf),
+                        "cd.subset")
+                    sup_np = np.asarray(sup_f32, np.float64)
+                    stats.rho_cd += int(d_rho)
+                    stats.wedges_cd += int(d_wedges)
+                    stats.huc_recounts += int(d_hucs)
+                    stats.elided_sweeps += int(d_elided)
+                    sweeps += int(d_rho)
+                    covered_wedges += float(d_covered)
+                    subset_id[dg.members[np.where(peeled_np)[0]]] = i
+                    if bool(ovf_h):
+                        # peel buffer overflow: replay this one sweep on
+                        # the host at the precise bucket, re-enter with a
+                        # wider buffer (bounded retry-with-widening,
+                        # DESIGN.md §7)
+                        replays += 1
+                        if replays > _MAX_OVERFLOW_REPLAYS:
+                            raise PeelOverflowError(
+                                f"peel-buffer overflow replay made no "
+                                f"progress after {_MAX_OVERFLOW_REPLAYS} "
+                                f"widenings (width={peel_width}, "
+                                f"rows_pad={dg.rows_pad})",
+                                dispatch="subset", subset=i, backend=backend,
+                                peel_width=peel_width, rows_pad=dg.rows_pad)
+                        stats.overflow_fallbacks += 1
+                        support, alive, info = host_sweep(
+                            dg, cfg, stats, support, alive, hi, lo, backend,
+                            blocks)
+                        if info is not None:
+                            covered_wedges += info["c_peel"]
+                            sweeps += 1
+                            rows = info["peel_np"].nonzero()[0]
+                            subset_id[dg.members[rows]] = i
+                        dv = residual_dv(dg.a, alive)
+                        sup_np, alive_np = fetch(stats, (support, alive),
+                                                 "cd.replay")
+                        sup_np = np.asarray(sup_np, np.float64)
+                        peel_width = min(dg.rows_pad, peel_width * 2)
+                        continue
+                    # max_sweeps valve: caps ONE invocation, never the
+                    # subset — a cap-exit with range left re-enters
+                    # (Theorem 1 needs [lo, hi) fully drained before the
+                    # bound is recorded)
+                    if not (alive_np & (sup_np < hi)).any():
+                        break
+                    if int(d_rho) == 0:
+                        raise RuntimeError(
+                            "CD device loop made no progress on a non-empty "
+                            "range (max_sweeps misconfigured?)")
+            else:
+                # -------- pre-PR engine: blocking host-driven sweeps --- #
+                # (no valve here: the host regains control at every sweep,
+                # and each sweep peels >= 1 row, so the loop terminates in
+                # <= n_rows sweeps — draining fully preserves Theorem 1)
+                while True:
                     support, alive, info = host_sweep(
                         dg, cfg, stats, support, alive, hi, lo, backend,
                         blocks)
-                    if info is not None:
-                        covered_wedges += info["c_peel"]
-                        sweeps += 1
-                        subset_id[dg.members[info["peel_np"].nonzero()[0]]] = i
-                    dv = residual_dv(dg.a, alive)
-                    sup_np = np.asarray(support, np.float64)
-                    alive_np = np.asarray(alive)
-                    stats.host_round_trips += 1
-                    peel_width = min(dg.rows_pad, peel_width * 2)
-                    continue
-                # max_sweeps valve: caps ONE invocation, never the subset
-                # — a cap-exit with range left re-enters (Theorem 1 needs
-                # [lo, hi) fully drained before the bound is recorded)
-                if not (alive_np & (sup_np < hi)).any():
-                    break
-                if int(d_rho) == 0:
-                    raise RuntimeError(
-                        "CD device loop made no progress on a non-empty "
-                        "range (max_sweeps misconfigured?)")
-        else:
-            # -------- pre-PR engine: blocking host-driven sweeps ------- #
-            # (no valve here: the host regains control at every sweep, and
-            # each sweep peels >= 1 row, so the loop terminates in
-            # <= n_rows sweeps — draining fully preserves Theorem 1)
-            while True:
-                support, alive, info = host_sweep(
-                    dg, cfg, stats, support, alive, hi, lo, backend, blocks)
-                if info is None:
-                    break
-                sweeps += 1
-                covered_wedges += info["c_peel"]
-                subset_id[dg.members[info["peel_np"].nonzero()[0]]] = i
-            sup_np = np.asarray(support, np.float64)
-            alive_np = np.asarray(alive)
-            stats.host_round_trips += 1
+                    if info is None:
+                        break
+                    sweeps += 1
+                    covered_wedges += info["c_peel"]
+                    subset_id[dg.members[info["peel_np"].nonzero()[0]]] = i
+                sup_np, alive_np = fetch(stats, (support, alive), "cd.host")
+                sup_np = np.asarray(sup_np, np.float64)
 
         stats.sweeps_per_subset.append(sweeps)
         bounds.append(hi)
@@ -376,7 +396,6 @@ def receipt_cd(
 
     stats.num_subsets = i
     stats.bounds = [float(b) for b in bounds]
-    stats.time_cd = time.perf_counter() - t0
     if plan is not None:
         plan.note_cd_peel_width(max(width_max, peel_width))
     # every vertex must be assigned
@@ -434,23 +453,22 @@ def _receipt_cd_graph(
     n_u = g.n_u
     p_total = cfg.num_partitions
 
-    t0 = time.perf_counter()
     subset_id = np.full(n_u, -1, np.int64)
     init_support = np.zeros(n_u, np.float64)
     dg = DeviceGraph(g, np.arange(n_u), cfg, plan=plan)
     stats.wedges_pvbcnt = g.counting_wedge_bound()
 
-    alive = jnp.zeros(dg.rows_pad, bool).at[: dg.n_rows].set(True)
-    fault_point("kernel_launch", KernelBackendError,
-                dispatch="graph", backend=backend, phase="count")
-    support = support_all(dg.a, alive, dg.ids,
-                          dg.kmax if sparse else None,
-                          backend=backend, blocks=blocks)
-    support = jnp.where(alive, support, _INF)
+    with span("cd.count") as sp:
+        alive = jnp.zeros(dg.rows_pad, bool).at[: dg.n_rows].set(True)
+        fault_point("kernel_launch", KernelBackendError,
+                    dispatch="graph", backend=backend, phase="count")
+        support = support_all(dg.a, alive, dg.ids,
+                              dg.kmax if sparse else None,
+                              backend=backend, blocks=blocks)
+        support = jnp.where(alive, support, _INF)
     # async dispatch: no blocking sync between counting and the CD loop
-    stats.time_count = time.perf_counter() - t0
+    stats.time_count = sp.seconds
 
-    t0 = time.perf_counter()
     peel_width = dg.initial_peel_width()
     width_hint = plan.cd_peel_width_hint() if plan is not None else None
     if width_hint is not None and cfg.peel_width is None:
@@ -468,9 +486,8 @@ def _receipt_cd_graph(
         # that peels EVERY survivor — the catch-all opener in particular
         # — takes the bufferless elide branch.  With p_total == 1 the
         # single catch-all sweep elides, so no sizing is needed at all.
-        sup_np = np.asarray(support, np.float64)
-        alive_np = np.asarray(alive)
-        stats.host_round_trips += 1
+        sup_np, alive_np = fetch(stats, (support, alive), "cd.size")
+        sup_np = np.asarray(sup_np, np.float64)
         tgt0 = max(dg.total_wedges / p_total, 1.0)
         hi0 = find_hi_np(sup_np, dg.w_np, alive_np, tgt0)
         n_first = int((alive_np & (sup_np < hi0)).sum())
@@ -495,8 +512,7 @@ def _receipt_cd_graph(
             max_iters=cfg.max_sweeps, p_total=p_total,
         )
         stats.device_loop_calls += 1
-        st = jax.device_get(state)                # THE blocking transfer
-        stats.host_round_trips += 1
+        st = fetch(stats, state, "cd.loop")       # THE blocking transfer
         if bool(st["done"]):
             break
         state = dict(state, iters=jnp.int32(0))   # fresh invocation budget
@@ -519,7 +535,8 @@ def _receipt_cd_graph(
         # permuted/compacted by the on-device DGM boundaries, so dg.a
         # would be stale), fold its effect into the carried state (the
         # replay's stats go through a scratch RunStats so the final
-        # device counters are added exactly once), re-enter wider
+        # device counters are added exactly once; its round trips are
+        # added here), re-enter wider
         stats.overflow_fallbacks += 1
         tmp = RunStats()
         i_cur = int(st["i"])
@@ -527,7 +544,7 @@ def _receipt_cd_graph(
         support2, alive2, info = host_sweep(
             gv, cfg, tmp, state["support"], state["alive"],
             float(st["hi"]), float(st["lo"]), backend, blocks)
-        stats.host_round_trips += tmp.host_round_trips + 1
+        stats.host_round_trips += tmp.host_round_trips
         state["support"] = support2
         state["alive"] = alive2
         state["dv"] = residual_dv(state["a"], alive2)
@@ -560,7 +577,6 @@ def _receipt_cd_graph(
         int(x) for x in np.asarray(st["rho_sub"])[:num_subsets])
     stats.num_subsets = num_subsets
     stats.bounds = [float(b) for b in bounds]
-    stats.time_cd = time.perf_counter() - t0
     if plan is not None:
         plan.note_cd_peel_width(peel_width)
     assert (subset_id >= 0).all(), "CD left unassigned vertices"

@@ -19,7 +19,7 @@ Runtime structure (``fd_mode="level"``, the default — DESIGN.md §2.2):
   device stacks hold the SURVIVORS of all hoisted levels (the catch-all
   subset typically shrinks severalfold); the last hoisted level's delta
   reaches the survivors through one grouped butterfly kernel call;
-* **one device dispatch + one blocking ``device_get`` per shape group**
+* **one device dispatch + one blocking ``fetch`` per shape group**
   (theta, per-subset sweep counts rho and dynamic wedge counters all ride
   back in the same transfer); a ``max_sweeps`` cap-exit re-enters with
   the carried state (the valve bounds one invocation, never the
@@ -61,7 +61,6 @@ for benchmarks/bench_receipt.py.
 """
 from __future__ import annotations
 
-import time
 from typing import Dict, List
 
 import jax
@@ -72,6 +71,7 @@ from ...api.errors import KernelBackendError
 from ...api.faults import fault_point
 from ...kernels import ops as kops
 from ...kernels.butterfly_sparse import batched_row_extents
+from ...utils.spans import fetch, span
 from ..graph import BipartiteGraph, pad_to_multiple
 from ..scheduler import pack_by_shape
 from .peel_loop import (
@@ -157,23 +157,24 @@ def build_fd_tasks(g: BipartiteGraph, subset_id: np.ndarray,
     wedges" saving) and record per-subset size/wedge-bound stats."""
     n_sub = int(subset_id.max()) + 1 if subset_id.size else 0
     tasks = []
-    for i in range(n_sub):
-        members = np.where(subset_id == i)[0]
-        stats.subset_sizes.append(len(members))
-        if len(members) == 0:
-            stats.subset_wedges_fd.append(0)
-            continue
-        sub, _ = g.induced_on_u(members)
-        wsub = int(sub.wedge_counts_u().sum())
-        stats.subset_wedges_fd.append(wsub)
-        tasks.append(
-            dict(
-                members=members,
-                sub=sub,
-                lo=float(bounds[i]),
-                wedges=wsub,
+    with span("fd.tasks", subsets=n_sub):
+        for i in range(n_sub):
+            members = np.where(subset_id == i)[0]
+            stats.subset_sizes.append(len(members))
+            if len(members) == 0:
+                stats.subset_wedges_fd.append(0)
+                continue
+            sub, _ = g.induced_on_u(members)
+            wsub = int(sub.wedge_counts_u().sum())
+            stats.subset_wedges_fd.append(wsub)
+            tasks.append(
+                dict(
+                    members=members,
+                    sub=sub,
+                    lo=float(bounds[i]),
+                    wedges=wsub,
+                )
             )
-        )
     return tasks
 
 
@@ -404,7 +405,7 @@ def build_level_stack(group: List[Dict], cfg: ReceiptConfig,
         los=los, cap1=cap1, dv0=dv0, alive0=alive0, row_ext=row_ext,
         row_ext_l1=row_ext_l1, mm=mm, cc=cc, w1=w1,
         peel_width=peel_width, update_mode=update_mode,
-        padded_cells=n_g * (mm + w1) * cc,
+        padded_cells=n_g * (mm + w1) * cc, bytes=a.nbytes + a_l1.nbytes,
         used_cells=int(sum(len(t["members"]) * max(t["sub"].n_v, 1)
                            for t in group)),
     )
@@ -457,34 +458,61 @@ def receipt_fd(
         raise ValueError(
             f"max_sweeps must be >= 1 (got {cfg.max_sweeps}): the valve "
             "bounds one loop invocation; a sub-1 cap makes no progress")
-    t0 = time.perf_counter()
-    theta = np.zeros(g.n_u, np.float64)
-    backend = cfg.backend or kops.default_backend()
+    with span("fd") as sp:
+        theta = np.zeros(g.n_u, np.float64)
+        backend = cfg.backend or kops.default_backend()
 
-    tasks = build_fd_tasks(g, subset_id, bounds, stats)
-    if cfg.fd_mode != "level":
-        stats.wedges_fd += int(sum(t["wedges"] for t in tasks))
+        tasks = build_fd_tasks(g, subset_id, bounds, stats)
+        if cfg.fd_mode != "level":
+            stats.wedges_fd += int(sum(t["wedges"] for t in tasks))
 
-    if cfg.fd_mode == "level":
-        if mesh is not None:
-            theta = _run_level_groups_mesh(tasks, init_support, cfg,
-                                           stats, theta, mesh, plan=plan)
+        if cfg.fd_mode == "level":
+            if mesh is not None:
+                theta = _run_level_groups_mesh(tasks, init_support, cfg,
+                                               stats, theta, mesh, plan=plan)
+            else:
+                theta = _run_level_groups(tasks, init_support, cfg, backend,
+                                          stats, theta, plan=plan)
         else:
-            theta = _run_level_groups(tasks, init_support, cfg, backend,
-                                      stats, theta, plan=plan)
-    else:
-        # workload-aware scheduling: equal-padded stacks (LPT analog)
+            # workload-aware scheduling: equal-padded stacks (LPT analog)
+            groups = pack_by_shape(
+                tasks,
+                size_of=lambda t: (len(t["members"]), max(t["sub"].n_v, 1)),
+                weight_of=lambda t: t["wedges"],
+                bucket=lambda n: bucket(n, 8),
+            )
+            stats.fd_groups = len(groups)
+            theta = _run_legacy_groups(groups, init_support, cfg, stats,
+                                       theta)
+    stats.time_fd = sp.seconds
+    return theta
+
+
+def _prepeel_groups(tasks, init_support, theta, stats, cfg, row_align,
+                    col_align) -> List[List[Dict]]:
+    """Host pre-peel of every task, then the survivors packed into
+    equal-padded-shape groups (one ``fd.prepeel`` span)."""
+    with span("fd.prepeel", levels=cfg.fd_prepeel_levels):
+        tasks = pre_peel_tasks(tasks, init_support, theta, stats,
+                               levels=cfg.fd_prepeel_levels)
         groups = pack_by_shape(
             tasks,
-            size_of=lambda t: (len(t["members"]), max(t["sub"].n_v, 1)),
+            size_of=lambda t: (len(t["surv"]), max(t["sub"].n_v, 1)),
             weight_of=lambda t: t["wedges"],
-            bucket=lambda n: bucket(n, 8),
+            bucket=lambda n: _level_pad(n, row_align),
+            bucket_cols=lambda n: _level_pad(n, col_align),
         )
-        stats.fd_groups = len(groups)
-        theta = _run_legacy_groups(groups, init_support, cfg, stats, theta)
+    stats.fd_groups = len(groups)
+    return groups
 
-    stats.time_fd = time.perf_counter() - t0
-    return theta
+
+def _stack(k: int, group: List[Dict], cfg: ReceiptConfig, backend: str,
+           plan) -> Dict:
+    """``build_level_stack`` for group ``k`` under an ``fd.stack`` span."""
+    with span("fd.stack", group=k) as sp:
+        built = build_level_stack(group, cfg, backend, plan=plan)
+        sp.set_metadata(bytes=built["bytes"])
+    return built
 
 
 def _run_level_groups(tasks, init_support, cfg, backend, stats, theta,
@@ -496,61 +524,54 @@ def _run_level_groups(tasks, init_support, cfg, backend, stats, theta,
     row_align, col_align, _ = _aligns(cfg, backend)
     sparse = backend in kops.SPARSE_BACKENDS
 
-    tasks = pre_peel_tasks(tasks, init_support, theta, stats,
-                           levels=cfg.fd_prepeel_levels)
-    groups = pack_by_shape(
-        tasks,
-        size_of=lambda t: (len(t["surv"]), max(t["sub"].n_v, 1)),
-        weight_of=lambda t: t["wedges"],
-        bucket=lambda n: _level_pad(n, row_align),
-        bucket_cols=lambda n: _level_pad(n, col_align),
-    )
-    stats.fd_groups = len(groups)
-
+    groups = _prepeel_groups(tasks, init_support, theta, stats, cfg,
+                             row_align, col_align)
     padded = used = 0
     pending = None           # (built, device outputs) one group in flight
 
     def launch(built):
-        g_n, mm, w1 = built["a"].shape[0], built["mm"], built["w1"]
-        fault_point("kernel_launch", KernelBackendError,
-                    dispatch="fd_level", backend=backend,
-                    group_shape=(g_n, mm))
-        a_dev = jnp.asarray(built["a"], cfg.dtype)
-        sup_dev = jnp.asarray(built["sup0"], cfg.dtype)
-        alive_dev = jnp.asarray(built["alive0"])
-        dv_dev = jnp.asarray(built["dv0"], jnp.float32)
-        lo_dev = jnp.asarray(built["los"], jnp.float32)
-        rext_dev = jnp.asarray(built["row_ext"])
-        # first-level delta: ONE grouped kernel call sized to survivors
-        # (output side) x first level (gathered side)
-        a_l1 = jnp.asarray(built["a_l1"], cfg.dtype)
-        valid1 = (jnp.arange(w1)[None, :]
-                  < jnp.asarray(built["n_l1"])[:, None])
-        ids_s = jnp.broadcast_to(
-            jnp.arange(mm, dtype=jnp.int32)[None, :], (g_n, mm))
-        ids_l1 = jnp.broadcast_to(
-            mm + jnp.arange(w1, dtype=jnp.int32)[None, :], (g_n, w1))
-        if sparse:
-            bi, bj, _bk = blocks
-            kma = rext_dev.reshape(g_n, -1, bi).max(axis=2).astype(jnp.int32)
-            kmb = jnp.asarray(built["row_ext_l1"]).reshape(
-                g_n, -1, bj).max(axis=2).astype(jnp.int32)
-        else:
-            kma = kmb = None
-        delta1 = kops.butterfly_update_batched(
-            a_dev, a_l1, valid1, ids_s, ids_l1,
-            backend=backend, blocks=blocks, kmax_a=kma, kmax_b=kmb,
-        )
-        cap1 = jnp.asarray(built["cap1"], cfg.dtype)
-        sup1 = jnp.maximum(sup_dev - delta1, cap1[:, None])
-        out = batched_level_loop(
-            a_dev, rext_dev, sup1, alive_dev, dv_dev, lo_dev,
-            backend=backend, blocks=blocks,
-            peel_width=built["peel_width"], max_sweeps=cfg.max_sweeps,
-            update_mode=built["update_mode"],
-        )
-        stats.device_loop_calls += 1
-        built["_loop_args"] = (a_dev, rext_dev, lo_dev)
+        with span("fd.launch", bytes=built["bytes"]):
+            g_n, mm, w1 = built["a"].shape[0], built["mm"], built["w1"]
+            fault_point("kernel_launch", KernelBackendError,
+                        dispatch="fd_level", backend=backend,
+                        group_shape=(g_n, mm))
+            a_dev = jnp.asarray(built["a"], cfg.dtype)
+            sup_dev = jnp.asarray(built["sup0"], cfg.dtype)
+            alive_dev = jnp.asarray(built["alive0"])
+            dv_dev = jnp.asarray(built["dv0"], jnp.float32)
+            lo_dev = jnp.asarray(built["los"], jnp.float32)
+            rext_dev = jnp.asarray(built["row_ext"])
+            # first-level delta: ONE grouped kernel call sized to survivors
+            # (output side) x first level (gathered side)
+            a_l1 = jnp.asarray(built["a_l1"], cfg.dtype)
+            valid1 = (jnp.arange(w1)[None, :]
+                      < jnp.asarray(built["n_l1"])[:, None])
+            ids_s = jnp.broadcast_to(
+                jnp.arange(mm, dtype=jnp.int32)[None, :], (g_n, mm))
+            ids_l1 = jnp.broadcast_to(
+                mm + jnp.arange(w1, dtype=jnp.int32)[None, :], (g_n, w1))
+            if sparse:
+                bi, bj, _bk = blocks
+                kma = rext_dev.reshape(g_n, -1, bi).max(axis=2).astype(
+                    jnp.int32)
+                kmb = jnp.asarray(built["row_ext_l1"]).reshape(
+                    g_n, -1, bj).max(axis=2).astype(jnp.int32)
+            else:
+                kma = kmb = None
+            delta1 = kops.butterfly_update_batched(
+                a_dev, a_l1, valid1, ids_s, ids_l1,
+                backend=backend, blocks=blocks, kmax_a=kma, kmax_b=kmb,
+            )
+            cap1 = jnp.asarray(built["cap1"], cfg.dtype)
+            sup1 = jnp.maximum(sup_dev - delta1, cap1[:, None])
+            out = batched_level_loop(
+                a_dev, rext_dev, sup1, alive_dev, dv_dev, lo_dev,
+                backend=backend, blocks=blocks,
+                peel_width=built["peel_width"], max_sweeps=cfg.max_sweeps,
+                update_mode=built["update_mode"],
+            )
+            stats.device_loop_calls += 1
+            built["_loop_args"] = (a_dev, rext_dev, lo_dev)
         return out
 
     def drain(built, out):
@@ -563,9 +584,8 @@ def _run_level_groups(tasks, init_support, cfg, backend, stats, theta,
         max_level_seen = 0
         while True:
             sup, alive, dv, th, rho, wedges, max_lev, _sweeps = out
-            th_h, alive_h, rho_h, wedges_h, max_lev_h = jax.device_get(
-                (th, alive, rho, wedges, max_lev))
-            stats.host_round_trips += 1
+            th_h, alive_h, rho_h, wedges_h, max_lev_h = fetch(
+                stats, (th, alive, rho, wedges, max_lev), "fd.drain")
             d_rho = int(np.asarray(rho_h).sum())
             stats.rho_fd += d_rho
             stats.wedges_fd += int(np.asarray(wedges_h, np.float64).sum())
@@ -591,8 +611,8 @@ def _run_level_groups(tasks, init_support, cfg, backend, stats, theta,
         for k, t in enumerate(built["group"]):
             theta[t["members"][t["surv"]]] = th_acc[k, : built["nmem"][k]]
 
-    for group in groups:
-        built = build_level_stack(group, cfg, backend, plan=plan)
+    for k, group in enumerate(groups):
+        built = _stack(k, group, cfg, backend, plan)
         padded += built["padded_cells"]
         used += built["used_cells"]
         out = launch(built)                     # async dispatch
@@ -635,16 +655,8 @@ def _run_level_groups_mesh(tasks, init_support, cfg, stats, theta, mesh,
     row_align, col_align, _ = _aligns(cfg, backend)
     n_shards = mesh.size
 
-    tasks = pre_peel_tasks(tasks, init_support, theta, stats,
-                           levels=cfg.fd_prepeel_levels)
-    groups = pack_by_shape(
-        tasks,
-        size_of=lambda t: (len(t["surv"]), max(t["sub"].n_v, 1)),
-        weight_of=lambda t: t["wedges"],
-        bucket=lambda n: _level_pad(n, row_align),
-        bucket_cols=lambda n: _level_pad(n, col_align),
-    )
-    stats.fd_groups = len(groups)
+    groups = _prepeel_groups(tasks, init_support, theta, stats, cfg,
+                             row_align, col_align)
     stats.fd_shards = n_shards
     shard_rho = np.zeros(n_shards, np.int64)
     shard_wedges = np.zeros(n_shards, np.float64)
@@ -655,22 +667,24 @@ def _run_level_groups_mesh(tasks, init_support, cfg, stats, theta, mesh,
 
     def launch(built):
         nonlocal lpt_loads
-        sharded, slots = shard_level_group(built, n_shards,
-                                           init_loads=lpt_loads)
-        lpt_loads = lpt_loads + sharded["shard_load"]
-        # pre-place the big stack with its mesh sharding so cap-exit
-        # re-entries reuse the device-resident copy (no re-upload)
-        sharded["a"] = jax.device_put(
-            np.asarray(sharded["a"], np.float32), fd_stack_sharding(mesh))
-        out = distributed_fd_level_peel(
-            mesh, sharded["a"], sharded["sup"], sharded["alive"],
-            sharded["dv"], sharded["lo"],
-            a_l1=sharded["a_l1"], n_l1=sharded["n_l1"],
-            cap1=sharded["cap1"],
-            update_mode=built["update_mode"],
-            peel_width=built["peel_width"],
-            max_sweeps=cfg.max_sweeps, full_state=True,
-        )
+        with span("fd.launch", bytes=built["bytes"]):
+            sharded, slots = shard_level_group(built, n_shards,
+                                               init_loads=lpt_loads)
+            lpt_loads = lpt_loads + sharded["shard_load"]
+            # pre-place the big stack with its mesh sharding so cap-exit
+            # re-entries reuse the device-resident copy (no re-upload)
+            sharded["a"] = jax.device_put(
+                np.asarray(sharded["a"], np.float32),
+                fd_stack_sharding(mesh))
+            out = distributed_fd_level_peel(
+                mesh, sharded["a"], sharded["sup"], sharded["alive"],
+                sharded["dv"], sharded["lo"],
+                a_l1=sharded["a_l1"], n_l1=sharded["n_l1"],
+                cap1=sharded["cap1"],
+                update_mode=built["update_mode"],
+                peel_width=built["peel_width"],
+                max_sweeps=cfg.max_sweeps, full_state=True,
+            )
         stats.device_loop_calls += 1
         return sharded, slots, out
 
@@ -684,9 +698,8 @@ def _run_level_groups_mesh(tasks, init_support, cfg, stats, theta, mesh,
         prev_alive = sharded["alive"]
         while True:
             sup, alive, dv, th, rho, wedges = out
-            th_h, alive_h, rho_h, wedges_h = jax.device_get(
-                (th, alive, rho, wedges))
-            stats.host_round_trips += 1
+            th_h, alive_h, rho_h, wedges_h = fetch(
+                stats, (th, alive, rho, wedges), "fd.drain")
             d_rho = int(np.asarray(rho_h).sum())
             stats.rho_fd += d_rho
             stats.wedges_fd += int(np.asarray(wedges_h, np.float64).sum())
@@ -717,11 +730,11 @@ def _run_level_groups_mesh(tasks, init_support, cfg, stats, theta, mesh,
             nm = int(built["nmem"][t_idx])
             theta[t["members"][t["surv"]]] = th_acc[s, :nm]
 
-    for group in groups:
+    for k, group in enumerate(groups):
         # plan hints apply (shape quantization + measured widths); the
         # measured-level feedback itself is recorded on the local path
         # only — the sharded loop keeps its 6-field state contract
-        built = build_level_stack(group, cfg, backend, plan=plan)
+        built = _stack(k, group, cfg, backend, plan)
         sharded, slots, out = launch(built)     # async dispatch
         padded += sharded["a"].size + sharded["a_l1"].size
         used += built["used_cells"]
@@ -777,8 +790,7 @@ def _run_legacy_groups(groups, init_support, cfg, stats, theta):
             th = _fd_peel_b2_vm(b2, sup_dev, nm_dev, lo_dev)
         else:
             th = _fd_peel_matvec_vm(a_dev, sup_dev, nm_dev, lo_dev)
-        th_np = np.asarray(th, np.float64)
-        stats.host_round_trips += 1
+        th_np = np.asarray(fetch(stats, th, "fd.legacy"), np.float64)
         stats.rho_fd += int(nmem.sum())       # one sync-round per peel step
         for k, t in enumerate(group):
             theta[t["members"]] = th_np[k, : nmem[k]]

@@ -68,6 +68,7 @@ from ...kernels.butterfly_sparse import (
     gathered_tile_extents,
     row_extents,
 )
+from ...utils.spans import fetch, span
 from ..graph import BipartiteGraph
 
 __all__ = [
@@ -286,9 +287,9 @@ class RunStats:
     fd_shard_wedges: List[float] = dataclasses.field(default_factory=list)
     #                               # per-shard dynamic wedge load (mesh
     #                               # FD; the LPT balance evidence)
-    time_count: float = 0.0
-    time_cd: float = 0.0
-    time_fd: float = 0.0
+    time_count: float = 0.0         # wall seconds of the receipt.cd.count
+    time_cd: float = 0.0            # span, of receipt.cd less counting,
+    time_fd: float = 0.0            # and of receipt.fd (utils/spans.py)
     # hardened-runtime evidence (DESIGN.md §7): which backend actually
     # produced the result, the degradation path that led there, and what
     # the self-verification pass checked
@@ -1234,55 +1235,57 @@ class DeviceGraph:
 
     def __init__(self, g: BipartiteGraph, members: np.ndarray,
                  cfg: ReceiptConfig, plan=None):
-        self.cfg = cfg
-        bi, bj, bk = cfg.kernel_blocks
-        # induce on the live rows, dropping V columns that cannot form a
-        # wedge (residual degree < 2) — the DGM column compaction
-        sub, _ = g.induced_on_u(members, min_degree_v=2)
-        dvk = sub.degrees_v()
-        eu, ev = sub.edges_u, sub.edges_v
+        with span("cd.graph") as sp:
+            self.cfg = cfg
+            bi, bj, bk = cfg.kernel_blocks
+            # induce on the live rows, dropping V columns that cannot form a
+            # wedge (residual degree < 2) — the DGM column compaction
+            sub, _ = g.induced_on_u(members, min_degree_v=2)
+            dvk = sub.degrees_v()
+            eu, ev = sub.edges_u, sub.edges_v
 
-        self.members = np.asarray(members)
-        self.n_rows = len(members)
-        self.n_cols = max(int(sub.n_v), 1)
-        self.rows_pad = bucket(self.n_rows, max(bi, bj))
-        self.cols_pad = bucket(self.n_cols, bk)
-        if plan is not None:
-            # DGM re-induction shapes quantize through the plan's
-            # geometric shape floors, so subset re-induction lands on a
-            # dispatch size an earlier same-signature run already traced
-            # (the executable cache stays warm instead of retracing per
-            # residual-graph size)
-            self.rows_pad = plan.quantize_dim("dgm_rows", self.rows_pad)
-            self.cols_pad = plan.quantize_dim("dgm_cols", self.cols_pad)
+            self.members = np.asarray(members)
+            self.n_rows = len(members)
+            self.n_cols = max(int(sub.n_v), 1)
+            self.rows_pad = bucket(self.n_rows, max(bi, bj))
+            self.cols_pad = bucket(self.n_cols, bk)
+            if plan is not None:
+                # DGM re-induction shapes quantize through the plan's
+                # geometric shape floors, so subset re-induction lands on a
+                # dispatch size an earlier same-signature run already traced
+                # (the executable cache stays warm instead of retracing per
+                # residual-graph size)
+                self.rows_pad = plan.quantize_dim("dgm_rows", self.rows_pad)
+                self.cols_pad = plan.quantize_dim("dgm_cols", self.cols_pad)
 
-        a = np.zeros((self.rows_pad, self.cols_pad), np.float32)
-        a[eu, ev] = 1.0
-        self.a = jnp.asarray(a, dtype=cfg.dtype)
-        self.ids = jnp.arange(self.rows_pad, dtype=jnp.int32)
-        # residual V degrees at construction (everything alive)
-        dv_pad = np.zeros(self.cols_pad, np.float32)
-        dv_pad[: len(dvk)] = dvk
-        self.dv0 = jnp.asarray(dv_pad)
-        # static per-row wedge counts in this residual graph (range proxy)
-        w = np.zeros(self.rows_pad, np.float64)
-        np.add.at(w, eu, (dvk[ev] - 1).astype(np.float64))
-        self.w_np = w
-        self.w = jnp.asarray(w, dtype=cfg.dtype)
-        # total residual wedges = sum of per-row counts (everything alive)
-        self.total_wedges = float(w.sum())
-        # Chiba-Nishizeki recount bound of this residual graph (HUC C_rcnt)
-        du = np.bincount(eu, minlength=self.rows_pad)
-        self.c_rcnt = float(np.minimum(du[eu], dvk[ev]).sum())
-        # block-sparse staircase metadata (scalar-prefetched by the
-        # pallas_sparse backend; cheap enough to keep fresh always)
-        backend = cfg.backend or kops.default_backend()
-        if backend in kops.SPARSE_BACKENDS and bi != bj:
-            raise ValueError("sparse backends require square row tiles")
-        rext = row_extents(a, bk)
-        self.row_ext = jnp.asarray(rext)
-        # tile extents = per-tile max of the row extents (one dense pass)
-        self.kmax = jnp.asarray(rext.reshape(-1, bi).max(axis=1))
+            a = np.zeros((self.rows_pad, self.cols_pad), np.float32)
+            a[eu, ev] = 1.0
+            self.a = jnp.asarray(a, dtype=cfg.dtype)
+            self.ids = jnp.arange(self.rows_pad, dtype=jnp.int32)
+            # residual V degrees at construction (everything alive)
+            dv_pad = np.zeros(self.cols_pad, np.float32)
+            dv_pad[: len(dvk)] = dvk
+            self.dv0 = jnp.asarray(dv_pad)
+            # static per-row wedge counts in this residual graph (range proxy)
+            w = np.zeros(self.rows_pad, np.float64)
+            np.add.at(w, eu, (dvk[ev] - 1).astype(np.float64))
+            self.w_np = w
+            self.w = jnp.asarray(w, dtype=cfg.dtype)
+            # total residual wedges = sum of per-row counts (everything alive)
+            self.total_wedges = float(w.sum())
+            # Chiba-Nishizeki recount bound of this residual graph (HUC C_rcnt)
+            du = np.bincount(eu, minlength=self.rows_pad)
+            self.c_rcnt = float(np.minimum(du[eu], dvk[ev]).sum())
+            # block-sparse staircase metadata (scalar-prefetched by the
+            # pallas_sparse backend; cheap enough to keep fresh always)
+            backend = cfg.backend or kops.default_backend()
+            if backend in kops.SPARSE_BACKENDS and bi != bj:
+                raise ValueError("sparse backends require square row tiles")
+            rext = row_extents(a, bk)
+            self.row_ext = jnp.asarray(rext)
+            # tile extents = per-tile max of the row extents (one dense pass)
+            self.kmax = jnp.asarray(rext.reshape(-1, bi).max(axis=1))
+            sp.set_metadata(rows=self.rows_pad, cols=self.cols_pad)
 
     def initial_peel_width(self) -> int:
         """Auto-sized device peel buffer: a quarter of the padded rows
@@ -1312,22 +1315,19 @@ def host_sweep(dg, cfg: ReceiptConfig, stats: RunStats,
 
     Returns (support, alive, info) where info is None when nothing was
     peelable, else a dict with keys ``peel_np`` (host peel mask),
-    ``n_peel`` and ``c_peel``.  Every blocking transfer increments
-    ``stats.host_round_trips`` — this is the per-sweep cost the
-    device-resident loop removes.
+    ``n_peel`` and ``c_peel``.  Every blocking transfer is a ``fetch``
+    counted in ``stats.host_round_trips`` — this is the per-sweep cost
+    the device-resident loop removes.
     """
     sparse = backend in kops.SPARSE_BACKENDS
     peel, n_peel, c_peel = sweep_info(dg.a, support, alive, hi)
-    n_peel = int(n_peel)
-    stats.host_round_trips += 1
+    n_peel = int(fetch(stats, n_peel, "sweep.select"))
     if n_peel == 0:
         return support, alive, None
-    c_peel = float(c_peel)
-    stats.host_round_trips += 1
+    c_peel = float(fetch(stats, c_peel, "sweep.cost"))
     stats.rho_cd += 1
 
-    n_alive_after = int(jnp.sum(alive)) - n_peel
-    stats.host_round_trips += 1
+    n_alive_after = int(fetch(stats, jnp.sum(alive), "sweep.alive")) - n_peel
     if n_alive_after == 0:
         # terminal-sweep elision (beyond-paper, DESIGN.md): when a sweep
         # peels every remaining vertex there is no survivor to update, so
@@ -1364,6 +1364,5 @@ def host_sweep(dg, cfg: ReceiptConfig, stats: RunStats,
         support = jnp.where(alive, support, _INF)
         stats.wedges_cd += int(c_peel)
 
-    peel_np = np.asarray(peel)
-    stats.host_round_trips += 1
+    peel_np = np.asarray(fetch(stats, peel, "sweep.mask"))
     return support, alive, dict(peel_np=peel_np, n_peel=n_peel, c_peel=c_peel)

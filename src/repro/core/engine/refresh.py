@@ -59,6 +59,7 @@ import numpy as np
 from ...kernels import ops as kops
 from ...kernels.butterfly_sparse import (batched_row_extents,
                                          gathered_tile_extents)
+from ...utils.spans import fetch
 from ..graph import BipartiteGraph
 from .peel_loop import (
     _INF,
@@ -215,8 +216,8 @@ def _drain(run_one, stops: Sequence[float], watch: np.ndarray,
 
     ``run_one(stop)`` runs one device-loop invocation at ``stop`` from
     the CURRENT carried state and returns the fetched
-    ``(alive, theta, rho, support)`` host views.  Returns
-    ``(alive_h, th_acc, stop_used)``.
+    ``(alive, theta, rho, support)`` host views (one counted ``fetch``).
+    Returns ``(alive_h, th_acc, stop_used)``.
     """
     watch = np.asarray(watch, np.int64).reshape(-1)
     th_acc = np.zeros(alive0.shape, np.float64)
@@ -227,7 +228,6 @@ def _drain(run_one, stops: Sequence[float], watch: np.ndarray,
         stop = float(stops[si])
         alive_h, th_h, rho_h, sup_h = run_one(min(stop, _STOP_MAX))
         stats.device_loop_calls += 1
-        stats.host_round_trips += 1
         newly_dead = prev_alive & ~alive_h
         th_acc = np.where(newly_dead, th_h, th_acc)
         prev_alive = alive_h
@@ -316,16 +316,17 @@ def repeel_tip_prefix(
             peel_width=peel_width, max_sweeps=cfg.max_sweeps)
         (carry["support"], carry["alive"], carry["dv"], carry["theta"],
          carry["rho"], carry["wedges"], _sw) = out
-        alive_h, th_h, rho_h, sup_h = jax.device_get(
-            (carry["alive"], carry["theta"], carry["rho"],
-             carry["support"]))
+        alive_h, th_h, rho_h, sup_h, carry["wedges_h"] = fetch(
+            stats, (carry["alive"], carry["theta"], carry["rho"],
+                    carry["support"], carry["wedges"]), "refresh")
+        carry["rho_h"] = int(rho_h)
         return (np.asarray(alive_h), np.asarray(th_h, np.float64),
                 int(rho_h), np.asarray(sup_h, np.float64))
 
     alive_h, th_acc, stop_used = _drain(run_one, stops, watch, alive0,
                                         stats)
-    stats.rho_fd += int(jax.device_get(carry["rho"]))
-    stats.wedges_fd += int(jax.device_get(carry["wedges"]))
+    stats.rho_fd += carry["rho_h"]
+    stats.wedges_fd += int(carry["wedges_h"])
     theta_new = np.where(alive_h[:n_u],
                          np.asarray(theta_old, np.int64)[:n_u],
                          np.round(th_acc[:n_u]).astype(np.int64))
@@ -379,16 +380,17 @@ def repeel_wing_prefix(
             backend=backend, blocks=blocks, max_sweeps=cfg.max_sweeps)
         (carry["a"], carry["support"], carry["alive"], carry["dv"],
          carry["theta"], carry["rho"], carry["wedges"], _sw) = out
-        alive_h, th_h, rho_h, sup_h = jax.device_get(
-            (carry["alive"], carry["theta"], carry["rho"],
-             carry["support"]))
+        alive_h, th_h, rho_h, sup_h, carry["wedges_h"] = fetch(
+            stats, (carry["alive"], carry["theta"], carry["rho"],
+                    carry["support"], carry["wedges"]), "refresh")
+        carry["rho_h"] = int(rho_h)
         return (np.asarray(alive_h), np.asarray(th_h, np.float64),
                 int(rho_h), np.asarray(sup_h, np.float64))
 
     alive_h, th_acc, stop_used = _drain(run_one, stops, watch, alive0,
                                         stats)
-    stats.rho_fd += int(jax.device_get(carry["rho"]))
-    stats.wedges_fd += int(jax.device_get(carry["wedges"]))
+    stats.rho_fd += carry["rho_h"]
+    stats.wedges_fd += int(carry["wedges_h"])
     psi_new = np.where(alive_h[:m],
                        np.asarray(psi_old, np.int64)[:m],
                        np.round(th_acc[:m]).astype(np.int64))

@@ -48,7 +48,6 @@ appends provably-inert zero filler slots to reach the quantized count.
 from __future__ import annotations
 
 import functools
-import time
 from typing import Tuple
 
 import jax
@@ -57,6 +56,7 @@ import numpy as np
 
 from ...kernels import butterfly_tiled as ktiled
 from ...kernels import ops as kops
+from ...utils.spans import fetch, span
 from ..graph import BipartiteGraph, TiledGraph
 from .peel_loop import (
     ReceiptConfig,
@@ -176,93 +176,93 @@ def receipt_tiled(
     driver handles side transposition and degree-sort unmapping, exactly
     as for the dense CD+FD pipeline).
     """
-    t0 = time.perf_counter()
-    backend = kops.resolve_backend(cfg.backend)
-    n_u = g_work.n_u
-    stats.wedges_pvbcnt = g_work.counting_wedge_bound()
-    stats.num_subsets = 1
-    theta_out = np.zeros(n_u, np.float64)
-    cur_ids = np.arange(n_u, dtype=np.int64)
-    # host DGM pre-compaction: degree-<2 columns complete no wedge
-    sub, _v_map = g_work.induced_on_u(cur_ids, min_degree_v=2)
-    stats.dgm_compactions += 1
-    seg_sweeps = max(1, min(cfg.max_sweeps, cfg.tiled_compact_every))
-    support_carry = None   # None until the first device count
-    stats.time_count += time.perf_counter() - t0
+    with span("tiled") as sp:
+        backend = kops.resolve_backend(cfg.backend)
+        n_u = g_work.n_u
+        stats.wedges_pvbcnt = g_work.counting_wedge_bound()
+        stats.num_subsets = 1
+        theta_out = np.zeros(n_u, np.float64)
+        cur_ids = np.arange(n_u, dtype=np.int64)
+        # host DGM pre-compaction: degree-<2 columns complete no wedge
+        sub, _v_map = g_work.induced_on_u(cur_ids, min_degree_v=2)
+        stats.dgm_compactions += 1
+        seg_sweeps = max(1, min(cfg.max_sweeps, cfg.tiled_compact_every))
+        support_carry = None   # None until the first device count
 
-    t1 = time.perf_counter()
-    while True:
-        # (re)build the slot list for the current survivor graph.  The
-        # peel state carries over: support values are the loop's CLAMPED
-        # supports (capped at the running level by apply_delta, exactly
-        # the oracle's Alg. 2 line 13), so they must be carried, never
-        # recounted — a recount could fall below the running level and
-        # break cap monotonicity.
-        tg = build_tiled(sub, cfg, plan=plan)
-        td = jnp.asarray(tg.tile_data)
-        srow = jnp.asarray(tg.srow)
-        scol = jnp.asarray(tg.scol)
-        sptr = jnp.asarray(tg.sptr)
-        pos = jnp.asarray(tg.pos)
-        sl = ktiled.slot_liveness(td)
-        rows_pad = tg.rows_pad
-        n_cur = sub.n_u
-
-        alive = jnp.arange(rows_pad) < n_cur
-        dv = ktiled.colsum_tiled(td, scol, tg.n_col_tiles)
-        if support_carry is None:
-            tc = time.perf_counter()
-            support = kops.butterfly_update_tiled(
-                td, srow, scol, sptr, pos, sl,
-                alive.astype(jnp.float32), backend=backend)
-            stats.time_count += time.perf_counter() - tc
-        else:
-            sup_host = np.zeros(rows_pad, np.float32)
-            sup_host[:n_cur] = support_carry
-            support = jnp.asarray(sup_host)
-        theta = jnp.zeros(rows_pad, jnp.float32)
-        prev_alive = np.ones(n_cur, dtype=bool)
-
-        done = False
         while True:
-            (td, sl, support, alive, theta, dv, wed,
-             sweeps) = _tiled_peel_loop(
-                td, sl, srow, scol, sptr, pos, support, alive, theta,
-                dv, backend=backend, max_sweeps=seg_sweeps,
-                regather_every=cfg.tiled_regather_every,
-                n_col_tiles=tg.n_col_tiles)
-            stats.device_loop_calls += 1
-            stats.host_round_trips += 1
-            n_sweeps = int(jax.device_get(sweeps))
-            stats.rho_fd += n_sweeps
-            stats.wedges_fd += int(round(float(jax.device_get(wed))))
-            stats.dgm_device_compactions += (
-                n_sweeps // cfg.tiled_regather_every)
-            alive_host = np.asarray(jax.device_get(alive))[:n_cur]
-            theta_host = np.asarray(jax.device_get(theta))[:n_cur]
-            died = prev_alive & ~alive_host
-            theta_out[cur_ids[died]] = theta_host[died]
-            prev_alive = alive_host
-            n_alive = int(alive_host.sum())
-            if n_alive == 0:
-                done = True
+            # (re)build the slot list for the current survivor graph.  The
+            # peel state carries over: support values are the loop's CLAMPED
+            # supports (capped at the running level by apply_delta, exactly
+            # the oracle's Alg. 2 line 13), so they must be carried, never
+            # recounted — a recount could fall below the running level and
+            # break cap monotonicity.
+            tg = build_tiled(sub, cfg, plan=plan)
+            td = jnp.asarray(tg.tile_data)
+            srow = jnp.asarray(tg.srow)
+            scol = jnp.asarray(tg.scol)
+            sptr = jnp.asarray(tg.sptr)
+            pos = jnp.asarray(tg.pos)
+            sl = ktiled.slot_liveness(td)
+            rows_pad = tg.rows_pad
+            n_cur = sub.n_u
+
+            alive = jnp.arange(rows_pad) < n_cur
+            dv = ktiled.colsum_tiled(td, scol, tg.n_col_tiles)
+            if support_carry is None:
+                with span("tiled.count") as sp_count:
+                    support = kops.butterfly_update_tiled(
+                        td, srow, scol, sptr, pos, sl,
+                        alive.astype(jnp.float32), backend=backend)
+                stats.time_count += sp_count.seconds
+            else:
+                sup_host = np.zeros(rows_pad, np.float32)
+                sup_host[:n_cur] = support_carry
+                support = jnp.asarray(sup_host)
+            theta = jnp.zeros(rows_pad, jnp.float32)
+            prev_alive = np.ones(n_cur, dtype=bool)
+
+            done = False
+            while True:
+                (td, sl, support, alive, theta, dv, wed,
+                 sweeps) = _tiled_peel_loop(
+                    td, sl, srow, scol, sptr, pos, support, alive, theta,
+                    dv, backend=backend, max_sweeps=seg_sweeps,
+                    regather_every=cfg.tiled_regather_every,
+                    n_col_tiles=tg.n_col_tiles)
+                stats.device_loop_calls += 1
+                # the support snapshot rides along for a host recompaction
+                n_sweeps, wed_h, alive_host, theta_host, sup_h = fetch(
+                    stats, (sweeps, wed, alive, theta, support),
+                    "tiled.segment")
+                n_sweeps = int(n_sweeps)
+                stats.rho_fd += n_sweeps
+                stats.wedges_fd += int(round(float(wed_h)))
+                stats.dgm_device_compactions += (
+                    n_sweeps // cfg.tiled_regather_every)
+                alive_host = np.asarray(alive_host)[:n_cur]
+                theta_host = np.asarray(theta_host)[:n_cur]
+                died = prev_alive & ~alive_host
+                theta_out[cur_ids[died]] = theta_host[died]
+                prev_alive = alive_host
+                n_alive = int(alive_host.sum())
+                if n_alive == 0:
+                    done = True
+                    break
+                if (cfg.tiled_compact_ratio > 0.0
+                        and n_alive <= cfg.tiled_compact_ratio * n_cur):
+                    # host recompaction: rebuild the slot list from the
+                    # survivors so per-sweep cost tracks the residual graph
+                    # (static shapes keep dead slots in every dispatch
+                    # until this rebuild — the host half of the tiled DGM)
+                    keep = np.where(alive_host)[0]
+                    support_carry = np.asarray(sup_h)[:n_cur][keep]
+                    cur_ids = cur_ids[keep]
+                    sub, _v_map = sub.induced_on_u(keep, min_degree_v=2)
+                    stats.dgm_compactions += 1
+                    break
+            if done:
                 break
-            if (cfg.tiled_compact_ratio > 0.0
-                    and n_alive <= cfg.tiled_compact_ratio * n_cur):
-                # host recompaction: rebuild the slot list from the
-                # survivors so per-sweep cost tracks the residual graph
-                # (static shapes keep dead slots in every dispatch
-                # until this rebuild — the host half of the tiled DGM)
-                keep = np.where(alive_host)[0]
-                support_carry = np.asarray(
-                    jax.device_get(support))[:n_cur][keep]
-                cur_ids = cur_ids[keep]
-                sub, _v_map = sub.induced_on_u(keep, min_degree_v=2)
-                stats.dgm_compactions += 1
-                break
-        if done:
-            break
-    stats.sweeps_per_subset.append(stats.rho_fd)
-    stats.subset_sizes.append(n_u)
-    stats.time_fd += time.perf_counter() - t1
+        stats.sweeps_per_subset.append(stats.rho_fd)
+        stats.subset_sizes.append(n_u)
+    stats.time_fd += sp.seconds - stats.time_count
     return theta_out

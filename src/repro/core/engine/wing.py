@@ -47,7 +47,6 @@ back through the canonical edge-order permutation.
 from __future__ import annotations
 
 import functools
-import time
 from typing import Optional, Tuple
 
 import jax
@@ -57,6 +56,7 @@ import numpy as np
 from ...api.errors import KernelBackendError
 from ...api.faults import fault_point
 from ...kernels import ops as kops
+from ...utils.spans import fetch, span
 from ..graph import BipartiteGraph
 from .peel_loop import (
     _INF,
@@ -159,27 +159,25 @@ def receipt_wing_cd(
     blocks = cfg.kernel_blocks
     p_total = cfg.num_partitions
 
-    t0 = time.perf_counter()
     es = build_edge_state(g, cfg, plan=plan)
     m = es["m"]
     subset_id = np.full(m, -1, np.int64)
     bounds = [0.0]
 
-    fault_point("kernel_launch", KernelBackendError,
-                dispatch="wing_subset", backend=backend, phase="count")
-    support = kops.edge_support_all(es["a"], es["eu"], es["ev"],
-                                    backend=backend, blocks=blocks)
-    alive = jnp.asarray(es["alive0"])
-    support = jnp.where(alive, support, _INF)
+    with span("cd.count") as sp:
+        fault_point("kernel_launch", KernelBackendError,
+                    dispatch="wing_subset", backend=backend, phase="count")
+        support = kops.edge_support_all(es["a"], es["eu"], es["ev"],
+                                        backend=backend, blocks=blocks)
+        alive = jnp.asarray(es["alive0"])
+        support = jnp.where(alive, support, _INF)
+        sup_np = np.asarray(fetch(stats, support, "cd.count"), np.float64)
+    stats.time_count = sp.seconds
     geom = {"a": es["a"], "eu": es["eu"], "ev": es["ev"]}
     dv = es["dv0"]
     theta0 = jnp.zeros(es["m_pad"], jnp.float32)
-    sup_np = np.asarray(support, np.float64)
     alive_np = np.asarray(es["alive0"])
-    stats.host_round_trips += 1
-    stats.time_count = time.perf_counter() - t0
 
-    t0 = time.perf_counter()
     peel_width = es["peel_width"]
     width_hint = plan.cd_peel_width_hint() if plan is not None else None
     if width_hint is not None and cfg.peel_width is None:
@@ -195,36 +193,37 @@ def receipt_wing_cd(
             vals = np.sort(sup_np[alive_np])
             tgt = max(len(vals) // (p_total - i), 1)
             hi = float(vals[min(tgt - 1, len(vals) - 1)]) + 1.0
-        sweeps = 0
-        while True:
-            fault_point("kernel_launch", KernelBackendError,
-                        dispatch="wing_subset", subset=i, backend=backend)
-            (geom, support, alive, dv, _th, peeled, d_rho, d_wedges,
-             d_hucs, d_elided, _d_cov, _d_sweeps, _ovf) = device_peel_loop(
-                geom, None, None, None, support, alive, dv, theta0,
-                hi, lo, es["c_rcnt"], 0,
-                backend=backend, blocks=blocks, use_huc=cfg.use_huc,
-                peel_width=peel_width, max_sweeps=cfg.max_sweeps,
-                minmode=False, axis="edge",
-            )
-            stats.device_loop_calls += 1
-            (peeled_np, alive_np, sup_f32, d_rho, d_wedges, d_hucs,
-             d_elided) = jax.device_get(
-                (peeled, alive, support, d_rho, d_wedges, d_hucs, d_elided))
-            stats.host_round_trips += 1
-            sup_np = np.asarray(sup_f32, np.float64)
-            stats.rho_cd += int(d_rho)
-            stats.wedges_cd += int(d_wedges)
-            stats.huc_recounts += int(d_hucs)
-            stats.elided_sweeps += int(d_elided)
-            sweeps += int(d_rho)
-            subset_id[np.where(peeled_np[:m])[0]] = i
-            if not (alive_np & (sup_np < hi)).any():
-                break
-            if int(d_rho) == 0:
-                raise RuntimeError(
-                    "wing CD device loop made no progress on a non-empty "
-                    "range (max_sweeps misconfigured?)")
+        with span("cd.subset", i=i):
+            sweeps = 0
+            while True:
+                fault_point("kernel_launch", KernelBackendError,
+                            dispatch="wing_subset", subset=i, backend=backend)
+                (geom, support, alive, dv, _th, peeled, d_rho, d_wedges,
+                 d_hucs, d_elided, _d_cov, _d_sweeps, _ovf) = device_peel_loop(
+                    geom, None, None, None, support, alive, dv, theta0,
+                    hi, lo, es["c_rcnt"], 0,
+                    backend=backend, blocks=blocks, use_huc=cfg.use_huc,
+                    peel_width=peel_width, max_sweeps=cfg.max_sweeps,
+                    minmode=False, axis="edge",
+                )
+                stats.device_loop_calls += 1
+                (peeled_np, alive_np, sup_f32, d_rho, d_wedges, d_hucs,
+                 d_elided) = fetch(
+                    stats, (peeled, alive, support, d_rho, d_wedges, d_hucs,
+                            d_elided), "cd.subset")
+                sup_np = np.asarray(sup_f32, np.float64)
+                stats.rho_cd += int(d_rho)
+                stats.wedges_cd += int(d_wedges)
+                stats.huc_recounts += int(d_hucs)
+                stats.elided_sweeps += int(d_elided)
+                sweeps += int(d_rho)
+                subset_id[np.where(peeled_np[:m])[0]] = i
+                if not (alive_np & (sup_np < hi)).any():
+                    break
+                if int(d_rho) == 0:
+                    raise RuntimeError(
+                        "wing CD device loop made no progress on a non-empty "
+                        "range (max_sweeps misconfigured?)")
         stats.sweeps_per_subset.append(sweeps)
         bounds.append(hi)
         lo = hi
@@ -234,7 +233,6 @@ def receipt_wing_cd(
 
     stats.num_subsets = i
     stats.bounds = [float(b) for b in bounds]
-    stats.time_cd = time.perf_counter() - t0
     if plan is not None:
         plan.note_cd_peel_width(peel_width)
     assert (subset_id >= 0).all(), "wing CD left unassigned edges"
@@ -360,19 +358,18 @@ def _receipt_wing_cd_graph(
     blocks = cfg.kernel_blocks
     p_total = cfg.num_partitions
 
-    t0 = time.perf_counter()
     es = build_edge_state(g, cfg, plan=plan)
     m = es["m"]
-    fault_point("kernel_launch", KernelBackendError,
-                dispatch="wing_graph", backend=backend, phase="count")
-    support = kops.edge_support_all(es["a"], es["eu"], es["ev"],
-                                    backend=backend, blocks=blocks)
-    alive = jnp.asarray(es["alive0"])
-    support = jnp.where(alive, support, _INF)
+    with span("cd.count") as sp:
+        fault_point("kernel_launch", KernelBackendError,
+                    dispatch="wing_graph", backend=backend, phase="count")
+        support = kops.edge_support_all(es["a"], es["eu"], es["ev"],
+                                        backend=backend, blocks=blocks)
+        alive = jnp.asarray(es["alive0"])
+        support = jnp.where(alive, support, _INF)
     # async dispatch: no blocking sync between counting and the CD loop
-    stats.time_count = time.perf_counter() - t0
+    stats.time_count = sp.seconds
 
-    t0 = time.perf_counter()
     peel_width = es["peel_width"]
     width_hint = plan.cd_peel_width_hint() if plan is not None else None
     if width_hint is not None and cfg.peel_width is None:
@@ -388,8 +385,7 @@ def _receipt_wing_cd_graph(
             p_total=p_total,
         )
         stats.device_loop_calls += 1
-        st = jax.device_get(state)                # THE blocking transfer
-        stats.host_round_trips += 1
+        st = fetch(stats, state, "cd.loop")       # THE blocking transfer
         if bool(st["done"]):
             break
         state = dict(state, iters=jnp.int32(0))   # max_sweeps cap-exit
@@ -406,7 +402,6 @@ def _receipt_wing_cd_graph(
         int(x) for x in np.asarray(st["rho_sub"])[:num_subsets])
     stats.num_subsets = num_subsets
     stats.bounds = [float(b) for b in bounds]
-    stats.time_cd = time.perf_counter() - t0
     if plan is not None:
         plan.note_cd_peel_width(peel_width)
     assert (subset_id >= 0).all(), "wing CD left unassigned edges"
@@ -435,7 +430,6 @@ def receipt_wing_fd(
     """
     backend = cfg.backend or kops.default_backend()
     blocks = cfg.kernel_blocks
-    t0 = time.perf_counter()
     m = es["m"]
     m_pad = es["m_pad"]
     psi = np.zeros(m, np.float64)
@@ -445,7 +439,6 @@ def receipt_wing_fd(
         stats.subset_sizes.append(int((subset_id == s).sum()))
     n_g = len(sids)
     if n_g == 0:
-        stats.time_fd = time.perf_counter() - t0
         return psi
     n_gp = plan.quantize_dim("wing_fd_groups", n_g) if plan is not None \
         else n_g
@@ -487,9 +480,8 @@ def receipt_wing_fd(
     max_level_seen = 0
     while True:
         a_c, sup, alv, dv_c, th, rho, wedges, max_lev, _sw = out
-        th_h, alive_h, rho_h, wedges_h, max_lev_h = jax.device_get(
-            (th, alv, rho, wedges, max_lev))
-        stats.host_round_trips += 1
+        th_h, alive_h, rho_h, wedges_h, max_lev_h = fetch(
+            stats, (th, alv, rho, wedges, max_lev), "fd.drain")
         d_rho = int(np.asarray(rho_h).sum())
         stats.rho_fd += d_rho
         stats.wedges_fd += int(np.asarray(wedges_h, np.float64).sum())
@@ -510,7 +502,6 @@ def receipt_wing_fd(
     stats.fd_peel_widths.append(m_pad)
 
     psi = th_acc[slot_of[subset_id], np.arange(m)]
-    stats.time_fd = time.perf_counter() - t0
     return psi
 
 
@@ -546,15 +537,20 @@ def wing_decompose_engine(
     stats = RunStats()
     if g.m == 0:
         return np.zeros(0, np.int64), stats
-    if cfg.cd_dispatch == "graph":
-        if not cfg.device_loop:
-            raise ValueError(
-                "cd_dispatch='graph' runs the whole CD phase on device "
-                "and requires device_loop=True")
-        subset_id, bounds, es = _receipt_wing_cd_graph(g, cfg, stats,
-                                                       plan=plan)
-    else:
-        subset_id, bounds, es = receipt_wing_cd(g, cfg, stats, plan=plan)
-    psi_f = receipt_wing_fd(g, subset_id, bounds, cfg, stats, es,
-                            plan=plan)
+    if cfg.cd_dispatch == "graph" and not cfg.device_loop:
+        raise ValueError(
+            "cd_dispatch='graph' runs the whole CD phase on device "
+            "and requires device_loop=True")
+    with span("cd", dispatch=cfg.cd_dispatch) as sp:
+        if cfg.cd_dispatch == "graph":
+            subset_id, bounds, es = _receipt_wing_cd_graph(g, cfg, stats,
+                                                           plan=plan)
+        else:
+            subset_id, bounds, es = receipt_wing_cd(g, cfg, stats,
+                                                    plan=plan)
+    stats.time_cd = sp.seconds - stats.time_count
+    with span("fd") as sp:
+        psi_f = receipt_wing_fd(g, subset_id, bounds, cfg, stats, es,
+                                plan=plan)
+    stats.time_fd = sp.seconds
     return np.round(psi_f).astype(np.int64), stats
