@@ -79,8 +79,8 @@ class EngineConfig:
     #   the service layer defaults to routing.
     tiled_regather_every: int = 1
     fd_prepeel_levels: int = 4
-    #   max support levels the FD host pre-peel hoists per task while
-    #   the device is busy (satellite of DESIGN.md §2.2); theta is
+    #   max support levels the FD host pre-peel hoists per task,
+    #   before the first FD launch (DESIGN.md §2.2); theta is
     #   identical for every value >= 1 (regression-tested).
     # hardened-runtime knobs (DESIGN.md §7) — service-layer only, never
     # forwarded to the engine's ReceiptConfig:

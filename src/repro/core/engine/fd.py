@@ -13,11 +13,13 @@ Runtime structure (``fd_mode="level"``, the default — DESIGN.md §2.2):
 
 * **iterated host pre-peel** (``pre_peel_tasks``): up to
   ``cfg.fd_prepeel_levels`` peel levels of every subset are resolved
-  from the host support snapshot while the device is busy — each level's
+  from the host support snapshot, between CD's last sync and the first
+  FD launch (nothing is queued on the device meanwhile) — each level's
   theta is assigned host-side and its delta folded in exactly (pairwise
-  shared-wedge subtraction; exact for simultaneous level peels), so the
-  device stacks hold the SURVIVORS of all hoisted levels (the catch-all
-  subset typically shrinks severalfold); the last hoisted level's delta
+  shared-wedge subtraction, computed from the level's wedges by a sparse
+  product; exact for simultaneous level peels), so the device stacks
+  hold the SURVIVORS of all hoisted levels (the catch-all subset
+  typically shrinks severalfold); the last hoisted level's delta
   reaches the survivors through one grouped butterfly kernel call;
 * **one device dispatch + one blocking ``fetch`` per shape group**
   (theta, per-subset sweep counts rho and dynamic wedge counters all ride
@@ -66,6 +68,7 @@ from typing import Dict, List
 import jax
 import jax.numpy as jnp
 import numpy as np
+from scipy.sparse import csr_matrix
 
 from ...api.errors import KernelBackendError
 from ...api.faults import fault_point
@@ -187,6 +190,25 @@ def _aligns(cfg: ReceiptConfig, backend: str):
     return max(bi, bj), bk, bj
 
 
+def _level_delta(a, surv_mask: np.ndarray, l_mask: np.ndarray):
+    """Exact support loss of the survivors when level ``L`` peels:
+    ``delta[u] = sum_{x in L} C(|N(u) & N(x)|, 2)`` for every ``u`` in
+    ``surv_mask`` (in row order), int64.
+
+    ``a`` is the subset's biadjacency as an int64 ``scipy.sparse`` CSR
+    matrix.  The sparse product ``a[surv] @ a[L].T`` walks the level's
+    wedges and nothing else: for each survivor ``u``, each ``v`` in
+    ``N(u)`` and each peeled ``x`` in ``N(v)`` it adds one to the
+    ``(u, x)`` count, so its work is the number of such pairs,
+    ``sum_v |N(v) & surv| * |N(v) & L|``, not the ``n * n_v * |L|`` of a
+    dense product.  A butterfly holds exactly two peeled-side vertices,
+    so the pairwise sum is exact for a simultaneous level peel.
+    """
+    w = (a[surv_mask] @ a[l_mask].T).tocsr()
+    w.data = w.data * (w.data - 1) // 2
+    return np.asarray(w.sum(axis=1), np.int64).ravel()
+
+
 def pre_peel_tasks(tasks: List[Dict], init_support: np.ndarray,
                    theta: np.ndarray, stats: RunStats,
                    levels: int = 1) -> List[Dict]:
@@ -201,15 +223,14 @@ def pre_peel_tasks(tasks: List[Dict], init_support: np.ndarray,
     padded stack (and the B2/kernel contraction that dominates FD) by a
     large factor.
 
-    ``levels > 1`` (``ReceiptConfig.fd_prepeel_levels``; closes the PR 5
-    deferred item) keeps peeling on the host while the device is busy
-    with the previous shape group: levels 2, 3, ... are derived by the
-    exact host butterfly delta — for survivor u and level set L,
-    ``delta[u] = sum_{x in L} C(|N(u) & N(x)|, 2)`` (a butterfly holds
-    exactly two peeled-side vertices, so pairwise shared-butterfly
-    subtraction is exact for a simultaneous level peel) — then supports
-    floor at the level cap.  Theta is IDENTICAL for every ``levels >=
-    1`` (tip numbers are canonical across exact schedules;
+    ``levels > 1`` (``ReceiptConfig.fd_prepeel_levels``) keeps peeling
+    on the host: levels 2, 3, ... are derived by folding each earlier
+    level's exact delta (``_level_delta``, from the level's wedges) into
+    the survivor supports, which then floor at the level cap.  This runs
+    between CD's last sync and the first FD launch, with nothing queued
+    on the device, so its cost is the pairs the deltas traverse
+    (``RunStats.fd_prepeel_pairs``).  Theta is IDENTICAL for every
+    ``levels >= 1`` (tip numbers are canonical across exact schedules;
     regression-tested).  The LAST hoisted level is handed to the device
     contract unchanged: ``l1``/``cap1``/``sup_surv`` describe that
     level, whose delta the launcher applies through one grouped
@@ -217,7 +238,8 @@ def pre_peel_tasks(tasks: List[Dict], init_support: np.ndarray,
     into ``sup_surv`` host-side.
 
     Mutates ``theta`` / ``stats`` (rho_fd += 1 and the level's dynamic
-    C_peel per hoisted level) and returns the survivor task list.
+    C_peel per hoisted level, the delta's pairs) and returns the
+    survivor task list.
     """
     levels = max(int(levels), 1)
     out = []
@@ -228,7 +250,7 @@ def pre_peel_tasks(tasks: List[Dict], init_support: np.ndarray,
         alive = np.ones(n, bool)
         # column degrees of the still-alive rows (wedge accounting)
         dv_cur = np.bincount(sub.edges_v, minlength=sub.n_v)
-        a_host = None                   # dense rows, built lazily (only
+        a = None                        # sparse rows, built lazily (only
         #                               # needed once a 2nd level peels)
         done = False
         for lvl in range(levels):
@@ -257,14 +279,14 @@ def pre_peel_tasks(tasks: List[Dict], init_support: np.ndarray,
                 done = True
                 break
             # fold this level's delta host-side and keep hoisting
-            if a_host is None:
-                a_host = np.zeros((n, sub.n_v), np.float64)
-                a_host[sub.edges_u, sub.edges_v] = 1.0
-            w = a_host[surv_mask] @ a_host[l_mask].T
-            delta = (w * (w - 1.0) * 0.5).sum(axis=1)
-            sup[surv_mask] = np.maximum(sup[surv_mask] - delta, cap_l)
-            a_host[l_mask] = 0.0
+            if a is None:
+                a = csr_matrix(
+                    (np.ones(sub.m, np.int64), (sub.edges_u, sub.edges_v)),
+                    shape=(n, sub.n_v))
+            delta = _level_delta(a, surv_mask, l_mask)
             dv_cur = dv_cur - colsum
+            stats.fd_prepeel_pairs += int((colsum * dv_cur).sum())
+            sup[surv_mask] = np.maximum(sup[surv_mask] - delta, cap_l)
             alive = surv_mask
         if not done and alive.any():
             # `levels` exhausted with survivors and no handover recorded
@@ -492,9 +514,11 @@ def _prepeel_groups(tasks, init_support, theta, stats, cfg, row_align,
                     col_align) -> List[List[Dict]]:
     """Host pre-peel of every task, then the survivors packed into
     equal-padded-shape groups (one ``fd.prepeel`` span)."""
-    with span("fd.prepeel", levels=cfg.fd_prepeel_levels):
+    with span("fd.prepeel", levels=cfg.fd_prepeel_levels) as sp:
+        pairs0 = stats.fd_prepeel_pairs
         tasks = pre_peel_tasks(tasks, init_support, theta, stats,
                                levels=cfg.fd_prepeel_levels)
+        sp.set_metadata(pairs=stats.fd_prepeel_pairs - pairs0)
         groups = pack_by_shape(
             tasks,
             size_of=lambda t: (len(t["surv"]), max(t["sub"].n_v, 1)),

@@ -157,7 +157,7 @@ class ReceiptConfig:
     #   <= 0 disables host recompaction)
     fd_prepeel_levels: int = 4               # max support levels the FD
     #   host pre-peel hoists per task (level 1, 2, ... on the host
-    #   support snapshot while the device is busy); 1 reproduces the
+    #   support snapshot, before the first FD launch); 1 reproduces the
     #   original single-level hoist.  Any value yields identical theta —
     #   the hoisted levels are the same exact level-peel sweeps the
     #   device loop would run (regression-tested).
@@ -281,6 +281,8 @@ class RunStats:
     fd_mask_fallbacks: int = 0      # groups whose largest level exceeded
     #                               # the gather buffer (on-device mask-form
     #                               # fallback fired; exact either way)
+    fd_prepeel_pairs: int = 0       # (u, x) pairs the FD host pre-peel's
+    #                               # level deltas traversed (its work)
     fd_shards: int = 0              # mesh devices driving FD (0 = local)
     fd_shard_rho: List[int] = dataclasses.field(default_factory=list)
     #                               # per-shard level sweeps (mesh FD)

@@ -7,11 +7,17 @@ import itertools
 import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, st
+from scipy.sparse import csr_matrix
 
-from repro.core.graph import BipartiteGraph, paper_fig1_graph
-from repro.core.peeling import bup_oracle
+from repro.core.graph import (
+    BipartiteGraph,
+    paper_fig1_graph,
+    powerlaw_bipartite,
+)
+from repro.core.peeling import bup_oracle, butterfly_supports
 from repro.core.receipt import ReceiptConfig, RunStats, receipt_cd, receipt_fd
 from repro.core.engine import tip_decompose
+from repro.core.engine.fd import _level_delta, build_fd_tasks, pre_peel_tasks
 from repro.core.scheduler import lpt_assign
 
 from conftest import GRAPH_CASES
@@ -253,3 +259,160 @@ def test_fd_mesh_requires_level_mode():
     with pytest.raises(ValueError, match="fd_mode='level'"):
         receipt_fd(g, sid, isup, bounds, _cfg(fd_mode="b2"), RunStats(),
                    mesh="sentinel")
+
+
+# --------------------------------------------------------------------- #
+# host pre-peel: the wedge-list level delta against the dense product
+# --------------------------------------------------------------------- #
+def _dense_pre_peel_tasks(tasks, init_support, theta, stats, levels=1):
+    """``pre_peel_tasks`` as it was with the dense float64 delta: the
+    reference the wedge-list delta must reproduce bit for bit."""
+    levels = max(int(levels), 1)
+    out = []
+    for t in tasks:
+        mems, sub, lo = t["members"], t["sub"], t["lo"]
+        sup = np.asarray(init_support[mems], np.float64).copy()
+        n = len(mems)
+        alive = np.ones(n, bool)
+        dv_cur = np.bincount(sub.edges_v, minlength=sub.n_v)
+        a_host = None
+        done = False
+        for lvl in range(levels):
+            cap_l = (max(float(sup[alive].min()), lo) if alive.any()
+                     else lo)
+            l_mask = alive & (sup <= cap_l)
+            theta[mems[l_mask]] = cap_l
+            peel_e = l_mask[sub.edges_u]
+            colsum = np.bincount(sub.edges_v[peel_e], minlength=sub.n_v)
+            stats.wedges_fd += int(
+                (colsum * np.maximum(dv_cur - 1, 0)).sum())
+            stats.rho_fd += 1
+            surv_mask = alive & ~l_mask
+            if not surv_mask.any():
+                done = True
+                break
+            if lvl == levels - 1:
+                out.append(dict(
+                    t, surv=np.where(surv_mask)[0],
+                    l1=np.where(l_mask)[0], cap1=cap_l,
+                    sup_surv=sup[surv_mask],
+                ))
+                done = True
+                break
+            if a_host is None:
+                a_host = np.zeros((n, sub.n_v), np.float64)
+                a_host[sub.edges_u, sub.edges_v] = 1.0
+            w = a_host[surv_mask] @ a_host[l_mask].T
+            delta = (w * (w - 1.0) * 0.5).sum(axis=1)
+            sup[surv_mask] = np.maximum(sup[surv_mask] - delta, cap_l)
+            a_host[l_mask] = 0.0
+            dv_cur = dv_cur - colsum
+            alive = surv_mask
+        if not done and alive.any():
+            out.append(dict(
+                t, surv=np.where(alive)[0], l1=np.zeros(0, np.int64),
+                cap1=lo, sup_surv=sup[alive],
+            ))
+    return out
+
+
+def _cd_fd_inputs(g, num_partitions):
+    """FD tasks and supports of ``g`` from a real CD partition."""
+    cfg = _cfg(num_partitions=num_partitions)
+    stats = RunStats()
+    sid, init_sup, bounds, _ = receipt_cd(g, cfg, stats)
+    return build_fd_tasks(g, sid, bounds, RunStats()), init_sup
+
+
+def _bicliques(sizes):
+    """Disjoint complete bipartite blocks ``K_{a,b}`` for each ``(a, b)``."""
+    eu, ev, u0, v0 = [], [], 0, 0
+    for a, b in sizes:
+        for u, v in itertools.product(range(a), range(b)):
+            eu.append(u0 + u)
+            ev.append(v0 + v)
+        u0, v0 = u0 + a, v0 + b
+    return BipartiteGraph.from_edges(u0, v0, eu, ev)
+
+
+def _whole_u_task(g):
+    """One FD task holding every U vertex, at its butterfly support."""
+    sup = butterfly_supports(g).astype(np.float64)
+    members = np.arange(g.n_u)
+    sub, _ = g.induced_on_u(members)
+    return [dict(members=members, sub=sub, lo=0.0, wedges=0)], sup
+
+
+_PREPEEL_CASES = {
+    # a CD catch-all subset: the first level is the bulk of its rows
+    "powerlaw": lambda: _cd_fd_inputs(
+        powerlaw_bipartite(400, 200, 3000, seed=5), 6),
+    # three support levels in one subset: drains on the host from
+    # levels=4 on, hands survivors over below it
+    "drains": lambda: _whole_u_task(_bicliques([(2, 2), (3, 3), (4, 4)])),
+    # every row shares one support: the first level leaves no survivor
+    "zero_survivors": lambda: _whole_u_task(_bicliques([(4, 5)])),
+}
+
+
+@pytest.mark.parametrize("levels", [1, 2, 4, 8])
+@pytest.mark.parametrize("case", sorted(_PREPEEL_CASES))
+def test_pre_peel_wedge_delta_equals_dense(case, levels):
+    tasks, init_sup = _PREPEEL_CASES[case]()
+    n_u = len(init_sup)
+    runs = []
+    for fn in (_dense_pre_peel_tasks, pre_peel_tasks):
+        theta, stats = np.zeros(n_u), RunStats()
+        out = fn(tasks, init_sup, theta, stats, levels=levels)
+        runs.append((out, theta, stats))
+    (want, th_want, st_want), (got, th_got, st_got) = runs
+
+    np.testing.assert_array_equal(th_got, th_want)
+    assert (st_got.rho_fd, st_got.wedges_fd) == (st_want.rho_fd,
+                                                 st_want.wedges_fd)
+    assert len(got) == len(want)
+    for g_t, w_t in zip(got, want):
+        assert g_t["members"] is w_t["members"]
+        for key in ("surv", "l1", "sup_surv"):
+            np.testing.assert_array_equal(g_t[key], w_t[key], err_msg=key)
+        assert g_t["cap1"] == w_t["cap1"]
+    if case == "drains":
+        assert len(got) == (0 if levels >= 3 else 1)
+    if case == "zero_survivors":
+        assert got == [] and st_got.fd_prepeel_pairs == 0
+    if case == "powerlaw" and levels > 1:
+        assert st_got.fd_prepeel_pairs > 0
+
+
+def _brute_delta(rows, surv, peeled):
+    """``sum_{x in L} C(|N(u) & N(x)|, 2)`` per survivor, by sets."""
+    return np.array([
+        sum(len(rows[u] & rows[x]) * (len(rows[u] & rows[x]) - 1) // 2
+            for x in peeled)
+        for u in surv], np.int64)
+
+
+# row 2 shares no column (its column 4 has degree 1); row 5 shares only
+# column 3; rows 0, 1, 3, 4 overlap in two or more columns
+_HAND_ROWS = [{0, 1, 2}, {0, 1}, {4}, {1, 2, 3}, {0, 1, 2, 3}, {3}]
+
+
+@pytest.mark.parametrize("rows, peeled", [
+    (_HAND_ROWS, [0, 3]),
+    (_HAND_ROWS, [4]),
+    (_HAND_ROWS, [2]),
+    (_HAND_ROWS, [1, 2, 5]),
+    ([{0}, {0}, {1}], [0]),
+    ([{0, 1, 2}, {0, 1, 2}, {0, 1, 2}, {0, 1}], [0, 1]),
+], ids=["two-peeled", "hub-peeled", "isolated-peeled", "sparse-peeled",
+        "no-butterfly", "dense"])
+def test_level_delta_equals_brute_force(rows, peeled):
+    n, n_v = len(rows), 1 + max(max(r) for r in rows)
+    eu = [u for u, r in enumerate(rows) for _ in r]
+    ev = [v for r in rows for v in sorted(r)]
+    a = csr_matrix((np.ones(len(eu), np.int64), (eu, ev)), shape=(n, n_v))
+    l_mask = np.isin(np.arange(n), peeled)
+    surv = np.flatnonzero(~l_mask)
+    got = _level_delta(a, ~l_mask, l_mask)
+    assert got.dtype == np.int64
+    np.testing.assert_array_equal(got, _brute_delta(rows, surv, peeled))

@@ -127,6 +127,15 @@ def test_subset_dispatch_spans_each_subset_and_device_graph(tmp_path):
         (cd[2] - cd[1]) / 1e9, rel=0.05, abs=2e-3)
 
 
+def test_prepeel_span_carries_its_pairs(tmp_path):
+    """``fd.prepeel`` reports the pairs its host level deltas traversed,
+    the same count as ``RunStats.fd_prepeel_pairs``."""
+    dec, spans = _traced(tmp_path, lambda: _executor().decompose(_graph()))
+    (prepeel,) = _named(spans, "fd.prepeel")
+    assert prepeel[4]["levels"] == 4
+    assert prepeel[4]["pairs"] == dec.stats.fd_prepeel_pairs > 0
+
+
 def test_repeel_counts_its_syncs(tmp_path):
     g = _graph()
     ex = _executor()
