@@ -17,8 +17,10 @@ API, so existing imports keep working.
 """
 from __future__ import annotations
 
+import contextlib
 from typing import Optional, Tuple
 
+import jax
 import numpy as np
 
 from ...utils.spans import span
@@ -50,6 +52,7 @@ __all__ = [
     "ReceiptConfig",
     "RunStats",
     "tip_decompose",
+    "cd_device",
     "wing_decompose_engine",
     "receipt_cd",
     "receipt_fd",
@@ -74,6 +77,13 @@ __all__ = [
 ]
 
 
+def cd_device(mesh):
+    """The device CD runs on under ``mesh``: the mesh's first device,
+    so that CD never lands on a device outside the mesh (JAX's default
+    device need not be one of its devices)."""
+    return mesh.devices.flat[0]
+
+
 def tip_decompose(
     g: BipartiteGraph, cfg: Optional[ReceiptConfig] = None,
     *, side: str = "U", mesh=None, plan=None,
@@ -88,10 +98,10 @@ def tip_decompose(
     sharded level-peel driver (`core/distributed.py` — subsets
     LPT-assigned to devices, zero collectives, per-shard stats
     reconciled into the returned RunStats).  CD runs single-device
-    either way (its multi-device twin ``distributed_cd_fused_loop`` is
-    a separate entry point: CD is one global range loop, not an
-    embarrassingly parallel stack).  Tip numbers are identical with and
-    without a mesh (DESIGN.md §4).
+    either way, under a mesh on ``cd_device(mesh)`` (its multi-device
+    twin ``distributed_cd_fused_loop`` is a separate entry point: CD is
+    one global range loop, not an embarrassingly parallel stack).  Tip
+    numbers are identical with and without a mesh (DESIGN.md §4).
 
     ``plan``: an ``repro.api.ExecutionPlan`` — supplies measured peel
     widths and shape quantization from earlier same-signature runs and
@@ -130,8 +140,10 @@ def tip_decompose(
         # dense biadjacency — the route above the dense memory ceiling
         theta_work = receipt_tiled(g_work, cfg, stats, plan=plan)
     else:
-        subset_id, init_support, bounds, _ = receipt_cd(g_work, cfg, stats,
-                                                        plan=plan)
+        with (jax.default_device(cd_device(mesh)) if mesh is not None
+              else contextlib.nullcontext()):
+            subset_id, init_support, bounds, _ = receipt_cd(
+                g_work, cfg, stats, plan=plan)
         theta_work = receipt_fd(g_work, subset_id, init_support, bounds, cfg,
                                 stats, mesh=mesh, plan=plan)
 

@@ -50,10 +50,12 @@ DESIGN.md §2.2 "Knobs"):
 same pipeline through ``_run_level_groups_mesh`` — per shape group, the
 survivor/first-level stacks are LPT-assigned to ``mesh.size`` shards
 (`core/distributed.shard_level_group`, with load carryover across
-groups) and peeled under ``shard_map`` with zero collectives
-(`core/distributed.distributed_fd_level_peel`); per-shard loads are
-reconciled into ``RunStats.fd_shard_rho`` / ``fd_shard_wedges`` and tip
-numbers are bit-identical to the local path.
+groups), placed on the shards (an ``fd.shard`` span) and peeled under
+``shard_map`` with zero collectives
+(`core/distributed.distributed_fd_level_peel`); per-shard loads and
+stack bytes are reconciled into ``RunStats.fd_shard_rho`` /
+``fd_shard_wedges`` / ``fd_shard_bytes`` and tip numbers are
+bit-identical to the local path.
 
 The legacy engines are preserved as ``fd_mode="b2"`` (dense (M, M)
 shared-butterfly stacks, one-vertex-per-step ``fori_loop``) and
@@ -662,8 +664,11 @@ def _run_level_groups_mesh(tasks, init_support, cfg, stats, theta, mesh,
     (`core/distributed.distributed_fd_level_peel`): subsets LPT-assigned
     to mesh devices (`core/distributed.shard_level_group`), zero
     collectives, every shard's while_loop exiting as soon as its local
-    subsets drain.  Per-shard sweep/wedge loads accumulate into
-    ``stats.fd_shard_rho`` / ``fd_shard_wedges`` — the reconciled
+    subsets drain.  Per group, an ``fd.shard`` span covers the LPT layout
+    and the sharded upload of the survivor and first-level stacks, and
+    ``fd.launch`` only the dispatch.  Per-shard sweep/wedge loads and
+    uploaded stack bytes accumulate into ``stats.fd_shard_rho`` /
+    ``fd_shard_wedges`` / ``fd_shard_bytes`` — the reconciled
     multi-shard report of the run.
 
     The shard_map local body computes with the pure-jnp oracle backend
@@ -685,21 +690,34 @@ def _run_level_groups_mesh(tasks, init_support, cfg, stats, theta, mesh,
     shard_rho = np.zeros(n_shards, np.int64)
     shard_wedges = np.zeros(n_shards, np.float64)
     lpt_loads = np.zeros(n_shards, np.float64)   # cross-group carryover
+    shard_bytes = np.zeros(n_shards, np.int64)   # stack bytes per shard
+    stack_sharding = fd_stack_sharding(mesh)
 
     padded = used = 0
     pending = None           # (built, sharded, slots, out) one in flight
 
-    def launch(built):
-        nonlocal lpt_loads
-        with span("fd.launch", bytes=built["bytes"]):
+    def place(built):
+        # LPT layout and the sharded upload of the group's two stacks;
+        # cap-exit re-entries reuse the device-resident survivor stack
+        nonlocal lpt_loads, shard_bytes
+        with span("fd.shard", shards=n_shards) as sp:
             sharded, slots = shard_level_group(built, n_shards,
                                                init_loads=lpt_loads)
             lpt_loads = lpt_loads + sharded["shard_load"]
-            # pre-place the big stack with its mesh sharding so cap-exit
-            # re-entries reuse the device-resident copy (no re-upload)
-            sharded["a"] = jax.device_put(
-                np.asarray(sharded["a"], np.float32),
-                fd_stack_sharding(mesh))
+            placed = np.zeros(n_shards, np.int64)
+            for key in ("a", "a_l1"):
+                arr = jax.device_put(sharded[key], stack_sharding)
+                for piece in arr.addressable_shards:
+                    placed[piece.index[0].start // sharded["per_shard"]] += (
+                        piece.data.nbytes)
+                sharded[key] = arr
+            shard_bytes += placed
+            sp.set_metadata(bytes=int(placed.sum()),
+                            max_shard_bytes=int(placed.max()))
+        return sharded, slots
+
+    def launch(built, sharded):
+        with span("fd.launch", bytes=built["bytes"]):
             out = distributed_fd_level_peel(
                 mesh, sharded["a"], sharded["sup"], sharded["alive"],
                 sharded["dv"], sharded["lo"],
@@ -710,7 +728,7 @@ def _run_level_groups_mesh(tasks, init_support, cfg, stats, theta, mesh,
                 max_sweeps=cfg.max_sweeps, full_state=True,
             )
         stats.device_loop_calls += 1
-        return sharded, slots, out
+        return out
 
     def drain(built, sharded, slots, out):
         # one blocking sync per group in the common case; a max_sweeps
@@ -759,7 +777,8 @@ def _run_level_groups_mesh(tasks, init_support, cfg, stats, theta, mesh,
         # measured-level feedback itself is recorded on the local path
         # only — the sharded loop keeps its 6-field state contract
         built = _stack(k, group, cfg, backend, plan)
-        sharded, slots, out = launch(built)     # async dispatch
+        sharded, slots = place(built)
+        out = launch(built, sharded)            # async dispatch
         padded += sharded["a"].size + sharded["a_l1"].size
         used += built["used_cells"]
         if pending is not None:
@@ -774,6 +793,7 @@ def _run_level_groups_mesh(tasks, init_support, cfg, stats, theta, mesh,
     stats.fd_padding_waste = 1.0 - used / padded if padded else 0.0
     stats.fd_shard_rho = [int(x) for x in shard_rho]
     stats.fd_shard_wedges = [float(x) for x in shard_wedges]
+    stats.fd_shard_bytes = [int(x) for x in shard_bytes]
     return theta
 
 

@@ -289,6 +289,9 @@ class RunStats:
     fd_shard_wedges: List[float] = dataclasses.field(default_factory=list)
     #                               # per-shard dynamic wedge load (mesh
     #                               # FD; the LPT balance evidence)
+    fd_shard_bytes: List[int] = dataclasses.field(default_factory=list)
+    #                               # per-shard stack bytes uploaded (mesh
+    #                               # FD; summed over the shape groups)
     time_count: float = 0.0         # wall seconds of the receipt.cd.count
     time_cd: float = 0.0            # span, of receipt.cd less counting,
     time_fd: float = 0.0            # and of receipt.fd (utils/spans.py)
