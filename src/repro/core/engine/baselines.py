@@ -51,7 +51,7 @@ def parb_tip_decompose(
     blocks = cfg.kernel_blocks
     sparse = backend in kops.SPARSE_BACKENDS
 
-    dg = DeviceGraph(g, np.arange(g.n_u), cfg)
+    dg = DeviceGraph(g, np.arange(g.n_u), cfg, stats=stats)
     stats.wedges_pvbcnt = g.counting_wedge_bound()
     alive = jnp.zeros(dg.rows_pad, bool).at[: dg.n_rows].set(True)
     support = support_all(dg.a, alive, dg.ids,

@@ -174,7 +174,7 @@ def _receipt_cd_subset(g: BipartiteGraph, cfg: ReceiptConfig,
         init_support = np.asarray(st["init_support"]).copy()
         bounds = [float(b) for b in st["bounds"]]
         members = np.asarray(st["members"])
-        dg = DeviceGraph(g, members, cfg, plan=plan)
+        dg = DeviceGraph(g, members, cfg, plan=plan, stats=stats)
         stats.wedges_pvbcnt = g.counting_wedge_bound()
         alive = jnp.zeros(dg.rows_pad, bool).at[: dg.n_rows].set(True)
         support = jnp.full(dg.rows_pad, _INF, cfg.dtype)
@@ -193,7 +193,7 @@ def _receipt_cd_subset(g: BipartiteGraph, cfg: ReceiptConfig,
         init_support = np.zeros(n_u, np.float64)
         bounds = [0.0]
 
-        dg = DeviceGraph(g, np.arange(n_u), cfg, plan=plan)
+        dg = DeviceGraph(g, np.arange(n_u), cfg, plan=plan, stats=stats)
         stats.wedges_pvbcnt = g.counting_wedge_bound()
 
         # --- initial per-vertex counting (pvBcnt) ---------------------- #
@@ -379,7 +379,7 @@ def _receipt_cd_subset(g: BipartiteGraph, cfg: ReceiptConfig,
             new_members = dg.members[live]
             sup_keep = sup_np[live]
             width_max = max(width_max, peel_width)
-            dg = DeviceGraph(g, new_members, cfg, plan=plan)
+            dg = DeviceGraph(g, new_members, cfg, plan=plan, stats=stats)
             stats.dgm_compactions += 1
             alive = jnp.zeros(dg.rows_pad, bool).at[: dg.n_rows].set(True)
             support = jnp.full(dg.rows_pad, _INF, cfg.dtype)
@@ -455,7 +455,7 @@ def _receipt_cd_graph(
 
     subset_id = np.full(n_u, -1, np.int64)
     init_support = np.zeros(n_u, np.float64)
-    dg = DeviceGraph(g, np.arange(n_u), cfg, plan=plan)
+    dg = DeviceGraph(g, np.arange(n_u), cfg, plan=plan, stats=stats)
     stats.wedges_pvbcnt = g.counting_wedge_bound()
 
     with span("cd.count") as sp:

@@ -66,7 +66,6 @@ from ...kernels.ref import EXACT
 from ...kernels.butterfly_sparse import (
     batched_gathered_tile_extents,
     gathered_tile_extents,
-    row_extents,
 )
 from ...utils.spans import fetch, span
 from ..graph import BipartiteGraph
@@ -105,8 +104,9 @@ class ReceiptConfig:
     backend: Optional[str] = None            # kernel backend (None = auto)
     kernel_blocks: Tuple[int, int, int] = (128, 128, 512)
     use_huc: bool = True
-    use_dgm: bool = True                     # DGM: host re-induction per
-    #   subset boundary (cd_dispatch="subset", gated by dgm_row_threshold)
+    use_dgm: bool = True                     # DGM: re-induction per subset
+    #   boundary (cd_dispatch="subset", gated by dgm_row_threshold): the
+    #   host induces the edge list, the device scatters the dense matrix;
     #   or on-device column compaction + c_rcnt re-estimation + staircase
     #   re-tightening at EVERY boundary (cd_dispatch="graph", §2.3)
     degree_sort: bool = True                 # Wang et al. relabel (tile density)
@@ -260,9 +260,12 @@ class RunStats:
     wedges_cd: int = 0              # wedges traversed peeling in CD
     wedges_fd: int = 0              # wedges traversed in FD (see docstring)
     huc_recounts: int = 0
-    dgm_compactions: int = 0        # host DGM re-inductions (subset dispatch)
+    dgm_compactions: int = 0        # DGM re-inductions (subset dispatch)
     dgm_device_compactions: int = 0  # on-device DGM column compactions at
     #                               # subset boundaries (graph dispatch)
+    cd_graph_upload_bytes: int = 0  # host-to-device bytes of the
+    #                               # DeviceGraph builds (edge lists and
+    #                               # O(rows + cols) metadata)
     elided_sweeps: int = 0          # terminal-sweep elision (beyond-paper)
     num_subsets: int = 0
     bounds: List[int] = dataclasses.field(default_factory=list)
@@ -340,6 +343,56 @@ def bucket(n: int, block: int) -> int:
 # ---------------------------------------------------------------------- #
 # jitted device primitives (cached per bucketed shape)
 # ---------------------------------------------------------------------- #
+# The TPU lays a 2-D float32 matrix out in (8, 128) tiles, tile after
+# tile.  A plain 2-D scatter is linearized row-major and then copied into
+# the tiles, two matrices at once; a scatter in the tiles' own order
+# leaves the matrix in place (a bfloat16 one too; tests/test_tpu_compile.py).
+_TILE = (8, 128)
+
+
+def _tile(shape) -> Tuple[int, int]:
+    """The scatter's tile for ``shape``: ``_TILE`` where it divides the
+    matrix, else whole rows (the order is then plain row-major)."""
+    rows, cols = shape
+    return (_TILE[0] if rows % _TILE[0] == 0 else 1,
+            _TILE[1] if cols % _TILE[1] == 0 else cols)
+
+
+@functools.partial(jax.jit, static_argnames=("shape", "dtype"))
+def _scatter_tiles(eu, ev, n_real, shape, dtype):
+    """Dense 0/1 matrix of ``shape``: the first ``n_real`` (u, v) pairs are
+    its ones, in tile order (``scatter_dense``); the rest write 0."""
+    rows, cols = shape
+    tr, tc = _tile(shape)
+    valid = (jnp.arange(eu.shape[0]) < n_real).astype(dtype)
+    a = jnp.zeros((rows // tr, cols // tc, tr, tc), dtype).at[
+        eu // tr, ev // tc, eu % tr, ev % tc].max(valid,
+                                                  indices_are_sorted=True)
+    return a.transpose(0, 2, 1, 3).reshape(shape)
+
+
+def scatter_dense(eu: np.ndarray, ev: np.ndarray, n_pad: int, shape,
+                  dtype):
+    """Dense 0/1 biadjacency of ``shape`` on the device from the
+    (u, v)-sorted, unique edge list ``eu``/``ev``.
+
+    The host orders the edges tile by tile, pads them to ``n_pad`` with
+    copies of the matrix's last cell (in bounds, last in tile order, and
+    written 0) and uploads them as int32; the device scatters them into
+    one matrix.  Returns the matrix and the uploaded edge arrays.
+    """
+    rows, cols = shape
+    tr, tc = _tile(shape)
+    # stable: within a tile the edges keep their (u, v) order
+    order = np.argsort((eu.astype(np.int64) // tr) * (cols // tc)
+                       + ev // tc, kind="stable")
+    pad = n_pad - len(eu)
+    edges = tuple(
+        jnp.asarray(np.append(x[order], np.full(pad, n - 1)).astype(np.int32))
+        for x, n in ((eu, rows), (ev, cols)))
+    return _scatter_tiles(*edges, len(eu), shape, jnp.dtype(dtype)), edges
+
+
 @functools.partial(jax.jit, static_argnames=("backend", "blocks"))
 def support_all(a, alive, ids, kmax, *, backend, blocks):
     """HUC recount / initial count: support of every row w.r.t. alive rows."""
@@ -1236,10 +1289,17 @@ class DeviceGraph:
     and the block-sparse staircase metadata (``kmax`` row-tile column
     extents + ``row_ext`` per-row extents) recomputed at every DGM
     compaction — exactly where compaction makes the staircase steepest.
+
+    The host induces and derives the metadata from the edge list, O(m);
+    the dense matrix is scattered on the device from the uploaded edges
+    (``scatter_dense``), so a build moves O(m + rows + cols) bytes to
+    the device and never O(rows × cols).  ``upload_bytes`` is what it
+    moved; ``stats.cd_graph_upload_bytes`` sums it over the builds.
     """
 
     def __init__(self, g: BipartiteGraph, members: np.ndarray,
-                 cfg: ReceiptConfig, plan=None):
+                 cfg: ReceiptConfig, plan=None,
+                 stats: Optional[RunStats] = None):
         with span("cd.graph") as sp:
             self.cfg = cfg
             bi, bj, bk = cfg.kernel_blocks
@@ -1247,13 +1307,14 @@ class DeviceGraph:
             # wedge (residual degree < 2) — the DGM column compaction
             sub, _ = g.induced_on_u(members, min_degree_v=2)
             dvk = sub.degrees_v()
-            eu, ev = sub.edges_u, sub.edges_v
+            eu, ev = sub.edges_u, sub.edges_v      # sorted by (u, v), unique
 
             self.members = np.asarray(members)
             self.n_rows = len(members)
             self.n_cols = max(int(sub.n_v), 1)
             self.rows_pad = bucket(self.n_rows, max(bi, bj))
             self.cols_pad = bucket(self.n_cols, bk)
+            n_edges = bucket(len(eu), 1024)
             if plan is not None:
                 # DGM re-induction shapes quantize through the plan's
                 # geometric shape floors, so subset re-induction lands on a
@@ -1262,10 +1323,10 @@ class DeviceGraph:
                 # residual-graph size)
                 self.rows_pad = plan.quantize_dim("dgm_rows", self.rows_pad)
                 self.cols_pad = plan.quantize_dim("dgm_cols", self.cols_pad)
+                n_edges = plan.quantize_dim("dgm_edges", n_edges)
 
-            a = np.zeros((self.rows_pad, self.cols_pad), np.float32)
-            a[eu, ev] = 1.0
-            self.a = jnp.asarray(a, dtype=cfg.dtype)
+            self.a, edges = scatter_dense(
+                eu, ev, n_edges, (self.rows_pad, self.cols_pad), cfg.dtype)
             self.ids = jnp.arange(self.rows_pad, dtype=jnp.int32)
             # residual V degrees at construction (everything alive)
             dv_pad = np.zeros(self.cols_pad, np.float32)
@@ -1286,11 +1347,20 @@ class DeviceGraph:
             backend = cfg.backend or kops.default_backend()
             if backend in kops.SPARSE_BACKENDS and bi != bj:
                 raise ValueError("sparse backends require square row tiles")
-            rext = row_extents(a, bk)
+            # a row's extent is one past the k-stripe of its last edge: the
+            # edges are (u, v)-sorted, so that is the row's final edge
+            rext = np.zeros(self.rows_pad, np.int32)
+            last = np.flatnonzero(np.diff(eu, append=self.rows_pad))
+            rext[eu[last]] = ev[last] // bk + 1
             self.row_ext = jnp.asarray(rext)
-            # tile extents = per-tile max of the row extents (one dense pass)
+            # tile extents = per-tile max of the row extents
             self.kmax = jnp.asarray(rext.reshape(-1, bi).max(axis=1))
-            sp.set_metadata(rows=self.rows_pad, cols=self.cols_pad)
+            self.upload_bytes = sum(x.nbytes for x in (
+                *edges, self.dv0, self.w, self.row_ext, self.kmax))
+            if stats is not None:
+                stats.cd_graph_upload_bytes += self.upload_bytes
+            sp.set_metadata(rows=self.rows_pad, cols=self.cols_pad,
+                            edges=n_edges, upload_bytes=self.upload_bytes)
 
     def initial_peel_width(self) -> int:
         """Auto-sized device peel buffer: a quarter of the padded rows

@@ -64,15 +64,27 @@ def recording_cd(*args, **kw):
     return receipt_cd(*args, **kw)
 
 
+graph_on = []
+DeviceGraph = engine.cd.DeviceGraph
+
+
+class RecordingGraph(DeviceGraph):
+    def __init__(self, *args, **kw):
+        super().__init__(*args, **kw)
+        graph_on.append(sorted(d.id for d in self.a.devices()))
+
+
 fd.span = Recording
 dist.shard_level_group = recording_layout
 engine.receipt_cd = recording_cd
+engine.cd.DeviceGraph = RecordingGraph
 
 g = powerlaw_bipartite(512, 256, 4000, seed=5)
 oracle, _ = bup_oracle(g)
 one = Executor(EngineConfig(side="U")).decompose(g)
 cd_one = list(cd_on)
-del opened[:], laid_out[:], cd_on[:]
+graph_one = list(graph_on)
+del opened[:], laid_out[:], cd_on[:], graph_on[:]
 
 dec = Executor(EngineConfig(side="U"),
                mesh=make_mesh((4,), ("data",))).decompose(g)
@@ -93,14 +105,17 @@ out = {
     "laid_out_bytes": list(laid_out),
     "cd_device_one": cd_one,
     "cd_device_mesh": list(cd_on),
+    "graph_devices_one": graph_one,
+    "graph_devices_mesh": list(graph_on),
 }
 
-del cd_on[:]
+del cd_on[:], graph_on[:]
 far = make_mesh((4,), ("data",), devices=jax.devices()[4:8])
 dec_far = Executor(EngineConfig(side="U"), mesh=far).decompose(g)
 out.update(
     far_mesh_devices=sorted(d.id for d in far.devices.flat),
     cd_device_far=list(cd_on),
+    graph_devices_far=list(graph_on),
     far_equals_oracle=bool((dec_far.theta == oracle).all()),
     far_fd_shards=dec_far.stats.fd_shards,
 )
@@ -151,3 +166,13 @@ def test_cd_runs_on_a_device_of_the_mesh(run):
     assert run["cd_device_far"] == [4]
     assert run["far_equals_oracle"]
     assert run["far_fd_shards"] == 4
+
+
+def test_cd_residual_graph_is_built_on_the_cd_device(run):
+    """Every ``DeviceGraph`` build, DGM boundaries included, lands its
+    dense matrix on the chip CD runs on, never on JAX's global default."""
+    for key in ("graph_devices_one", "graph_devices_mesh"):
+        assert len(run[key]) > 1, key
+        assert run[key] == [[0]] * len(run[key]), key
+    assert len(run["graph_devices_far"]) > 1
+    assert run["graph_devices_far"] == [[4]] * len(run["graph_devices_far"])

@@ -127,6 +127,25 @@ def test_subset_dispatch_spans_each_subset_and_device_graph(tmp_path):
         (cd[2] - cd[1]) / 1e9, rel=0.05, abs=2e-3)
 
 
+def test_device_graph_span_carries_its_edges_and_upload_bytes(tmp_path):
+    """Each ``cd.graph`` build reports its padded edge count and the bytes
+    it moved to the device; those sum to ``RunStats.cd_graph_upload_bytes``,
+    and each is the int32 edge list plus ``dv0``, ``w``, ``row_ext`` and
+    ``kmax``, never the dense matrix."""
+    dec, spans = _traced(
+        tmp_path, lambda: _executor(cd_dispatch="subset").decompose(_graph()))
+    graphs = _named(spans, "cd.graph")
+    assert len(graphs) == 1 + dec.stats.dgm_compactions > 1
+    for s in graphs:
+        meta = s[4]
+        assert meta["edges"] >= 1024
+        rows = meta["rows"]
+        assert meta["upload_bytes"] == 8 * meta["edges"] + 4 * meta["cols"] \
+            + 8 * rows + 4 * rows // SMALL_BLOCKS[0]
+    assert sum(s[4]["upload_bytes"] for s in graphs) \
+        == dec.stats.cd_graph_upload_bytes
+
+
 def test_prepeel_span_carries_its_pairs(tmp_path):
     """``fd.prepeel`` reports the pairs its host level deltas traversed,
     the same count as ``RunStats.fd_prepeel_pairs``."""

@@ -1,6 +1,6 @@
-"""Compile rehearsals: the main-path Pallas kernels, compiled for one
-described TPU v5e chip at the production blocks (128, 128, 512) and the
-shapes ``chip_smoke.py`` drives.
+"""Compile rehearsals: the main-path Pallas kernels and the CD build's
+dense scatter, compiled for one described TPU v5e chip at the production
+blocks (128, 128, 512) and the shapes ``chip_smoke.py`` drives.
 
 Nothing runs — the TPU compiler refuses here what it would refuse on the
 chip (VMEM overruns, tiling-misaligned slices, SMEM sizes), at no chip
@@ -8,12 +8,15 @@ time.  The topology is described inside a fixture, never at import: only
 one process may load the TPU library, and every test worker imports this
 file.  The tests skip where no topology can be described.
 """
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 
+from repro.core.engine import peel_loop
 from repro.kernels import butterfly, butterfly_sparse, butterfly_tiled
 
 BLOCKS = (128, 128, 512)
@@ -138,3 +141,29 @@ def test_tiled_update_compiles_at_chip_limits(one_chip, edge):
              ((n_slots, bi, bk), F32), ((n_slots,), I32), ((n_slots,), I32),
              ((n_rt + 1,), I32), ((n_rt, n_ct), I32), ((n_slots,), I32),
              ((n_rt * bi,), F32))
+
+
+@pytest.mark.parametrize("rows, cols, edges, dtype", [
+    (N_U, N_V, 262144, F32),             # the cells' first DGM build
+    (256, N_V, 1024, F32),               # their last
+    (N_U, N_V, 262144, jnp.bfloat16),
+    (65536, 32768, 1 << 20, F32),        # 2^31 cells, 8 GiB
+])
+def test_dense_scatter_fills_one_matrix(one_chip, rows, cols, edges, dtype):
+    # DeviceGraph's build scatters its edges into the matrix in place: the
+    # program holds the matrix once, and its scatter writes into exactly
+    # the matrix's cells (an index past them would break the sorted
+    # promise on the chip)
+    idx = jax.ShapeDtypeStruct((edges,), I32, sharding=one_chip)
+    n = jax.ShapeDtypeStruct((), I32, sharding=one_chip)
+    c = peel_loop._scatter_tiles.lower(idx, idx, n, (rows, cols),
+                                       jnp.dtype(dtype)).compile()
+    matrix = rows * cols * jnp.dtype(dtype).itemsize
+    mem = c.memory_analysis()
+    assert mem.output_size_in_bytes == matrix
+    assert mem.temp_size_in_bytes <= matrix // 64
+    scatters = re.findall(r"= \w+\[([\d,]+)\]\S* scatter\((.*)", c.as_text())
+    assert scatters
+    for dims, rest in scatters:
+        assert math.prod(map(int, dims.split(","))) == rows * cols
+        assert "indices_are_sorted=true" in rest
