@@ -1,23 +1,21 @@
-"""RECEIPT engine benchmark: device-resident vs host-driven sweep loops.
+"""RECEIPT engine benchmark: CD dispatch modes and the ParB baseline.
 
 Runs RECEIPT and the ParB baseline on the synthetic power-law interaction
 graphs (src/repro/data/synthetic.py — the KONECT-shaped workload of the
-paper's Table 3) with the fused ``lax.while_loop`` engine ON and OFF, and
-writes ``BENCH_receipt.json`` with, per graph and engine:
+paper's Table 3) on the fused ``lax.while_loop`` engine, and writes
+``BENCH_receipt.json`` with, per graph and engine:
 
   * wall clock (cold = includes jit, warm = steady-state best-of-3),
   * blocking host round trips (RunStats.host_round_trips) — the
     dispatch-layer analogue of the paper's synchronization counter rho,
   * rho_cd / rho_fd / wedge counters / HUC / DGM / elision counters,
   * FD runtime shape: shape-group count, stack padding waste,
-  * derived reductions (host-loop RTs / device-loop RTs, wall speedups,
-    FD level-peel vs the PR 1 sequential-peel baseline).
+  * derived ratios (graph- vs subset-dispatch CD round trips and wedges).
 
 Engines: ``receipt_device`` (fused per-subset CD loop + FD level-peel,
 the default stack), ``receipt_graph`` (whole-graph single-dispatch CD —
-cd_dispatch="graph", the ISSUE 3 tentpole), ``receipt_fd_b2`` (fused CD
-loop + the PR 1 sequential FD — the FD baseline), ``receipt_host`` /
-``parb_*`` (round-trip comparators).  A separate CD-phase-only
+cd_dispatch="graph"), ``parb_device`` (the ParB min-peel schedule on the
+same engine, the sync-count comparator).  A separate CD-phase-only
 measurement records the tentpole metrics: O(1) blocking host round trips
 per GRAPH for the single-dispatch driver vs >= 1 per subset
 (``cd_phase_round_trips`` / ``derived.cd_rt_graph_total``), and — with
@@ -183,14 +181,9 @@ def bench_graph(name: str, builder, *, partitions: int, check: bool) -> dict:
         theta_ref, _ = bup_oracle(g)
 
     for label, runner, kw in (
-        ("receipt_device", tip_decompose, dict(device_loop=True)),
-        ("receipt_graph", tip_decompose, dict(device_loop=True,
-                                              cd_dispatch="graph")),
-        ("receipt_fd_b2", tip_decompose, dict(device_loop=True,
-                                              fd_mode="b2")),
-        ("receipt_host", tip_decompose, dict(device_loop=False)),
-        ("parb_device", parb_tip_decompose, dict(device_loop=True)),
-        ("parb_host", parb_tip_decompose, dict(device_loop=False)),
+        ("receipt_device", tip_decompose, dict()),
+        ("receipt_graph", tip_decompose, dict(cd_dispatch="graph")),
+        ("parb_device", parb_tip_decompose, dict()),
     ):
         cfg = ReceiptConfig(num_partitions=partitions, backend="xla", **kw)
         theta, stats, cold, warm, fd_warm = _run_engine(runner, g, cfg)
@@ -235,10 +228,8 @@ def bench_graph(name: str, builder, *, partitions: int, check: bool) -> dict:
           f"{cd_rt['graph']['dgm_device_compactions']} device DGM)",
           flush=True)
 
-    ed, eh = rec["engines"]["receipt_device"], rec["engines"]["receipt_host"]
-    ef = rec["engines"]["receipt_fd_b2"]
+    ed = rec["engines"]["receipt_device"]
     eg = rec["engines"]["receipt_graph"]
-    pd, ph = rec["engines"]["parb_device"], rec["engines"]["parb_host"]
     n_sub = max(ed["num_subsets"], 1)
     rec["derived"] = {
         # whole-graph single-dispatch CD: O(1) RTs per graph
@@ -258,40 +249,19 @@ def bench_graph(name: str, builder, *, partitions: int, check: bool) -> dict:
             / max(cd_rt["subset"]["wedges_cd"], 1),
         "cd_graph_dgm_device": cd_rt["graph"]["dgm_device_compactions"],
         "cd_rt_per_subset_device": ed["host_round_trips"] / n_sub,
-        "cd_rt_per_subset_host": eh["host_round_trips"] / n_sub,
-        "cd_round_trip_reduction":
-            eh["host_round_trips"] / max(ed["host_round_trips"], 1),
-        "cd_wall_speedup_warm": eh["wall_warm_s"] / max(ed["wall_warm_s"],
-                                                        1e-9),
-        "parb_round_trip_reduction":
-            ph["host_round_trips"] / max(pd["host_round_trips"], 1),
-        "parb_wall_speedup_warm": ph["wall_warm_s"] / max(pd["wall_warm_s"],
-                                                          1e-9),
-        # FD level-peel vs the PR 1 sequential-peel baseline
         "fd_group_count": ed["fd_groups"],
         "fd_padding_waste": ed["fd_padding_waste"],
         "fd_rho_level": ed["rho_fd"],
-        "fd_rho_seq": ef["rho_fd"],
-        "fd_rho_reduction": ef["rho_fd"] / max(ed["rho_fd"], 1),
-        "fd_wall_speedup_warm":
-            ef["time_fd_warm_s"] / max(ed["time_fd_warm_s"], 1e-9),
     }
     d = rec["derived"]
-    print(f"  -> RT reduction {d['cd_round_trip_reduction']:.1f}x "
-          f"({d['cd_rt_per_subset_host']:.1f} -> "
-          f"{d['cd_rt_per_subset_device']:.1f} per subset; "
+    print(f"  -> {d['cd_rt_per_subset_device']:.1f} RTs per subset; "
           f"single-dispatch CD: {d['cd_rt_subset_total']} -> "
           f"{d['cd_rt_graph_total']} per graph, "
-          f"wedge ratio {d['cd_graph_wedge_ratio']:.3f} vs subset DGM), "
-          f"wall speedup {d['cd_wall_speedup_warm']:.2f}x, "
-          f"ParB RT reduction {d['parb_round_trip_reduction']:.0f}x",
+          f"wedge ratio {d['cd_graph_wedge_ratio']:.3f} vs subset DGM",
           flush=True)
     print(f"  -> FD: {d['fd_group_count']} groups, "
           f"{d['fd_padding_waste']*100:.0f}% padding waste, "
-          f"rho_fd {d['fd_rho_seq']} -> {d['fd_rho_level']} "
-          f"({d['fd_rho_reduction']:.1f}x fewer sweeps), "
-          f"level-peel wall speedup {d['fd_wall_speedup_warm']:.2f}x",
-          flush=True)
+          f"rho_fd {d['fd_rho_level']}", flush=True)
     return rec
 
 
@@ -849,13 +819,10 @@ def main(argv=None) -> int:
     Path(args.out).write_text(json.dumps(payload, indent=2))
     print(f"[bench_receipt] wrote {args.out}")
 
-    largest = results[-1]["derived"]
     largest_cd = results[-1]["cd_phase_round_trips"]["graph"]
-    ok = (largest["cd_round_trip_reduction"] >= 5.0
-          and largest["fd_rho_reduction"] > 1.0
-          # single-dispatch CD: O(1) RTs per graph (2 + a bounded
-          # overflow surcharge), independent of the subset count
-          and largest_cd["host_round_trips"]
+    # single-dispatch CD: O(1) RTs per graph (2 + a bounded overflow
+    # surcharge), independent of the subset count
+    ok = (largest_cd["host_round_trips"]
           <= 2 + OVF_RT_SURCHARGE * largest_cd["overflow_fallbacks"]
           # multi-graph batched decomposition: deterministic dispatch
           # counts and a fully-cached warm fleet (the PR 5 acceptance)
@@ -935,27 +902,9 @@ def main(argv=None) -> int:
               f"exact={service_async['async_exact']}, "
               f"eviction={service_async['eviction']})")
     ok = ok and sa_ok
-    if not args.quick:
-        # wall-clock criteria run on the FULL bench only: --quick is the
-        # per-push CI smoke (scripts/ci.sh quick fails on this exit
-        # code), and shared runners are too noisy to gate on wall time —
-        # the deterministic counters above carry the regression signal
-        # there (scripts/bench_gate.py makes the same call).  The FD
-        # wall criterion targets the LARGEST graph (small stacks are
-        # dominated by fixed dispatch costs, where the sequential
-        # baseline's single fori_loop is hard to beat on CPU); the
-        # deterministic FD signal is fd_rho_reduction (checked above);
-        # on CPU the FD gate allows 10% scheduler noise — the two
-        # engines are flop-parity there and the level-peel win is
-        # structural on latency-bound accelerators.
-        ok = (ok and largest["cd_wall_speedup_warm"] > 1.0
-              and largest["fd_wall_speedup_warm"] > 0.9)
     print(f"[bench_receipt] largest graph: "
-          f"{largest['cd_round_trip_reduction']:.1f}x fewer host round "
-          f"trips, {largest['cd_wall_speedup_warm']:.2f}x warm wall "
-          f"speedup, FD level-peel {largest['fd_wall_speedup_warm']:.2f}x "
-          f"wall / {largest['fd_rho_reduction']:.1f}x fewer sweeps "
-          f"-> {'OK' if ok else 'BELOW TARGET'}")
+          f"{largest_cd['host_round_trips']} CD host round trips "
+          f"(graph dispatch) -> {'OK' if ok else 'BELOW TARGET'}")
     return 0 if ok else 1
 
 

@@ -53,7 +53,7 @@ def _single_cfg():
 
 def _fleet_cfg():
     return EngineConfig(backend="interpret", num_partitions=3,
-                        kernel_blocks=BLOCKS, fd_mode="level")
+                        kernel_blocks=BLOCKS)
 
 
 def main() -> int:

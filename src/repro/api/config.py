@@ -60,13 +60,10 @@ class EngineConfig:
     use_dgm: bool = True
     degree_sort: bool = True
     dgm_row_threshold: float = 0.7
-    fd_mode: str = "level"
     cd_dispatch: str = "subset"
     dtype: str = "float32"
     max_sweeps: int = 100_000
-    device_loop: bool = True
     peel_width: Optional[int] = None
-    fd_overlap: bool = True
     fd_update_mode: str = "auto"
     fd_b2_cells: int = 1 << 24
     representation: str = "auto"
@@ -143,13 +140,6 @@ class EngineConfig:
                 "derived.cd_graph_wedge_ratio).  Enable use_dgm, or use "
                 "cd_dispatch='subset'; for A/B experiments construct a "
                 "raw ReceiptConfig instead.")
-        if self.fd_mode != "level" and not self.device_loop:
-            raise ValueError(
-                f"fd_mode={self.fd_mode!r} with device_loop=False mixes "
-                "the legacy sequential FD with the blocking host CD "
-                "engine — a comparator pairing the benchmarks never "
-                "measure.  Use fd_mode='level', or pin one comparator "
-                "through a raw ReceiptConfig.")
 
     # ------------------------------------------------------------------ #
     # conversions
@@ -201,17 +191,14 @@ class EngineConfig:
                         if self.backend != resolved else repr(resolved))
         lines.append(f"  backend:          {backend_note}")
         shown = {"side", "workload", "backend"}
-        for name in ("num_partitions", "kernel_blocks", "representation",
-                     "cd_dispatch", "fd_mode", "fd_update_mode",
-                     "degree_sort", "use_huc", "use_dgm", "device_loop",
-                     "dtype"):
+        listed = ("num_partitions", "kernel_blocks", "representation",
+                  "cd_dispatch", "fd_update_mode", "degree_sort", "use_huc",
+                  "use_dgm", "dtype")
+        for name in listed:
             val = getattr(self, name)
             flag = "" if val == defaults.get(name) else "   [non-default]"
             lines.append(f"  {name + ':':<17} {val!r}{flag}")
-        shown.update(("num_partitions", "kernel_blocks", "representation",
-                      "cd_dispatch", "fd_mode", "fd_update_mode",
-                      "degree_sort", "use_huc", "use_dgm", "device_loop",
-                      "dtype"))
+        shown.update(listed)
         extras = [f.name for f in dataclasses.fields(self)
                   if f.name not in shown
                   and getattr(self, f.name) != defaults.get(f.name)]

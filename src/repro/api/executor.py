@@ -614,10 +614,6 @@ class Executor:
                 f"workload={self.workload!r} is not mappable — use "
                 "Executor.decompose per graph (the wing FD stack already "
                 "batches its subsets)", dispatch="map")
-        if cfg.fd_mode != "level":
-            raise ValueError(
-                "Executor.map batches graphs through the level-peel "
-                f"loop; set fd_mode='level' (got {cfg.fd_mode!r})")
         if self.mesh is not None:
             raise ValueError(
                 "Executor.map runs single-device; sharding map chunks "

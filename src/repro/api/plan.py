@@ -118,13 +118,11 @@ class ExecutionPlan:
     cd_peel_width0: int              # initial CD gather-buffer width
     cd_host_syncs_bound: int         # O(1) bound for the graph dispatch,
     #                                # O(P) for the subset dispatch
-    fd_mode: str
     fd_update_policy: str            # "auto" | "b2" | "kernel"
     est_fd_groups: List[Dict[str, int]]   # wedge-equipartition ESTIMATE
     est_fd_padding_waste: float
     mesh_shards: int                 # 0 = single device
     degree_sort: bool
-    device_loop: bool
     padded_bytes: int                # device-memory estimate
     workload: str = "tip"            # "tip" (vertex axis) | "wing"
     #                                # (edge axis, DESIGN.md §10) — part
@@ -227,8 +225,8 @@ class ExecutionPlan:
             f"  CD            : dispatch={self.cd_dispatch!r}, "
             f"P={self.num_partitions}, peel_width0={self.cd_peel_width0}, "
             f"host syncs <= {self.cd_host_syncs_bound}\n"
-            f"  FD            : mode={self.fd_mode!r}, "
-            f"update={self.fd_update_policy!r}, est groups: {est} "
+            f"  FD            : update={self.fd_update_policy!r}, "
+            f"est groups: {est} "
             f"(est padding waste {self.est_fd_padding_waste:.0%})\n"
             f"  mesh shards   : {self.mesh_shards or 'single-device'}\n"
             f"  measured      : cd_peel_width="
@@ -470,10 +468,10 @@ class Planner:
             cd_peel_width0=width0,
             cd_host_syncs_bound=(2 if cfg.cd_dispatch == "graph"
                                  else admitted_p + 1),
-            fd_mode=cfg.fd_mode, fd_update_policy=cfg.fd_update_mode,
+            fd_update_policy=cfg.fd_update_mode,
             est_fd_groups=est_groups, est_fd_padding_waste=est_waste,
             mesh_shards=mesh_shards,
-            degree_sort=cfg.degree_sort, device_loop=cfg.device_loop,
+            degree_sort=cfg.degree_sort,
             padded_bytes=padded_bytes,
             representation=representation,
             cost_model=cost_model,
@@ -557,12 +555,11 @@ class Planner:
             cd_peel_width0=width0,
             cd_host_syncs_bound=(2 if cfg.cd_dispatch == "graph"
                                  else admitted_p + 1),
-            fd_mode=cfg.fd_mode, fd_update_policy="kernel",
+            fd_update_policy="kernel",
             est_fd_groups=est_groups, est_fd_padding_waste=est_waste,
             mesh_shards=mesh_shards,
             degree_sort=False,          # edge axis never relabels (it
             #                           # would permute canonical edge ids)
-            device_loop=cfg.device_loop,
             padded_bytes=padded_bytes,
             representation="dense",
             cost_model=cost_model,

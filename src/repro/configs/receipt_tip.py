@@ -19,7 +19,6 @@ def full_config() -> ReceiptConfig:
         use_huc=True,
         use_dgm=True,
         degree_sort=True,
-        fd_mode="level",      # batched level-peel on the unified core
     )
 
 

@@ -107,11 +107,10 @@ def receipt_cd(
     initialization vector (Alg. 3 line 7) and bounds[i] = theta(i+1) lower
     bounds, bounds[-1] > theta_max.
 
-    With ``cfg.device_loop`` (default) each subset's sweep loop runs
-    device-resident (see ``device_peel_loop``); the host syncs ONCE per
-    subset to snapshot supports (needed for the FD init vector and findHi
-    anyway).  ``device_loop=False`` preserves the blocking host-driven
-    engine for apples-to-apples round-trip benchmarks.
+    Each subset's sweep loop runs device-resident (see
+    ``device_peel_loop``); the host syncs ONCE per subset to snapshot
+    supports (needed for the FD init vector and findHi anyway), plus once
+    per peel-buffer overflow, which ``host_sweep`` replays exactly.
 
     checkpoint_cb(state): called with a cd_checkpoint_state pytree at
     every subset boundary.  resume_state: continue an interrupted run
@@ -138,15 +137,11 @@ def receipt_cd(
             "progress and would break Theorem 1's range containment")
     if cfg.cd_dispatch not in ("subset", "graph"):
         raise ValueError(f"unknown cd_dispatch {cfg.cd_dispatch!r}")
-    if cfg.cd_dispatch == "graph":
-        if not cfg.device_loop:
-            raise ValueError(
-                "cd_dispatch='graph' runs the whole CD phase on device "
-                "and requires device_loop=True")
-        if checkpoint_cb is not None or resume_state is not None:
-            raise ValueError(
-                "CD checkpointing captures subset-boundary state on the "
-                "host; use cd_dispatch='subset'")
+    if cfg.cd_dispatch == "graph" and (checkpoint_cb is not None
+                                       or resume_state is not None):
+        raise ValueError(
+            "CD checkpointing captures subset-boundary state on the "
+            "host; use cd_dispatch='subset'")
     with span("cd", dispatch=cfg.cd_dispatch) as sp:
         if cfg.cd_dispatch == "graph":
             out = _receipt_cd_graph(g, cfg, stats, plan=plan)
@@ -250,113 +245,96 @@ def _receipt_cd_subset(g: BipartiteGraph, cfg: ReceiptConfig,
 
             sweeps = 0
             covered_wedges = 0.0
-            if cfg.device_loop:
-                # -------- device-resident sweep loop (O(1) syncs) ------ #
-                # the subset's FIRST sweep peels the whole initial range; its
-                # size is already known from the host snapshot, so size the
-                # peel buffer to fit it and overflow only on larger cascades
-                # (an explicit cfg.peel_width — or a plan's measured width,
-                # which must stay data-independent to keep the trace cache
-                # hitting — pins the initial width instead)
-                if cfg.peel_width is None and width_hint is None:
-                    n_first = int((alive_np & (sup_np < hi)).sum())
-                    peel_width = max(peel_width, min(
-                        dg.rows_pad,
-                        bucket(max(n_first, blocks[1]), blocks[1]),
-                    ))
-                if fault_point("peel_buffer", dispatch="subset", subset=i,
-                               backend=backend):
-                    # injected sizing fault: undersize the buffer to the
-                    # smallest width the backend accepts (one row on xla,
-                    # one block tile on the kernel routes) so the overflow
-                    # replay path is forced on any larger sweep (degrade-
-                    # style point — results stay exact through the replay +
-                    # retry-with-widening)
-                    peel_width = 1 if backend == "xla" else blocks[1]
-                replays = 0
-                while True:
-                    fault_point("kernel_launch", KernelBackendError,
-                                dispatch="subset", subset=i,
-                                backend=backend)
-                    (support, alive, dv, _th, peeled, d_rho, d_wedges,
-                     d_hucs, d_elided, d_covered, _d_sweeps,
-                     ovf) = device_peel_loop(
-                        dg.a, dg.ids, dg.row_ext, dg.kmax, support, alive,
-                        dv, jnp.zeros(dg.rows_pad, jnp.float32), hi, lo,
-                        dg.c_rcnt, 0,
-                        backend=backend, blocks=blocks, use_huc=cfg.use_huc,
-                        peel_width=peel_width, max_sweeps=cfg.max_sweeps,
-                        minmode=False,
-                    )
-                    stats.device_loop_calls += 1
-                    (peeled_np, alive_np, sup_f32, d_rho, d_wedges, d_hucs,
-                     d_elided, d_covered, ovf_h) = fetch(
-                        stats, (peeled, alive, support, d_rho, d_wedges,
-                                d_hucs, d_elided, d_covered, ovf),
-                        "cd.subset")
-                    sup_np = np.asarray(sup_f32, np.float64)
-                    stats.rho_cd += int(d_rho)
-                    stats.wedges_cd += int(d_wedges)
-                    stats.huc_recounts += int(d_hucs)
-                    stats.elided_sweeps += int(d_elided)
-                    sweeps += int(d_rho)
-                    covered_wedges += float(d_covered)
-                    subset_id[dg.members[np.where(peeled_np)[0]]] = i
-                    if bool(ovf_h):
-                        # peel buffer overflow: replay this one sweep on
-                        # the host at the precise bucket, re-enter with a
-                        # wider buffer (bounded retry-with-widening,
-                        # DESIGN.md §7)
-                        replays += 1
-                        if replays > _MAX_OVERFLOW_REPLAYS:
-                            raise PeelOverflowError(
-                                f"peel-buffer overflow replay made no "
-                                f"progress after {_MAX_OVERFLOW_REPLAYS} "
-                                f"widenings (width={peel_width}, "
-                                f"rows_pad={dg.rows_pad})",
-                                dispatch="subset", subset=i, backend=backend,
-                                peel_width=peel_width, rows_pad=dg.rows_pad)
-                        stats.overflow_fallbacks += 1
-                        support, alive, info = host_sweep(
-                            dg, cfg, stats, support, alive, hi, lo, backend,
-                            blocks)
-                        if info is not None:
-                            covered_wedges += info["c_peel"]
-                            sweeps += 1
-                            rows = info["peel_np"].nonzero()[0]
-                            subset_id[dg.members[rows]] = i
-                        dv = residual_dv(dg.a, alive)
-                        sup_np, alive_np = fetch(stats, (support, alive),
-                                                 "cd.replay")
-                        sup_np = np.asarray(sup_np, np.float64)
-                        peel_width = min(dg.rows_pad, peel_width * 2)
-                        continue
-                    # max_sweeps valve: caps ONE invocation, never the
-                    # subset — a cap-exit with range left re-enters
-                    # (Theorem 1 needs [lo, hi) fully drained before the
-                    # bound is recorded)
-                    if not (alive_np & (sup_np < hi)).any():
-                        break
-                    if int(d_rho) == 0:
-                        raise RuntimeError(
-                            "CD device loop made no progress on a non-empty "
-                            "range (max_sweeps misconfigured?)")
-            else:
-                # -------- pre-PR engine: blocking host-driven sweeps --- #
-                # (no valve here: the host regains control at every sweep,
-                # and each sweep peels >= 1 row, so the loop terminates in
-                # <= n_rows sweeps — draining fully preserves Theorem 1)
-                while True:
+            # -------- device-resident sweep loop (O(1) syncs) ---------- #
+            # the subset's FIRST sweep peels the whole initial range; its
+            # size is already known from the host snapshot, so size the
+            # peel buffer to fit it and overflow only on larger cascades
+            # (an explicit cfg.peel_width — or a plan's measured width,
+            # which must stay data-independent to keep the trace cache
+            # hitting — pins the initial width instead)
+            if cfg.peel_width is None and width_hint is None:
+                n_first = int((alive_np & (sup_np < hi)).sum())
+                peel_width = max(peel_width, min(
+                    dg.rows_pad,
+                    bucket(max(n_first, blocks[1]), blocks[1]),
+                ))
+            if fault_point("peel_buffer", dispatch="subset", subset=i,
+                           backend=backend):
+                # injected sizing fault: undersize the buffer to the
+                # smallest width the backend accepts (one row on xla,
+                # one block tile on the kernel routes) so the overflow
+                # replay path is forced on any larger sweep (degrade-
+                # style point — results stay exact through the replay +
+                # retry-with-widening)
+                peel_width = 1 if backend == "xla" else blocks[1]
+            replays = 0
+            while True:
+                fault_point("kernel_launch", KernelBackendError,
+                            dispatch="subset", subset=i,
+                            backend=backend)
+                (support, alive, dv, _th, peeled, d_rho, d_wedges,
+                 d_hucs, d_elided, d_covered, _d_sweeps,
+                 ovf) = device_peel_loop(
+                    dg.a, dg.ids, dg.row_ext, dg.kmax, support, alive,
+                    dv, jnp.zeros(dg.rows_pad, jnp.float32), hi, lo,
+                    dg.c_rcnt, 0,
+                    backend=backend, blocks=blocks, use_huc=cfg.use_huc,
+                    peel_width=peel_width, max_sweeps=cfg.max_sweeps,
+                    minmode=False,
+                )
+                stats.device_loop_calls += 1
+                (peeled_np, alive_np, sup_f32, d_rho, d_wedges, d_hucs,
+                 d_elided, d_covered, ovf_h) = fetch(
+                    stats, (peeled, alive, support, d_rho, d_wedges,
+                            d_hucs, d_elided, d_covered, ovf),
+                    "cd.subset")
+                sup_np = np.asarray(sup_f32, np.float64)
+                stats.rho_cd += int(d_rho)
+                stats.wedges_cd += int(d_wedges)
+                stats.huc_recounts += int(d_hucs)
+                stats.elided_sweeps += int(d_elided)
+                sweeps += int(d_rho)
+                covered_wedges += float(d_covered)
+                subset_id[dg.members[np.where(peeled_np)[0]]] = i
+                if bool(ovf_h):
+                    # peel buffer overflow: replay this one sweep on
+                    # the host at the precise bucket, re-enter with a
+                    # wider buffer (bounded retry-with-widening,
+                    # DESIGN.md §7)
+                    replays += 1
+                    if replays > _MAX_OVERFLOW_REPLAYS:
+                        raise PeelOverflowError(
+                            f"peel-buffer overflow replay made no "
+                            f"progress after {_MAX_OVERFLOW_REPLAYS} "
+                            f"widenings (width={peel_width}, "
+                            f"rows_pad={dg.rows_pad})",
+                            dispatch="subset", subset=i, backend=backend,
+                            peel_width=peel_width, rows_pad=dg.rows_pad)
+                    stats.overflow_fallbacks += 1
                     support, alive, info = host_sweep(
                         dg, cfg, stats, support, alive, hi, lo, backend,
                         blocks)
-                    if info is None:
-                        break
-                    sweeps += 1
-                    covered_wedges += info["c_peel"]
-                    subset_id[dg.members[info["peel_np"].nonzero()[0]]] = i
-                sup_np, alive_np = fetch(stats, (support, alive), "cd.host")
-                sup_np = np.asarray(sup_np, np.float64)
+                    if info is not None:
+                        covered_wedges += info["c_peel"]
+                        sweeps += 1
+                        rows = info["peel_np"].nonzero()[0]
+                        subset_id[dg.members[rows]] = i
+                    dv = residual_dv(dg.a, alive)
+                    sup_np, alive_np = fetch(stats, (support, alive),
+                                             "cd.replay")
+                    sup_np = np.asarray(sup_np, np.float64)
+                    peel_width = min(dg.rows_pad, peel_width * 2)
+                    continue
+                # max_sweeps valve: caps ONE invocation, never the
+                # subset — a cap-exit with range left re-enters
+                # (Theorem 1 needs [lo, hi) fully drained before the
+                # bound is recorded)
+                if not (alive_np & (sup_np < hi)).any():
+                    break
+                if int(d_rho) == 0:
+                    raise RuntimeError(
+                        "CD device loop made no progress on a non-empty "
+                        "range (max_sweeps misconfigured?)")
 
         stats.sweeps_per_subset.append(sweeps)
         bounds.append(hi)

@@ -9,7 +9,7 @@ whole current-minimum support level of every still-live subset in the
 stack — the ParButterfly / PBNG peel granularity, vmapped over the shape
 group and dispatched through the grouped butterfly kernels.
 
-Runtime structure (``fd_mode="level"``, the default — DESIGN.md §2.2):
+Runtime structure (DESIGN.md §2.2):
 
 * **iterated host pre-peel** (``pre_peel_tasks``): up to
   ``cfg.fd_prepeel_levels`` peel levels of every subset are resolved
@@ -28,7 +28,7 @@ Runtime structure (``fd_mode="level"``, the default — DESIGN.md §2.2):
   schedule — DESIGN.md §2.0);
 * **double-buffered group dispatch**: the host induces and stacks the
   NEXT group's subgraphs while the device peels the current group (JAX
-  async dispatch; ``cfg.fd_overlap`` gates it for benchmarking);
+  async dispatch);
 * ``RunStats.rho_fd`` counts actual level sweeps, ``RunStats.wedges_fd``
   the dynamically traversed wedges (sum of per-sweep C_peel) — both were
   previously static placeholders.
@@ -56,12 +56,6 @@ groups), placed on the shards (an ``fd.shard`` span) and peeled under
 stack bytes are reconciled into ``RunStats.fd_shard_rho`` /
 ``fd_shard_wedges`` / ``fd_shard_bytes`` and tip numbers are
 bit-identical to the local path.
-
-The legacy engines are preserved as ``fd_mode="b2"`` (dense (M, M)
-shared-butterfly stacks, one-vertex-per-step ``fori_loop``) and
-``fd_mode="matvec"`` (recompute one B2 row per step): they are the
-equivalence comparators (tests/test_fd_engine.py) and the PR 1 baseline
-for benchmarks/bench_receipt.py.
 """
 from __future__ import annotations
 
@@ -80,7 +74,6 @@ from ...utils.spans import fetch, span
 from ..graph import BipartiteGraph, pad_to_multiple
 from ..scheduler import pack_by_shape
 from .peel_loop import (
-    _INF,
     ReceiptConfig,
     RunStats,
     batched_level_loop,
@@ -88,69 +81,6 @@ from .peel_loop import (
 )
 
 __all__ = ["receipt_fd", "build_fd_tasks", "build_level_stack"]
-
-
-# ---------------------------------------------------------------------- #
-# legacy sequential peels (fd_mode="b2" / "matvec"; PR 1 comparators)
-# ---------------------------------------------------------------------- #
-def _fd_peel_b2(b2, sup0, n_members, lo):
-    """Exact sequential bottom-up peel of one padded subset (B2 mode).
-
-    b2: (M, M) pairwise shared butterflies (zero diag, zero on padding);
-    sup0: (M,) FD-initialized supports (+inf padding); returns theta (M,).
-    """
-    mm = b2.shape[0]
-
-    def body(t, st):
-        sup, alive, theta = st
-        masked = jnp.where(alive, sup, _INF)
-        u = jnp.argmin(masked)
-        th = jnp.maximum(masked[u], lo)
-        do = t < n_members
-        theta = jnp.where(do, theta.at[u].set(th), theta)
-        new_sup = jnp.maximum(sup - b2[u], th)
-        sup = jnp.where(do & alive, new_sup, sup)
-        alive = jnp.where(do, alive.at[u].set(False), alive)
-        return sup, alive, theta
-
-    alive0 = jnp.arange(mm) < n_members
-    theta0 = jnp.zeros(mm, sup0.dtype)
-    _, _, theta = jax.lax.fori_loop(0, mm, body, (sup0, alive0, theta0))
-    return theta
-
-
-_fd_peel_b2_vm = jax.jit(jax.vmap(_fd_peel_b2, in_axes=(0, 0, 0, 0)))
-
-
-def _fd_peel_matvec(a_sub, sup0, n_members, lo):
-    """Exact sequential peel recomputing one B2 row per step (matvec mode).
-
-    a_sub: (M, C) induced biadjacency; avoids materializing (M, M).
-    """
-    mm = a_sub.shape[0]
-
-    def body(t, st):
-        sup, alive, theta = st
-        masked = jnp.where(alive, sup, _INF)
-        u = jnp.argmin(masked)
-        th = jnp.maximum(masked[u], lo)
-        do = t < n_members
-        w_row = a_sub @ a_sub[u]                       # (M,) wedge counts
-        b2_row = w_row * (w_row - 1.0) * 0.5
-        b2_row = b2_row.at[u].set(0.0)
-        new_sup = jnp.maximum(sup - b2_row, th)
-        theta = jnp.where(do, theta.at[u].set(th), theta)
-        sup = jnp.where(do & alive, new_sup, sup)
-        alive = jnp.where(do, alive.at[u].set(False), alive)
-        return sup, alive, theta
-
-    alive0 = jnp.arange(mm) < n_members
-    theta0 = jnp.zeros(mm, sup0.dtype)
-    _, _, theta = jax.lax.fori_loop(0, mm, body, (sup0, alive0, theta0))
-    return theta
-
-
-_fd_peel_matvec_vm = jax.jit(jax.vmap(_fd_peel_matvec, in_axes=(0, 0, 0, 0)))
 
 
 # ---------------------------------------------------------------------- #
@@ -469,15 +399,7 @@ def receipt_fd(
     (``_run_level_groups_mesh``); tip numbers are identical to the
     single-device path and per-shard loads are reconciled into
     ``stats.fd_shard_rho`` / ``fd_shard_wedges`` (DESIGN.md §4).
-    Requires ``fd_mode="level"`` — the legacy sequential engines are
-    single-device comparators only.
     """
-    if cfg.fd_mode not in ("level", "b2", "matvec"):
-        raise ValueError(f"unknown fd_mode {cfg.fd_mode!r}")
-    if mesh is not None and cfg.fd_mode != "level":
-        raise ValueError(
-            "mesh-sharded FD runs the batched level-peel loop; set "
-            f"fd_mode='level' (got {cfg.fd_mode!r})")
     if cfg.max_sweeps < 1:
         raise ValueError(
             f"max_sweeps must be >= 1 (got {cfg.max_sweeps}): the valve "
@@ -487,27 +409,12 @@ def receipt_fd(
         backend = cfg.backend or kops.default_backend()
 
         tasks = build_fd_tasks(g, subset_id, bounds, stats)
-        if cfg.fd_mode != "level":
-            stats.wedges_fd += int(sum(t["wedges"] for t in tasks))
-
-        if cfg.fd_mode == "level":
-            if mesh is not None:
-                theta = _run_level_groups_mesh(tasks, init_support, cfg,
-                                               stats, theta, mesh, plan=plan)
-            else:
-                theta = _run_level_groups(tasks, init_support, cfg, backend,
-                                          stats, theta, plan=plan)
+        if mesh is not None:
+            theta = _run_level_groups_mesh(tasks, init_support, cfg, stats,
+                                           theta, mesh, plan=plan)
         else:
-            # workload-aware scheduling: equal-padded stacks (LPT analog)
-            groups = pack_by_shape(
-                tasks,
-                size_of=lambda t: (len(t["members"]), max(t["sub"].n_v, 1)),
-                weight_of=lambda t: t["wedges"],
-                bucket=lambda n: bucket(n, 8),
-            )
-            stats.fd_groups = len(groups)
-            theta = _run_legacy_groups(groups, init_support, cfg, stats,
-                                       theta)
+            theta = _run_level_groups(tasks, init_support, cfg, backend,
+                                      stats, theta, plan=plan)
     stats.time_fd = sp.seconds
     return theta
 
@@ -644,10 +551,7 @@ def _run_level_groups(tasks, init_support, cfg, backend, stats, theta,
         out = launch(built)                     # async dispatch
         if pending is not None:
             drain(*pending)
-        if cfg.fd_overlap:
-            pending = (built, out)              # fetch AFTER next build
-        else:
-            drain(built, out)
+        pending = (built, out)                  # fetch AFTER next build
     if pending is not None:
         drain(*pending)
 
@@ -783,10 +687,7 @@ def _run_level_groups_mesh(tasks, init_support, cfg, stats, theta, mesh,
         used += built["used_cells"]
         if pending is not None:
             drain(*pending)
-        if cfg.fd_overlap:
-            pending = (built, sharded, slots, out)  # fetch AFTER next build
-        else:
-            drain(built, sharded, slots, out)
+        pending = (built, sharded, slots, out)  # fetch AFTER next build
     if pending is not None:
         drain(*pending)
 
@@ -794,50 +695,4 @@ def _run_level_groups_mesh(tasks, init_support, cfg, stats, theta, mesh,
     stats.fd_shard_rho = [int(x) for x in shard_rho]
     stats.fd_shard_wedges = [float(x) for x in shard_wedges]
     stats.fd_shard_bytes = [int(x) for x in shard_bytes]
-    return theta
-
-
-def _run_legacy_groups(groups, init_support, cfg, stats, theta):
-    """PR 1 engines: vmapped one-vertex-per-step sequential peels."""
-    padded = used = 0
-    for group in groups:
-        mm = max(bucket(max(len(t["members"]) for t in group), 8), 8)
-        cc = max(bucket(max(t["sub"].n_v for t in group), 8), 8)
-        n_g = len(group)
-        sup0 = np.full((n_g, mm), np.inf, np.float64)
-        nmem = np.zeros(n_g, np.int32)
-        los = np.zeros(n_g, np.float64)
-        a_stack = np.zeros((n_g, mm, cc), np.float32)
-        for k, t in enumerate(group):
-            mems = t["members"]
-            nmem[k] = len(mems)
-            los[k] = t["lo"]
-            sup0[k, : len(mems)] = init_support[mems]
-            s = t["sub"]
-            a_stack[k, s.edges_u, s.edges_v] = 1.0
-        padded += n_g * mm * cc
-        used += int(sum(len(t["members"]) * max(t["sub"].n_v, 1)
-                        for t in group))
-
-        a_dev = jnp.asarray(a_stack, cfg.dtype)
-        sup_dev = jnp.asarray(sup0, cfg.dtype)
-        nm_dev = jnp.asarray(nmem)
-        lo_dev = jnp.asarray(los, cfg.dtype)
-        if cfg.fd_mode == "b2":
-            backend = kops.resolve_backend(cfg.backend)
-            bi, bj, bk = cfg.kernel_blocks
-            aligned = (mm % bi == 0 and mm % bj == 0 and cc % bk == 0)
-            b2 = kops.b2_stack(
-                a_dev.astype(jnp.float32),
-                backend=backend if aligned else "xla",
-                blocks=cfg.kernel_blocks).astype(cfg.dtype)
-            th = _fd_peel_b2_vm(b2, sup_dev, nm_dev, lo_dev)
-        else:
-            th = _fd_peel_matvec_vm(a_dev, sup_dev, nm_dev, lo_dev)
-        th_np = np.asarray(fetch(stats, th, "fd.legacy"), np.float64)
-        stats.rho_fd += int(nmem.sum())       # one sync-round per peel step
-        for k, t in enumerate(group):
-            theta[t["members"]] = th_np[k, : nmem[k]]
-
-    stats.fd_padding_waste = 1.0 - used / padded if padded else 0.0
     return theta

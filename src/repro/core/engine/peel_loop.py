@@ -48,8 +48,8 @@ batched loop through the grouped entry point
 subsets, staircase extents per group member for the sparse backends).
 
 `DeviceGraph` (the bucketed residual-graph container) and ``host_sweep``
-(the blocking host-driven sweep: pre-PR engine, overflow fallback and
-bench comparator) complete the module.
+(one blocking host-driven sweep: the peel-buffer overflow replay)
+complete the module.
 """
 from __future__ import annotations
 
@@ -111,22 +111,18 @@ class ReceiptConfig:
     #   re-tightening at EVERY boundary (cd_dispatch="graph", §2.3)
     degree_sort: bool = True                 # Wang et al. relabel (tile density)
     dgm_row_threshold: float = 0.7           # re-induce when alive < thresh*rows
-    fd_mode: str = "level"                   # "level" (batched level-peel)
-    #                                        # | "b2" | "matvec" (legacy seq)
     cd_dispatch: str = "subset"              # "subset": one device loop per
     #   CD subset, findHi on the host snapshot (DGM + checkpointing live
     #   here); "graph": the WHOLE CD phase is one dispatch — findHi runs
     #   on device (kernels.ops.find_hi_device) and the host blocks O(1)
-    #   times per graph (DESIGN.md §2.3; requires device_loop=True)
+    #   times per graph (DESIGN.md §2.3)
     dtype: Any = jnp.float32
     max_sweeps: int = 100_000                # valve: bounds ONE device-loop
     #   invocation (never the schedule — drivers re-enter on cap-exit,
     #   so Theorem 1's range containment survives any cap >= 1)
-    device_loop: bool = True                 # fused lax.while_loop sweep engine
     peel_width: Optional[int] = None         # device peel buffer (None = auto;
     #   CD sizes it to the first sweep of each subset from the host
     #   snapshot, FD to mm/8 — both bucketed, doubled on overflow)
-    fd_overlap: bool = True                  # double-buffered FD group dispatch
     fd_update_mode: str = "auto"             # level-peel support updates:
     #   "auto"   cost model: precompute the (G, M, M) B2 stack when it fits
     #            fd_b2_cells, else the grouped butterfly kernel (the HUC
@@ -165,9 +161,9 @@ class ReceiptConfig:
     def __post_init__(self):
         """Validate every knob AT CONSTRUCTION (PR 5 satellite): the
         pre-PR behavior deferred checks to whichever driver happened to
-        read a knob first (``fd_mode`` only in ``receipt_fd``,
-        ``cd_dispatch`` only in ``receipt_cd``, ``backend`` nowhere — a
-        typo'd backend silently routed to the compiled pallas kernel).
+        read a knob first (``cd_dispatch`` only in ``receipt_cd``,
+        ``backend`` nowhere — a typo'd backend silently routed to the
+        compiled pallas kernel).
         ``repro.api.EngineConfig`` layers stricter cross-knob rules on
         top; this is the floor every config object must clear.
         """
@@ -184,18 +180,10 @@ class ReceiptConfig:
             raise ValueError(
                 f"sparse backends require square row tiles (bi == bj), "
                 f"got kernel_blocks={self.kernel_blocks!r}")
-        if self.fd_mode not in ("level", "b2", "matvec"):
-            raise ValueError(
-                f"unknown fd_mode {self.fd_mode!r}: expected 'level', "
-                "'b2' or 'matvec'")
         if self.cd_dispatch not in ("subset", "graph"):
             raise ValueError(
                 f"unknown cd_dispatch {self.cd_dispatch!r}: expected "
                 "'subset' or 'graph'")
-        if self.cd_dispatch == "graph" and not self.device_loop:
-            raise ValueError(
-                "cd_dispatch='graph' runs the whole CD phase on device "
-                "and requires device_loop=True")
         if self.fd_update_mode not in ("auto", "b2", "kernel"):
             raise ValueError(
                 f"unknown fd_update_mode {self.fd_update_mode!r}: "
@@ -245,10 +233,8 @@ class RunStats:
     """The paper's evaluation counters (Table 3 / Figs 5-9).
 
     ``rho_fd`` counts FD peel sweeps: level-peel sweeps summed over
-    subsets in ``fd_mode="level"``, sequential peel steps (one per
-    member) in the legacy modes.  ``wedges_fd`` is the number of wedges
-    DYNAMICALLY traversed by the FD level-peel loop (sum of per-sweep
-    C_peel); the legacy modes keep the static induced-subgraph bound.
+    subsets.  ``wedges_fd`` is the number of wedges DYNAMICALLY
+    traversed by the FD level-peel loop (sum of per-sweep C_peel).
     ``subset_wedges_fd`` always records the static per-subset bound —
     it is the scheduler's workload proxy, known before peeling.
     """
@@ -1376,7 +1362,7 @@ class DeviceGraph:
 
 
 # ---------------------------------------------------------------------- #
-# host-driven sweep (pre-PR engine; also the bucket-overflow fallback)
+# host-driven sweep (the peel-buffer overflow replay)
 # ---------------------------------------------------------------------- #
 def host_sweep(dg, cfg: ReceiptConfig, stats: RunStats,
                support, alive, hi: float, lo: float, backend, blocks,

@@ -537,10 +537,6 @@ def wing_decompose_engine(
     stats = RunStats()
     if g.m == 0:
         return np.zeros(0, np.int64), stats
-    if cfg.cd_dispatch == "graph" and not cfg.device_loop:
-        raise ValueError(
-            "cd_dispatch='graph' runs the whole CD phase on device "
-            "and requires device_loop=True")
     with span("cd", dispatch=cfg.cd_dispatch) as sp:
         if cfg.cd_dispatch == "graph":
             subset_id, bounds, es = _receipt_wing_cd_graph(g, cfg, stats,

@@ -59,7 +59,6 @@ def tip_decompose(
 
     td = api.decompose(g, cfg, side=side, mesh=mesh)
     return td.theta, td.stats
-from .engine.fd import _fd_peel_b2, _fd_peel_matvec  # noqa: F401 (compat)
 from .engine.peel_loop import (  # noqa: F401 (compat)
     apply_delta,
     residual_dv,

@@ -65,7 +65,7 @@ def test_engine_config_rejects_unknown_keys_with_hint():
     dict(side="W"),
     dict(dtype="float64"),
     dict(backend="palas"),                       # typo: actionable error
-    dict(fd_mode="Level"),
+    dict(representation="sparse"),
     dict(cd_dispatch="Graph"),
     dict(num_partitions=0),
     dict(max_sweeps=0),
@@ -85,8 +85,6 @@ def test_engine_config_rejects_conflicting_knobs():
     ReceiptConfig keeps permitting them for A/B tests)."""
     with pytest.raises(ValueError, match="use_dgm"):
         _cfg(cd_dispatch="graph", use_dgm=False)
-    with pytest.raises(ValueError, match="device_loop"):
-        _cfg(cd_dispatch="graph", device_loop=False)
     # the engine-layer config allows the A/B combination
     ReceiptConfig(cd_dispatch="graph", use_dgm=False)
 
@@ -114,7 +112,6 @@ def test_plan_surfaces_execution_structure():
     assert plan.cd_dispatch == "subset"
     assert plan.num_partitions == 8
     assert plan.cd_peel_width0 >= 8
-    assert plan.fd_mode == "level"
     assert plan.est_fd_groups, "planner must estimate FD shape groups"
     assert all(g_["rows"] % 8 == 0 for g_ in plan.est_fd_groups)
     assert 0.0 <= plan.est_fd_padding_waste < 1.0
@@ -370,11 +367,6 @@ def test_map_respects_stack_cell_budget():
     for g, td in zip(graphs, tds):
         tb, _ = bup_oracle(g)
         np.testing.assert_array_equal(td.theta, tb)
-
-
-def test_map_rejects_legacy_fd_modes():
-    with pytest.raises(ValueError, match="fd_mode"):
-        Executor(_cfg(fd_mode="b2")).map([GRAPH_CASES["fig1"]()])
 
 
 # --------------------------------------------------------------------- #

@@ -22,7 +22,7 @@ RECEIPT_EXPORTS = [
     "batched_level_loop", "host_sweep", "bucket", "find_hi_np",
     "_DeviceGraph", "_cd_device_loop", "_host_sweep", "_bucket",
     "_find_hi_np", "_support_all", "_support_delta", "_sweep_info",
-    "_residual_dv", "_apply_delta", "_fd_peel_b2", "_fd_peel_matvec",
+    "_residual_dv", "_apply_delta",
 ]
 
 

@@ -1,6 +1,6 @@
-"""FD level-peel engine: equivalence vs the legacy sequential peels,
-counter semantics, kernel-path fallbacks, and the scheduler's Graham
-bound (ISSUE 2 satellite suite)."""
+"""FD level-peel engine: equivalence with the BUP oracle on CD's
+partition, counter semantics, kernel-path fallbacks, and the scheduler's
+Graham bound."""
 import dataclasses
 import itertools
 
@@ -33,45 +33,39 @@ def _cfg(**kw):
     return ReceiptConfig(**base)
 
 
-def _fd_all_modes(g, cfg):
-    """Run CD once, then FD under every mode on the same partition."""
+def _fd_on_cd(g, cfg):
+    """Run CD, then FD on its partition: (FD theta, FD-only stats)."""
+    sid, init_sup, bounds, _ = receipt_cd(g, cfg, RunStats())
     stats = RunStats()
-    sid, init_sup, bounds, _ = receipt_cd(g, cfg, stats)
-    out = {}
-    for mode in ("level", "b2", "matvec"):
-        mstats = RunStats()
-        mcfg = dataclasses.replace(cfg, fd_mode=mode)
-        out[mode] = (receipt_fd(g, sid, init_sup, bounds, mcfg, mstats),
-                     mstats)
-    return out
+    return receipt_fd(g, sid, init_sup, bounds, cfg, stats), stats
 
 
 # --------------------------------------------------------------------- #
-# level-peel vs legacy sequential peels (identical theta)
+# level-peel on CD's partition vs the BUP oracle (identical theta)
 # --------------------------------------------------------------------- #
 @pytest.mark.parametrize("case", ["powerlaw", "fig1"])
 def test_level_peel_equals_legacy_peels(case):
-    """The new batched level-peel must reproduce the legacy b2 and matvec
-    sequential peels EXACTLY on the same CD partition."""
+    """The batched level-peel reproduces the exact BUP tip numbers on
+    CD's partition: each independent subset peels to its true theta."""
     g = GRAPH_CASES[case]()
-    out = _fd_all_modes(g, _cfg())
-    th_level = out["level"][0]
-    np.testing.assert_array_equal(th_level, out["b2"][0])
-    np.testing.assert_array_equal(th_level, out["matvec"][0])
+    tb, _ = bup_oracle(g)
+    th, _ = _fd_on_cd(g, _cfg())
+    np.testing.assert_array_equal(th, tb)
 
 
 @pytest.mark.parametrize("case", ["vhub", "er_dense", "star"])
 def test_level_peel_equals_legacy_more_shapes(case):
     g = GRAPH_CASES[case]()
-    out = _fd_all_modes(g, _cfg(num_partitions=4))
-    np.testing.assert_array_equal(out["level"][0], out["b2"][0])
-
-
-@pytest.mark.parametrize("mode", ["level", "b2", "matvec"])
-def test_fd_modes_match_bup_end_to_end(mode):
-    g = GRAPH_CASES["powerlaw"]()
     tb, _ = bup_oracle(g)
-    tr, _ = tip_decompose(g, _cfg(fd_mode=mode))
+    th, _ = _fd_on_cd(g, _cfg(num_partitions=4))
+    np.testing.assert_array_equal(th, tb)
+
+
+@pytest.mark.parametrize("case", ["powerlaw", "vhub", "er_dense"])
+def test_fd_modes_match_bup_end_to_end(case):
+    g = GRAPH_CASES[case]()
+    tb, _ = bup_oracle(g)
+    tr, _ = tip_decompose(g, _cfg())
     np.testing.assert_array_equal(tb, tr)
 
 
@@ -89,7 +83,7 @@ def test_property_level_peel_equals_bup(n_u, n_v, density, p, seed):
     eu, ev = np.nonzero(a)
     g = BipartiteGraph.from_edges(n_u, n_v, eu, ev)
     tb, _ = bup_oracle(g)
-    tr, _ = tip_decompose(g, _cfg(num_partitions=p, fd_mode="level"))
+    tr, _ = tip_decompose(g, _cfg(num_partitions=p))
     np.testing.assert_array_equal(tb, tr)
 
 
@@ -99,19 +93,14 @@ def test_property_level_peel_equals_bup(n_u, n_v, density, p, seed):
 # --------------------------------------------------------------------- #
 def test_level_peel_counters_are_dynamic():
     g = GRAPH_CASES["powerlaw"]()
-    out = _fd_all_modes(g, _cfg())
-    th, stats = out["level"]
-    _, legacy = out["b2"]
+    _, stats = _fd_on_cd(g, _cfg())
     n_peeled = int(sum(stats.subset_sizes))
     static_bound = int(sum(stats.subset_wedges_fd))
     assert stats.rho_fd > 0
-    # level-peel sweeps <= sequential steps (one level >= one vertex),
-    # and legacy counts exactly one sync round per peel step
-    assert stats.rho_fd <= legacy.rho_fd == n_peeled
+    # level-peel sweeps <= sequential steps (one level >= one vertex)
+    assert stats.rho_fd <= n_peeled
     # dynamically traversed wedges never exceed the static induced bound
     assert 0 < stats.wedges_fd <= static_bound
-    # legacy engines keep the static accounting
-    assert legacy.wedges_fd == static_bound
     assert stats.fd_groups > 0
     assert 0.0 <= stats.fd_padding_waste < 1.0
 
@@ -147,27 +136,28 @@ def test_level_peel_tiny_gather_buffer_falls_back_on_device():
 def test_level_peel_sweep_cap_reenters():
     """A tiny max_sweeps caps ONE loop invocation, not the schedule: the
     level driver must re-enter until every subset drains — survivors must
-    not silently keep theta=0.  The pinned property is level == legacy
-    under the same cap (the cap also constrains the CD phase, identically
-    for every FD mode, so BUP equality is not the right oracle here)."""
+    not silently keep theta=0.  CD re-enters under the same cap, so its
+    partition stays range-contained and FD must still reach the exact
+    BUP tip numbers."""
     for seed in range(4):
         rng = np.random.default_rng(seed)
         a = rng.random((30, 20)) < 0.3
         eu, ev = np.nonzero(a)
         g = BipartiteGraph.from_edges(30, 20, eu, ev)
+        tb, _ = bup_oracle(g)
         for ms in (1, 2, 3):
-            out = _fd_all_modes(g, _cfg(num_partitions=4, max_sweeps=ms))
-            np.testing.assert_array_equal(out["level"][0], out["b2"][0],
+            th, _ = _fd_on_cd(g, _cfg(num_partitions=4, max_sweeps=ms))
+            np.testing.assert_array_equal(th, tb,
                                           err_msg=f"seed={seed} ms={ms}")
-            # every vertex of every non-empty subset received a theta
-            # (level theta can be 0 only where b2's is too)
-            assert (out["level"][0] == out["matvec"][0]).all()
 
 
 def test_unknown_fd_mode_raises():
-    g = GRAPH_CASES["fig1"]()
+    """The FD engine has no modes: a stored config that still names the
+    removed ``fd_mode`` knob fails loudly, naming the key."""
+    from repro.api import EngineConfig
+
     with pytest.raises(ValueError, match="fd_mode"):
-        tip_decompose(g, _cfg(fd_mode="Level"))
+        EngineConfig.from_dict({"fd_mode": "level"})
 
 
 def test_level_peel_interpret_backend():
@@ -192,11 +182,14 @@ def test_level_peel_sparse_backend():
 
 
 def test_level_peel_no_overlap_matches():
-    """Double-buffered group dispatch is a pure latency optimization."""
+    """Double-buffered group dispatch is a pure latency optimization: on
+    a graph with at least two shape groups (so one group is in flight
+    while the next is built) theta is the exact BUP answer."""
     g = GRAPH_CASES["vhub"]()
-    t1, _ = tip_decompose(g, _cfg(fd_overlap=True))
-    t2, _ = tip_decompose(g, _cfg(fd_overlap=False))
-    np.testing.assert_array_equal(t1, t2)
+    tb, _ = bup_oracle(g)
+    tr, stats = tip_decompose(g, _cfg())
+    assert stats.fd_groups >= 2
+    np.testing.assert_array_equal(tb, tr)
 
 
 # --------------------------------------------------------------------- #
@@ -246,19 +239,6 @@ def test_lpt_init_loads_carry_across_batches():
     assert second == [[], [0]]
     # default (no seed) is unchanged legacy behavior
     assert lpt_assign([8.0], 2, init_loads=None) == [[0], []]
-
-
-def test_fd_mesh_requires_level_mode():
-    """The sharded FD driver runs the batched level loop only; the legacy
-    sequential comparators reject a mesh with a clear error."""
-    g = GRAPH_CASES["fig1"]()
-    from repro.core.receipt import RunStats, receipt_cd, receipt_fd
-
-    stats = RunStats()
-    sid, isup, bounds, _ = receipt_cd(g, _cfg(), stats)
-    with pytest.raises(ValueError, match="fd_mode='level'"):
-        receipt_fd(g, sid, isup, bounds, _cfg(fd_mode="b2"), RunStats(),
-                   mesh="sentinel")
 
 
 # --------------------------------------------------------------------- #

@@ -57,15 +57,13 @@ def test_receipt_partition_sweep(p):
     assert stats.num_subsets <= max(p, 1)
 
 
-@pytest.mark.parametrize("fd_mode", ["b2", "matvec"])
+@pytest.mark.parametrize("case", ["vhub", "powerlaw"])
 @pytest.mark.parametrize("huc", [True, False])
 @pytest.mark.parametrize("dgm", [True, False])
-def test_receipt_feature_matrix(fd_mode, huc, dgm):
-    g = GRAPH_CASES["vhub"]()
+def test_receipt_feature_matrix(case, huc, dgm):
+    g = GRAPH_CASES[case]()
     tb, _ = bup_oracle(g)
-    tr, stats = tip_decompose(
-        g, _cfg(fd_mode=fd_mode, use_huc=huc, use_dgm=dgm)
-    )
+    tr, stats = tip_decompose(g, _cfg(use_huc=huc, use_dgm=dgm))
     np.testing.assert_array_equal(tb, tr)
     if not huc:
         assert stats.huc_recounts == 0
@@ -194,36 +192,35 @@ def test_property_hub_graphs(n_u, n_hubs, seed):
 
 
 # --------------------------------------------------------------------- #
-# device-resident sweep loop vs the host-driven engine
+# the device-resident sweep loop
 # --------------------------------------------------------------------- #
 @pytest.mark.slow
 @pytest.mark.parametrize("case", ["er_small", "powerlaw", "vhub", "star",
                                   "empty_edges"])
 def test_device_loop_equals_host_loop(case):
-    """The fused lax.while_loop engine must reproduce the host engine
-    EXACTLY: same theta, same rho/wedge/HUC/elision counters, same subset
-    structure — only the host round-trip count may differ."""
+    """The fused lax.while_loop engine reproduces the BUP oracle exactly,
+    and its subset structure is consistent: every CD sweep is counted in
+    exactly one subset and the subset bounds are nondecreasing."""
     g = GRAPH_CASES[case]()
-    tr_d, s_d = tip_decompose(g, _cfg(device_loop=True))
-    tr_h, s_h = tip_decompose(g, _cfg(device_loop=False))
-    np.testing.assert_array_equal(tr_d, tr_h)
-    assert s_d.rho_cd == s_h.rho_cd
-    assert s_d.wedges_cd == s_h.wedges_cd
-    assert s_d.huc_recounts == s_h.huc_recounts
-    assert s_d.elided_sweeps == s_h.elided_sweeps
-    assert s_d.num_subsets == s_h.num_subsets
-    assert s_d.bounds == s_h.bounds
-    assert s_d.sweeps_per_subset == s_h.sweeps_per_subset
+    tb, _ = bup_oracle(g)
+    tr, s = tip_decompose(g, _cfg())
+    np.testing.assert_array_equal(tb, tr)
+    assert sum(s.sweeps_per_subset) == s.rho_cd
+    assert len(s.bounds) == s.num_subsets + 1
+    assert all(a <= b for a, b in zip(s.bounds, s.bounds[1:]))
 
 
 def test_device_loop_reduces_host_round_trips():
     """The point of the fused engine: O(1) blocking transfers per subset
-    instead of O(sweeps x ~4)."""
+    instead of O(sweeps x ~4).  Without an overflow replay the run
+    fetches once for the initial count, once per CD subset loop and once
+    per FD shape group, and nothing else."""
     g = GRAPH_CASES["powerlaw"]()
-    _, s_d = tip_decompose(g, _cfg(device_loop=True))
-    _, s_h = tip_decompose(g, _cfg(device_loop=False))
-    assert s_d.host_round_trips * 5 <= s_h.host_round_trips
-    assert s_d.device_loop_calls >= s_d.num_subsets
+    _, s = tip_decompose(g, _cfg())
+    assert s.overflow_fallbacks == 0
+    assert s.device_loop_calls == s.num_subsets + s.fd_groups
+    assert s.host_round_trips == 1 + s.num_subsets + s.fd_groups
+    assert s.host_round_trips < s.rho_cd
 
 
 @pytest.mark.slow
@@ -232,7 +229,7 @@ def test_device_loop_overflow_fallback_exact():
     (host replays the oversized sweep, buffer doubles): still exact."""
     g = GRAPH_CASES["powerlaw"]()
     tb, _ = bup_oracle(g)
-    tr, stats = tip_decompose(g, _cfg(device_loop=True, peel_width=8))
+    tr, stats = tip_decompose(g, _cfg(peel_width=8))
     np.testing.assert_array_equal(tb, tr)
     assert stats.overflow_fallbacks > 0
 
@@ -249,11 +246,8 @@ def test_device_loop_matches_oracle_random():
         g = BipartiteGraph.from_edges(n_u, n_v, eu, ev)
         tb, _ = bup_oracle(g)
         p = int(rng.integers(1, 9))
-        tr_d, s_d = tip_decompose(g, _cfg(num_partitions=p, device_loop=True))
-        tr_h, s_h = tip_decompose(g, _cfg(num_partitions=p, device_loop=False))
-        np.testing.assert_array_equal(tb, tr_d)
-        np.testing.assert_array_equal(tb, tr_h)
-        assert s_d.rho_cd == s_h.rho_cd, trial
+        tr, _ = tip_decompose(g, _cfg(num_partitions=p))
+        np.testing.assert_array_equal(tb, tr, err_msg=f"trial {trial}")
 
 
 @pytest.mark.slow
@@ -268,33 +262,31 @@ def test_sparse_backend_through_engine():
 
 @pytest.mark.slow
 def test_parb_device_loop_equals_host():
-    """ParB baseline: device-resident min-schedule == host schedule,
-    including terminal-sweep elision."""
+    """ParB baseline: the device-resident min-schedule matches the BUP
+    oracle, elides the terminal sweep, and needs more synchronization
+    rounds than RECEIPT's range-peel CD on the same graph (the paper's
+    sync-count argument)."""
     from repro.core.receipt import parb_tip_decompose
 
     g = GRAPH_CASES["vhub"]()
     tb, _ = bup_oracle(g)
-    td, sd = parb_tip_decompose(g, _cfg(device_loop=True))
-    th, sh = parb_tip_decompose(g, _cfg(device_loop=False))
+    td, sd = parb_tip_decompose(g, _cfg())
     np.testing.assert_array_equal(tb, td)
-    np.testing.assert_array_equal(tb, th)
-    assert sd.rho_cd == sh.rho_cd
-    assert sd.wedges_cd == sh.wedges_cd
-    assert sd.elided_sweeps == sh.elided_sweeps
     assert sd.elided_sweeps >= 1          # terminal sweep skips the kernel
-    assert sd.host_round_trips < sh.host_round_trips
+    _, sr = tip_decompose(g, _cfg())
+    assert sd.rho_cd > sr.rho_cd
 
 
 @pytest.mark.slow
 def test_parb_device_loop_sweep_cap_reenters():
     """A tiny max_sweeps forces repeated cap-exits of the device loop;
-    the driver must re-enter (the host schedule has no cap), not silently
-    return theta=0 for the survivors."""
+    the driver must re-enter, not silently return theta=0 for the
+    survivors."""
     from repro.core.receipt import parb_tip_decompose
 
     g = GRAPH_CASES["er_small"]()
     tb, _ = bup_oracle(g)
-    td, sd = parb_tip_decompose(g, _cfg(device_loop=True, max_sweeps=3))
+    td, sd = parb_tip_decompose(g, _cfg(max_sweeps=3))
     np.testing.assert_array_equal(tb, td)
     assert sd.device_loop_calls > 1
 
@@ -433,8 +425,6 @@ def test_cd_dispatch_and_valve_validation():
     g = GRAPH_CASES["fig1"]()
     with pytest.raises(ValueError, match="cd_dispatch"):
         tip_decompose(g, _cfg(cd_dispatch="Graph"))
-    with pytest.raises(ValueError, match="device_loop"):
-        tip_decompose(g, _cfg(cd_dispatch="graph", device_loop=False))
     with pytest.raises(ValueError, match="max_sweeps"):
         tip_decompose(g, _cfg(max_sweeps=0))
     with pytest.raises(ValueError, match="checkpoint"):

@@ -297,10 +297,10 @@ class TestFleetIsolation:
 
     def test_bad_member_isolated_healthy_bit_identical(self):
         fleet = self._fleet()
-        clean = Executor(_cfg(fd_mode="level")).map(fleet)
+        clean = Executor(_cfg()).map(fleet)
         bad = BipartiteGraph(4, 4, np.array([9]), np.array([0]))
         fleet_bad = fleet[:2] + [bad] + fleet[2:]
-        ex = Executor(_cfg(fd_mode="level", fault_spec="map_chunk@1"))
+        ex = Executor(_cfg(fault_spec="map_chunk@1"))
         res = ex.map(fleet_bad)
         assert len(res) == 6
         assert isinstance(res[2], GraphValidationError)
@@ -316,7 +316,7 @@ class TestFleetIsolation:
     def test_strict_mode_aggregates(self):
         fleet = self._fleet()
         fleet[1] = BipartiteGraph(4, 4, np.array([9]), np.array([0]))
-        ex = Executor(_cfg(fd_mode="level"))
+        ex = Executor(_cfg())
         with pytest.raises(FleetPartialFailure) as ei:
             ex.map(fleet, strict=True)
         assert list(ei.value.errors) == [1]
@@ -325,15 +325,14 @@ class TestFleetIsolation:
 
     def test_non_graph_member_reported_not_raised(self):
         fleet = self._fleet()
-        res = Executor(_cfg(fd_mode="level")).map(fleet[:2] + ["nope"])
+        res = Executor(_cfg()).map(fleet[:2] + ["nope"])
         assert isinstance(res[2], GraphValidationError)
         assert all(not isinstance(r, ReceiptError) for r in res[:2])
 
     def test_chunk_fault_retries_on_fallback_backend(self):
         fleet = self._fleet()
-        clean = Executor(_cfg(fd_mode="level",
-                              backend="interpret")).map(fleet)
-        ex = Executor(_cfg(fd_mode="level", backend="interpret",
+        clean = Executor(_cfg(backend="interpret")).map(fleet)
+        ex = Executor(_cfg(backend="interpret",
                            fault_spec="map_chunk:backend=interpret@1"))
         res = ex.map(fleet)
         for got, want in zip(res, clean):
@@ -377,7 +376,7 @@ class TestVerifyMode:
 
     def test_verify_map_results_without_bounds(self):
         fleet = [_er(16, 12, 60, s) for s in range(3)]
-        res = Executor(_cfg(fd_mode="level")).map(fleet)
+        res = Executor(_cfg()).map(fleet)
         for g, r in zip(fleet, res):
             assert verify_tip_decomposition(g, "U", r.theta) >= 1
 
@@ -406,7 +405,7 @@ def test_map_straggler_flagging_monkeypatched():
     are fed to the shared StragglerMonitor (forced here by faking one
     slow chunk EWMA)."""
     fleet = [_er(16, 12, 60, s) for s in range(4)]
-    ex = Executor(_cfg(fd_mode="level"), map_stack_cells=16 * 16)
+    ex = Executor(_cfg(), map_stack_cells=16 * 16)
     res = ex.map(fleet)                         # >= 3 chunks recorded
     assert len(ex._stragglers.timings) >= 3
     slow = next(iter(ex._stragglers.timings))

@@ -74,10 +74,10 @@ def _check_closed(spans, outer):
 @pytest.mark.parametrize("kw, phases", [
     (dict(cd_dispatch="subset"), ("cd", "fd")),
     (dict(cd_dispatch="graph"), ("cd", "fd")),
-    (dict(fd_overlap=False), ("cd", "fd")),
+    (dict(side="V"), ("cd", "fd")),
     (dict(representation="tiled"), ("tiled",)),
     (dict(workload="wing"), ("cd", "fd")),
-], ids=["dense-subset", "dense-graph", "no-overlap", "tiled", "wing"])
+], ids=["dense-subset", "dense-graph", "side-V", "tiled", "wing"])
 def test_decompose_spans_nest_and_count_every_sync(tmp_path, kw, phases):
     g = _graph()
     plain = _executor(**kw).decompose(g)
